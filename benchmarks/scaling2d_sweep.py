@@ -141,7 +141,7 @@ _SUB_CODE = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.roofline import halo_wire_bytes_model
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_stencil_mesh
     from repro.stencil.advection import stratus_fields
     from repro.stencil.distributed import (count_exchange_wire_bytes,
                                            make_distributed_step,
@@ -153,7 +153,7 @@ _SUB_CODE = textwrap.dedent("""
     p = default_params(Z)
     counted, measured = [], []
     for nx, ny in cfg["meshes"]:
-        mesh = compat_make_mesh((nx, ny), ("x", "y"))
+        mesh = make_stencil_mesh(nx, ny)
         sh = NamedSharding(mesh, P("x", "y", None))
         args = [jax.device_put(t, sh) for t in (u, v, w)]
         for T in cfg["T"]:

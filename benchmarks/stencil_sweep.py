@@ -220,7 +220,7 @@ _SUB_CODE = textwrap.dedent("""
     import numpy as np
     from repro.core.roofline import halo_wire_bytes_model
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_stencil_mesh
     from repro.stencil import spec as SP
     from repro.stencil.advection import stratus_fields
     from repro.stencil.distributed import (count_exchange_wire_bytes,
@@ -243,10 +243,10 @@ _SUB_CODE = textwrap.dedent("""
     for key, nx, ny, T, exchange in cfg["cases"]:
         spec, sp_params, fields, dt = OPS[key]
         if nx > 1:
-            mesh = compat_make_mesh((nx, ny), ("x", "y"))
+            mesh = make_stencil_mesh(nx, ny)
             kw = dict(axis="y", x_axis="x")
         else:
-            mesh = compat_make_mesh((ny,), ("y",))
+            mesh = jax.make_mesh((ny,), ("y",))
             kw = dict(axis="y")
         ref_step = make_distributed_step(mesh, p, T=T, dt=dt, spec=spec,
                                          spec_params=sp_params,

@@ -78,8 +78,7 @@ def main():
                                                reference_global_step)
         from repro.stencil.advection import stratus_fields
         from repro.kernels.advection.ref import default_params
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4,), ("data",))
+        mesh = jax.make_mesh((4,), ("data",))
         u, v, w = stratus_fields(8, 32, 16)
         p = default_params(16)
         fn = make_distributed_advect(mesh, p)

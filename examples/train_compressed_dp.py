@@ -17,7 +17,6 @@ CODE = textwrap.dedent("""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np, functools
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import pspec
     from repro.configs import get_smoke_config
@@ -28,8 +27,7 @@ CODE = textwrap.dedent("""
 
     cfg = get_smoke_config("qwen3-32b")
     layout = M.make_layout(cfg, tp=1)
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4,), ("dp",))
+    mesh = jax.make_mesh((4,), ("dp",))
     params = pspec.init_params(M.param_specs(cfg, layout), jax.random.PRNGKey(0))
     opt_state = O.init_opt_state(params)
     residuals = init_residuals(params)
@@ -49,11 +47,11 @@ CODE = textwrap.dedent("""
             loss = jax.lax.pmean(loss, "dp")
             return loss, grads, residuals
         pspec_b = jax.tree.map(lambda _: P("dp"), batch)
-        loss, grads, residuals = shard_map(
+        loss, grads, residuals = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), pspec_b, P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, batch, residuals)
         params, opt_state, _ = O.adamw_update(params, grads, opt_state, oc)
         return loss, params, opt_state, residuals

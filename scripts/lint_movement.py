@@ -262,8 +262,7 @@ def _vmem_rows():
         fused_ring_plan(Y, Z, T=T, itemsize=ITEM, y_tile=8, halo=T,
                         context="fused rung rings"),
         distributed_block_plan((DX // 2, DY // 2, DZ), T=T, itemsize=ITEM,
-                               local_kernel="fused", exchange="collective",
-                               interpret=True, nx=2, ny=2,
+                               local_kernel="fused", nx=2, ny=2,
                                context="distributed fused rung"),
         serving_ring_plan(Y, Z, batch=BATCH, T=T, itemsize=ITEM, y_tile=8,
                           n_fields=3, context="serving rung slot rings"),

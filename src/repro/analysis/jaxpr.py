@@ -23,6 +23,7 @@ import hashlib
 import re
 
 import jax
+import jax.extend.core as jax_core
 import numpy as np
 
 __all__ = [
@@ -32,14 +33,13 @@ __all__ = [
 
 
 def iter_jaxprs(val):
-    """Yield every `jax.core.Jaxpr` reachable from an eqn param value:
-    a ClosedJaxpr, a bare Jaxpr, or any list/tuple nesting of them.
-    (Dict-valued params carry no jaxprs on the pinned jax; mirroring the
-    legacy counters, they are not descended into.)"""
-    core = jax.core
-    if isinstance(val, core.ClosedJaxpr):
+    """Yield every `Jaxpr` reachable from an eqn param value: a
+    ClosedJaxpr, a bare Jaxpr, or any list/tuple nesting of them.
+    (Dict-valued params carry no jaxprs; mirroring the legacy counters,
+    they are not descended into.)"""
+    if isinstance(val, jax_core.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, core.Jaxpr):
+    elif isinstance(val, jax_core.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for v in val:
@@ -79,7 +79,7 @@ def _var_str(var) -> str:
     # cache-compatible when those values arrive as arguments. Static
     # leaks of the PR 5 class resolve at trace time into eqn params or
     # structure (slice starts, unrolled bodies) and stay visible.
-    if isinstance(var, jax.core.Literal):
+    if isinstance(var, jax_core.Literal):
         return "lit" + _aval_str(var.aval)
     return _aval_str(var.aval)
 
@@ -124,7 +124,7 @@ def structural_fingerprint(traced) -> str:
     knob changed the TRACE itself — either legitimately (shapes, depth)
     or because a static Python value leaked in (the retrace detector's
     quarry)."""
-    jaxpr = traced.jaxpr if isinstance(traced, jax.core.ClosedJaxpr) else traced
+    jaxpr = traced.jaxpr if isinstance(traced, jax_core.ClosedJaxpr) else traced
     digest = hashlib.sha256(
         "\n".join(fingerprint_parts(jaxpr)).encode()).hexdigest()
     return digest[:16]

@@ -10,18 +10,18 @@ Categories (the paper's profiling-table rows, trace-time edition):
   integrity_words    rank < 3 ppermute operands — the uint32
                      `band_checksum` words a verified exchange rides on
                      each band (`roofline.integrity_bytes_model`).
-  pallas_hbm         rank >= 3 operands/results of field-moving
-                     `pallas_call`s — the HBM streams
-                     (`kernels.advection.hbm_bytes_model`).
-  guard_field_reads  rank >= 3 operands of guard-pass `pallas_call`s
-                     (every result rank < 3 — the guard signature): the
+  pallas_hbm         field operands/results (rank >= 3, both trailing
+                     dims > 1) of field-moving `pallas_call`s — the HBM
+                     streams (`kernels.advection.hbm_bytes_model`).
+  guard_field_reads  field operands of guard-pass `pallas_call`s (no
+                     result is a field — the guard signature): the
                      detection re-read of the fields.
-  guard_flag_words   rank < 3 operands/results of guard-pass calls: the
+  guard_flag_words   non-field operands/results of guard-pass calls: the
                      flag words. guard_field_reads + guard_flag_words
                      is `roofline.guard_bytes_model`'s quantity.
-  pallas_control     rank < 3 operands/results of field-moving
-                     `pallas_call`s — packed coefficient vectors and
-                     interior masks, scalar-pipeline traffic the
+  pallas_control     non-field operands/results of field-moving
+                     `pallas_call`s — packed coefficient rows and
+                     interior mask columns, scalar-pipeline traffic the
                      analytic models deliberately never charged (the
                      documented exclusion in `count_pallas_hbm_bytes`);
                      the coverage pass treats it as unpriced-by-design.
@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
 import jax
+import jax.extend.core as jax_core
 
 from repro.analysis.jaxpr import aval_bytes, walk_jaxpr
 
@@ -84,6 +85,15 @@ def _kernel_name(eqn) -> str:
     return str(getattr(nsi, "name", nsi or ""))
 
 
+def _is_field(aval) -> bool:
+    """A Pallas operand that is a field: rank >= 3 with both trailing dims
+    wider than one. The kernels' control operands — coefficient rows
+    ``(1, N)``, mask columns ``(N, 1)`` and their slot-stacked forms — are
+    rank < 3 or have a unit trailing dim."""
+    shape = tuple(getattr(aval, "shape", ()))
+    return len(shape) >= 3 and min(shape[-2:]) > 1
+
+
 class MovementLedger:
     """The attributed byte records of one traced program."""
 
@@ -102,7 +112,7 @@ class MovementLedger:
     @classmethod
     def from_traced(cls, traced) -> "MovementLedger":
         jaxpr = (traced.jaxpr
-                 if isinstance(traced, jax.core.ClosedJaxpr) else traced)
+                 if isinstance(traced, jax_core.ClosedJaxpr) else traced)
         records = []
 
         def add(category, eqn, var, kernel=""):
@@ -122,17 +132,16 @@ class MovementLedger:
                         eqn, var)
             elif name == "pallas_call":
                 kernel = _kernel_name(eqn)
-                # the guard signature: EVERY result rank < 3 (flags are
-                # (X,) / vmapped (B, X); field kernels emit rank >= 3)
-                guard = all(getattr(v.aval, "ndim", 3) < 3
-                            for v in eqn.outvars)
+                # the guard signature: NO result is a field (flags are
+                # (X,) / (B*X,); field kernels emit fields)
+                guard = not any(_is_field(v.aval) for v in eqn.outvars)
                 for var in list(eqn.invars) + list(eqn.outvars):
-                    ndim = getattr(var.aval, "ndim", 0)
+                    field_ = _is_field(var.aval)
                     if guard:
-                        cat = ("guard_field_reads" if ndim >= 3
+                        cat = ("guard_field_reads" if field_
                                else "guard_flag_words")
                     else:
-                        cat = "pallas_hbm" if ndim >= 3 else "pallas_control"
+                        cat = "pallas_hbm" if field_ else "pallas_control"
                     add(cat, eqn, var, kernel)
             elif name in _COLLECTIVES:
                 for var in eqn.invars:

@@ -95,6 +95,6 @@ def vmem_budget_pass(plan: VmemPlan) -> VmemPlan:
 @register_pass(
     "tiling-contract",
     "lint every pallas_call's block shapes against the (8, 128) tile, "
-    "Unblocked bounds and in-place aliasing windows")
+    "Element bounds and in-place aliasing windows")
 def tiling_contract_pass(fn, *args, **kw):
     return lint_tiling(fn, *args, **kw)

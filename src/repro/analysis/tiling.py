@@ -13,18 +13,19 @@ Three checks per block mapping:
                   visible; the linter makes it enumerable. Warnings,
                   not errors: the interpret-mode compute grids are
                   deliberately tiny and misaligned.
-  unblocked-oob   (error) for `pl.Unblocked` mappings the index map
+  element-oob     (error) for `pl.Element` block dims the index map
                   returns ELEMENT offsets with no XLA clamp semantics:
                   the linter evaluates the index-map jaxpr over the
                   launch grid (every point up to `max_grid_points`,
                   corners beyond) and flags any block reaching outside
                   the operand extent — the out-of-bounds read/write a
                   wrong `_slab_lo` clip would cause, caught before
-                  anything runs.
+                  anything runs.  Blocked dims of the same mapping are
+                  checked too, their block index scaled to elements.
   alias-*         (error) `input_output_aliases` pairs update a buffer
                   in place: operand/result extents must match
-                  (alias-shape) and, when both sides are Unblocked,
-                  their index maps must address the same window at
+                  (alias-shape) and, when either side has an Element
+                  dim, their index maps must address the same window at
                   every grid point (alias-window) — otherwise the
                   in-place write lands somewhere the aliased read
                   didn't come from.
@@ -54,7 +55,7 @@ SUBLANE, LANE = 8, 128
 @dataclass(frozen=True)
 class TilingIssue:
     severity: str      # "error" | "warn"
-    kind: str          # "lane" | "sublane" | "unblocked-oob" | "alias-*"
+    kind: str          # "lane" | "sublane" | "element-oob" | "alias-*"
     kernel: str
     operand: str
     detail: str
@@ -105,30 +106,49 @@ def _grid_points(grid, max_grid_points):
 
 
 def _eval_index_map(index_map_jaxpr, point):
-    vals = jax.core.eval_jaxpr(index_map_jaxpr.jaxpr, index_map_jaxpr.consts,
+    vals = jax.core.eval_jaxpr(index_map_jaxpr.jaxpr,
+                               index_map_jaxpr.consts,
                                *[np.int32(p) for p in point])
     return tuple(int(v) for v in vals)
 
 
+def _block_size(b) -> int:
+    """Elements a block dim spans: a plain int, or the `block_size` of a
+    `pl.Blocked` / `pl.Element` dim (squeezed dims span 1)."""
+    if isinstance(b, (int, np.integer)):
+        return int(b)
+    return int(getattr(b, "block_size", 1) or 1)
+
+
 def _block_dims(block_shape):
-    """Block shape entries that are concrete ints (squeezed/mapped dims
-    are pallas-internal sentinels — skipped)."""
-    return [(d, int(b)) for d, b in enumerate(block_shape)
-            if isinstance(b, (int, np.integer))]
+    """(dim, size) of every block dim that occupies a tile dimension
+    (squeezed dims are pallas-internal sentinels — skipped)."""
+    return [(d, _block_size(b)) for d, b in enumerate(block_shape)
+            if isinstance(b, (int, np.integer))
+            or type(b).__name__ in ("Blocked", "Element")]
+
+
+def _is_element(b) -> bool:
+    return type(b).__name__ == "Element"
+
+
+def _has_element_dim(bm) -> bool:
+    return any(_is_element(b) for b in getattr(bm, "block_shape", ()) or ())
 
 
 def _check_mapping(bm, *, kernel, operand, grid, max_grid_points, issues,
                    sublane, lane):
     block = list(getattr(bm, "block_shape", ()) or ())
     dims = _block_dims(block)
-    arr = getattr(getattr(bm, "array_shape_dtype", None), "shape", None)
+    shape = tuple(_block_size(b) for b in block)
+    arr = getattr(getattr(bm, "array_aval", None), "shape", None)
     # ---- (8, 128) contract: warn on misaligned trailing dims
     if dims:
         last_d, last_b = dims[-1]
         if last_b % lane:
             issues.append(TilingIssue(
                 "warn", "lane", kernel, operand,
-                f"block shape {tuple(block)} last dim {last_b} is not a "
+                f"block shape {shape} last dim {last_b} is not a "
                 f"multiple of the {lane}-lane tile — every vregister is "
                 f"padded (the hbm model's lane_eff penalty)"))
         if len(dims) >= 2:
@@ -136,15 +156,14 @@ def _check_mapping(bm, *, kernel, operand, grid, max_grid_points, issues,
             if sub_b % sublane:
                 issues.append(TilingIssue(
                     "warn", "sublane", kernel, operand,
-                    f"block shape {tuple(block)} dim {sub_d} ({sub_b} "
+                    f"block shape {shape} dim {sub_d} ({sub_b} "
                     f"rows) is not a multiple of the {sublane}-sublane "
                     f"tile"))
-    # ---- Unblocked bounds vs operand extent
-    mode = type(getattr(bm, "indexing_mode", None)).__name__
-    if mode != "Unblocked" or arr is None:
+    # ---- Element (element-offset) bounds vs operand extent
+    if not _has_element_dim(bm) or arr is None:
         return
-    padding = getattr(bm.indexing_mode, "padding", None)
-    if padding and any(int(lo) or int(hi) for lo, hi in padding):
+    if any(any(int(x) for x in getattr(b, "padding", (0, 0)))
+           for b in block if _is_element(b)):
         return  # padded refs extend the addressable window by design
     imap = getattr(bm, "index_map_jaxpr", None)
     if imap is None:
@@ -155,21 +174,21 @@ def _check_mapping(bm, *, kernel, operand, grid, max_grid_points, issues,
     except Exception as e:  # unevaluable map: surface, don't crash
         issues.append(TilingIssue(
             "warn", "index-map-uneval", kernel, operand,
-            f"could not evaluate Unblocked index map statically: {e!r}"))
+            f"could not evaluate Element index map statically: {e!r}"))
         return
     for starts, pt in starts_per_point:
-        # starts align 1:1 with block dims for Unblocked mappings;
-        # squeezed dims carry a sentinel block entry and span 1 element
-        for d, start in enumerate(starts):
+        # the index map returns an element offset for Element dims and a
+        # block index for Blocked ones (scaled to elements here)
+        for d, idx in enumerate(starts):
             if d >= len(arr) or d >= len(block):
                 continue
-            size = (int(block[d])
-                    if isinstance(block[d], (int, np.integer)) else 1)
+            size = _block_size(block[d])
+            start = idx if _is_element(block[d]) else idx * size
             extent = int(arr[d])
             if start < 0 or start + size > extent:
                 issues.append(TilingIssue(
-                    "error", "unblocked-oob", kernel, operand,
-                    f"grid point {pt}: Unblocked window "
+                    "error", "element-oob", kernel, operand,
+                    f"grid point {pt}: block window "
                     f"[{start}, {start + size}) exceeds operand extent "
                     f"{extent} in dim {d} (operand shape {tuple(arr)})"))
                 return  # one witness per operand is enough
@@ -199,9 +218,9 @@ def _lint_pallas_eqn(eqn, *, max_grid_points, sublane, lane, issues):
         if in_idx >= len(mappings) or n_in + out_idx >= len(mappings):
             continue
         bm_in, bm_out = mappings[in_idx], mappings[n_in + out_idx]
-        shp_in = getattr(getattr(bm_in, "array_shape_dtype", None),
+        shp_in = getattr(getattr(bm_in, "array_aval", None),
                          "shape", None)
-        shp_out = getattr(getattr(bm_out, "array_shape_dtype", None),
+        shp_out = getattr(getattr(bm_out, "array_aval", None),
                           "shape", None)
         pair = f"in[{in_idx}]<->out[{out_idx}]"
         if shp_in != shp_out:
@@ -211,9 +230,7 @@ def _lint_pallas_eqn(eqn, *, max_grid_points, sublane, lane, issues):
                 f"{shp_out} — the in-place update writes outside the "
                 f"buffer it reads"))
             continue
-        modes = {type(getattr(b, "indexing_mode", None)).__name__
-                 for b in (bm_in, bm_out)}
-        if modes == {"Unblocked"}:
+        if _has_element_dim(bm_in) or _has_element_dim(bm_out):
             try:
                 for pt in _grid_points(grid, max_grid_points):
                     si = _eval_index_map(bm_in.index_map_jaxpr, pt)

@@ -6,10 +6,9 @@ The serving tier already had this discipline for ONE buffer class
 (`roofline.serving_max_batch` bounds the batched slot rings); this pass
 generalises it to every rung of the ladder: the fused kernel's
 shift-register ring (`kernels.advection.fused_register_bytes`, spec
-geometry included), the remote-DMA engine's staged-send slabs and
-double-buffered recv slabs (`kernels.advection.dma_slab_bytes`, the
-exact scratch/out shapes `halo_band_exchange_dma` declares), and the
-serving engine's per-slot rings. A `VmemPlan` is a list of named
+geometry included) on every shard of a distributed block, and the
+serving engine's per-slot rings. (The remote-DMA exchange moves its
+bands HBM to HBM, so it holds no VMEM.) A `VmemPlan` is a list of named
 buffers plus the budget; `check()` raises `VmemBudgetExceeded` NAMING
 the largest offender, so an over-budget config fails at build/trace
 time with the buffer to shrink instead of at compile time with a Mosaic
@@ -111,22 +110,19 @@ def fused_ring_plan(y_rows: int, Z: int, *, T: int, itemsize: int = 4,
 
 def distributed_block_plan(shard_shape: Tuple[int, int, int], *, T: int,
                            itemsize: int = 4, local_kernel: str,
-                           exchange: str, interpret: bool,
                            y_tile: Optional[int] = None, nx: int = 1,
                            ny: int = 1, spec=None,
                            budget: int = R.VMEM_PER_CORE,
                            context: str = "") -> VmemPlan:
-    """Static per-shard VMEM plan of one distributed substep block:
-    the fused ring over the halo-EXTENDED slab (when
-    `local_kernel="fused"`) plus the compiled remote-DMA engine's
-    staged-send and recv slabs for both exchange phases (when
-    `exchange="remote_dma"` and not interpreting — the emulation stages
-    nothing in VMEM). `spec` switches the ring to the generalised
-    `stencil_fused` geometry and the exchange depth to `spec.halo(T)`.
+    """Static per-shard VMEM plan of one distributed substep block: the
+    fused ring over the halo-EXTENDED slab (when `local_kernel="fused"`;
+    the jnp reference loop holds no VMEM of its own, and either exchange
+    engine moves its bands HBM to HBM). `spec` switches the ring to the
+    generalised `stencil_fused` geometry and the exchange depth to
+    `spec.halo(T)`.
     """
     Xl, Yl, Z = shard_shape
     depth = spec.halo(T) if spec is not None else T
-    n_fields = spec.n_fields if spec is not None else 3
     dx = depth if nx > 1 else 0
     dy = depth if ny > 1 else 0
     buffers = []
@@ -142,26 +138,6 @@ def distributed_block_plan(shard_shape: Tuple[int, int, int], *, T: int,
             "fused shift-register ring (halo-extended shard slab)", per,
             f"slab {(Xl + 2 * dx, Yl + 2 * dy, Z)}, y_tile={y_tile}, "
             f"T={T}, depth={depth}"))
-    if exchange == "remote_dma" and not interpret:
-        if dx:
-            stage, recv = K.dma_slab_bytes((Xl, Yl, Z), dx, 0, itemsize,
-                                           n_fields=n_fields)
-            buffers.append(VmemBuffer(
-                "remote-DMA staged-send slabs (x phase)", stage,
-                f"depth={dx} planes of {(Xl, Yl, Z)}"))
-            buffers.append(VmemBuffer(
-                "remote-DMA double-buffered recv slabs (x phase)", recv,
-                "2 slots x 2 sides"))
-        if dy:
-            x_ext = Xl + 2 * dx
-            stage, recv = K.dma_slab_bytes((x_ext, Yl, Z), dy, 1, itemsize,
-                                           n_fields=n_fields)
-            buffers.append(VmemBuffer(
-                "remote-DMA staged-send slabs (y phase, x-extended)",
-                stage, f"depth={dy} rows of {(x_ext, Yl, Z)}"))
-            buffers.append(VmemBuffer(
-                "remote-DMA double-buffered recv slabs (y phase)", recv,
-                "2 slots x 2 sides"))
     return VmemPlan(tuple(buffers), budget=budget, context=context)
 
 

@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,11 +68,11 @@ def pipeline_apply(stacked_params, xs, block_fn: Callable, mesh: Mesh,
         last = (s == n_stage - 1).astype(outs.dtype)
         return jax.lax.psum(outs * last, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(P(axis), P()),      # params split by stage; xs replicated
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, xs)
 

@@ -47,7 +47,7 @@ Grid-tiled execution contract (the `y_tile` path, `tiling="grid"`):
   `blocked`/`dataflow`/`wide`/`fused` accept `y_tile` and run the whole
   domain in ONE kernel launch over a 2D `(y_tile, x)` grid — the y-tile
   index is the outer (slow) grid dimension, x the inner streaming one.
-  Element-indexed (`pl.Unblocked`) block specs select each tile's slab
+  Element-indexed (`pl.Element`) block dims select each tile's slab
   (`y_tile + 2*halo` rows, clipped flush into the domain at the edges) and
   write each tile's owned rows in place, so there is no host-side restitch
   (`jnp.concatenate`) and no per-tile dispatch. The ring register is sized
@@ -93,11 +93,22 @@ alternate slots across K substep-blocks inside one traced program instead
 of trusting XLA to schedule a `ppermute` — the paper's §IV "do the data
 movement yourself" lesson at the chip-to-chip level.
 
+Compiled vs interpreted: every entry point takes `interpret=None` and
+resolves it with `resolve_interpret` — Mosaic where the data lives on a
+TPU, the Pallas interpreter elsewhere. The compiled path keeps to what
+Mosaic lowers: element-indexed slab blocks whose row offsets are aligned to
+the 8-row sublane tile (`_grid_geometry(align=8)` rounds the fetch halo up),
+coefficient rows ``(1, Z+2)`` and mask columns ``(N, 1)`` instead of rank-1
+vectors, tiled outputs copied out of a VMEM slab at a dynamic row offset,
+and the guard's flags in SMEM. Fields stay in the row-major HBM layout
+`field_format` names.
+
 Validated with interpret=True against ref.pw_advect_ref, the f64 oracle, and
 the multi-step f64 oracle (fused) across shape/dtype/T/y_tile sweeps in
 tests/test_advection_kernels.py, tests/test_advection_fused.py and
-tests/test_advection_grid_tiled.py; the remote-DMA band kernel is
-compiled-TPU-only and rides tests/test_compiled_smoke.py.
+tests/test_advection_grid_tiled.py; tests/test_tpu_compile.py compiles the
+main-path kernels (the remote-DMA band kernel included) for a described
+TPU v5e at real size, and chip_smoke.py runs them on the chip.
 """
 from __future__ import annotations
 
@@ -107,6 +118,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Format, Layout
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.advection.ref import AdvectParams
@@ -114,10 +126,78 @@ from repro.launch.mesh import dma_neighbor_coords
 
 TILINGS = ("grid", "host")
 _WIDE_HALO = 8   # sublane-rounded fetch halo: keeps wide's (8,128) contract
+_SUBLANE = 8     # f32 sublane tile: compiled slab/output row offsets align
 
 
-def _source_slices(um, uc, up, vm, vc, vp, wm, wc, wp, tcx, tcy, tzc1, tzc2):
-    """PW source terms for one x-slice. Inputs (rows, Z) f32 views."""
+def resolve_interpret(interpret: Optional[bool] = None, *arrays,
+                      mesh=None) -> bool:
+    """The Pallas mode when the caller did not choose one: compiled Mosaic
+    where the data lives on a TPU, the interpreter anywhere else. A mesh
+    decides by its devices and a concrete array by its own; a traced
+    value carries no device, so the default backend decides for it."""
+    if interpret is not None:
+        return bool(interpret)
+    if mesh is not None:
+        return mesh.devices.flat[0].platform != "tpu"
+    for a in arrays:
+        if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer):
+            return next(iter(a.devices())).platform != "tpu"
+    return jax.default_backend() != "tpu"
+
+
+def field_format(sharding) -> Format:
+    """The row-major HBM layout the kernels stream fields in, on `sharding`.
+
+    XLA's default TPU layout for a (..., Y, Z) f32 array with Z < 128 puts
+    Y on the lanes, while the kernels read (rows, Z) slabs with Z on the
+    lanes. A compiled program whose field arguments keep the default
+    layout copies every field into the kernel's layout on entry and back
+    on exit; placing the fields in this layout (`jax.device_put(x, fmt)`)
+    and compiling with it (`in_shardings`/`out_shardings`) keeps them in
+    place. At Z=64 this layout pads each row to 128 lanes."""
+    return Format(Layout(major_to_minor=(0, 1, 2)), sharding)
+
+
+def _pack_coeffs(p: AdvectParams):
+    """Scalars + z-metrics packed into two ``(1, Z+2)`` rows
+    ``[tcx, tcy, tzc...]`` — one lane-major row per metric, the layout
+    Mosaic accepts for a small operand (rank 1 is refused)."""
+    t1 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc1])
+    t2 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc2])
+    return t1[None, :], t2[None, :]
+
+
+def _coeffs(t1_ref, t2_ref):
+    """(tcx, tcy, tzc1, tzc2) from the packed rows: ``(1, 1)`` scalars and
+    the ``(1, Z-2)`` z-interior metrics, broadcast over (rows, Z-2)."""
+    t1, t2 = t1_ref[...], t2_ref[...]
+    n = t1.shape[1] - 1
+    return 0.0 + t1[:, 0:1], t1[:, 1:2], t1[:, 3:n], t2[:, 3:n]
+
+
+def _masks(x_interior_mask, y_interior_mask, X: int, Y: int):
+    """The interior masks as ``(X, 1)`` / ``(Y, 1)`` f32 columns (all-ones
+    when absent): one mask word per row of a sublane-major operand."""
+    out = []
+    for m, n, name in ((x_interior_mask, X, "x"), (y_interior_mask, Y, "y")):
+        m = (jnp.ones((n,), jnp.float32) if m is None
+             else jnp.asarray(m, jnp.float32))
+        if m.shape != (n,):
+            raise ValueError(f"{name}_interior_mask must have shape ({n},), "
+                             f"got {m.shape}")
+        out.append(m[:, None])
+    return tuple(out)
+
+
+def _plane_ok(xm_ref, j, X: int):
+    """``(1, 1)`` flag: may x-plane j (clipped into the domain) take a
+    source? A dynamic one-row read of the ``(X, 1)`` x-mask column."""
+    return xm_ref[pl.ds(jnp.clip(j, 0, X - 1), 1), :] > 0.0
+
+
+def _source_slices(um, uc, up, vm, vc, vp, wm, wc, wp, tcx, tcy, t1, t2):
+    """PW source terms for one x-slice. Inputs (rows, Z) f32 views;
+    `t1`/`t2` are the z-interior metrics (see `_coeffs`)."""
     def inner(f):
         return f[1:-1, 1:-1]
 
@@ -125,9 +205,6 @@ def _source_slices(um, uc, up, vm, vc, vp, wm, wc, wp, tcx, tcy, tzc1, tzc2):
         f = {-1: f_m, 0: f_c, 1: f_p}[di]
         Y, Z = f.shape
         return f[1 + dj:Y - 1 + dj, 1 + dk:Z - 1 + dk]
-
-    t1 = tzc1[1:-1]
-    t2 = tzc2[1:-1]
 
     def source(fm, fc, fp):
         fx = tcx * (sh(um, uc, up, -1, 0, 0) * (inner(fc) + inner(fm))
@@ -160,50 +237,104 @@ def _check_y_tile(y_tile: Optional[int]) -> None:
         raise ValueError(f"y_tile must be >= 1, got {y_tile}")
 
 
-def _grid_geometry(Y: int, y_tile: Optional[int],
-                   halo: int) -> Tuple[int, int, int]:
+def _grid_geometry(Y: int, y_tile: Optional[int], halo: int,
+                   align: int = 1) -> Tuple[int, int, int]:
     """(TY, S, n_ty): owned rows per tile, static slab rows, tile count.
 
     Untiled (or a slab that would not fit the domain) degenerates to one
     full-domain tile (Y, Y, 1) — the 2D grid with n_ty=1 IS the untiled
-    kernel, so there is a single code path.
+    kernel, so there is a single code path. `align` > 1 (the compiled
+    path: Mosaic's pipelined DMAs start on a sublane tile) rounds the
+    slab's fetch halo up to a multiple of it, so every slab and output
+    row offset is aligned; the tile and Y must then be multiples of it.
+    The slab's fetch halo is ``(S - TY) // 2``.
     """
-    if y_tile is None or y_tile >= Y or y_tile + 2 * halo > Y:
+    if y_tile is None or y_tile >= Y:
         return Y, Y, 1
+    halo = -(-halo // align) * align
+    if y_tile + 2 * halo > Y:
+        return Y, Y, 1
+    if y_tile % align or Y % align:
+        raise ValueError(
+            f"compiled y-tiling needs y_tile and Y to be multiples of the "
+            f"{align}-row sublane tile, got y_tile={y_tile}, Y={Y}")
     return y_tile, y_tile + 2 * halo, -(-Y // y_tile)
 
 
-def _slab_lo(t, Y: int, TY: int, S: int, H: int):
+def _aligned(x, align: int):
+    return pl.multiple_of(x, align) if align > 1 else x
+
+
+def _slab_lo(t, Y: int, TY: int, S: int, align: int = 1):
     """Global row of slab row 0 for tile t, clipped flush into the domain."""
-    return jnp.clip(t * TY - H, 0, Y - S)
+    return _aligned(jnp.clip(t * TY - (S - TY) // 2, 0, Y - S), align)
 
 
-def _out_lo(t, Y: int, TY: int):
+def _out_lo(t, Y: int, TY: int, align: int = 1):
     """Global row of the tile's (1, TY, Z) output block; the remainder tile
     slides down so its static-shaped block stays in bounds — its extra rows
     overlap the previous tile's and are rewritten with identical values
     (every row it emits has >= halo rows of slab margin)."""
-    return jnp.minimum(t * TY, Y - TY)
+    return _aligned(jnp.minimum(t * TY, Y - TY), align)
 
 
-def _own_start(t, Y: int, TY: int, S: int, H: int):
+def _own_start(t, Y: int, TY: int, S: int, align: int = 1):
     """Slab-local row where the tile's owned output rows begin."""
-    return _out_lo(t, Y, TY) - _slab_lo(t, Y, TY, S, H)
+    return _aligned(_out_lo(t, Y, TY) - _slab_lo(t, Y, TY, S), align)
 
 
-def _emit_tile_outputs(refs, sources, cens, interior, start, fuse, dt):
+def _slab_specs(X: int, Y: int, Z: int, TY: int, S: int, lag: int,
+                align: int):
+    """(field slab in, owned rows out, y-mask) block specs of the in-grid
+    (y_tile, x) launch: grid step (t, i) reads x-slice min(i, X-1) of tile
+    t's slab and writes x-slice clip(i - lag) of its owned rows. Every dim
+    is element-indexed (Mosaic takes Element dims all-or-none)."""
+    E = pl.Element
+    in_spec = pl.BlockSpec(
+        (E(1), E(S), E(Z)),
+        lambda t, i: (jnp.minimum(i, X - 1), _slab_lo(t, Y, TY, S, align), 0))
+    out_spec = pl.BlockSpec(
+        (E(1), E(TY), E(Z)),
+        lambda t, i: (jnp.clip(i - lag, 0, X - 1), _out_lo(t, Y, TY, align),
+                      0))
+    ym_spec = pl.BlockSpec((E(S), E(1)),
+                           lambda t, i: (_slab_lo(t, Y, TY, S, align), 0))
+    return in_spec, out_spec, ym_spec
+
+
+def _out_scratch(TY: int, S: int, Z: int, dtype):
+    """A tiled launch stages each output slab in VMEM and copies the owned
+    rows out of it (a dynamic row window of a ref, which Mosaic lowers);
+    an untiled one writes the slab straight to its block."""
+    return [] if TY == S else [pltpu.VMEM((S, Z), dtype)]
+
+
+def _write_owned(pairs, obuf, start, TY: int):
+    """Write each ``(out_ref, slab_value)`` pair's owned rows
+    ``[start, start + TY)`` to the (1, TY, Z) output block."""
+    for ref, val in pairs:
+        if obuf is None:
+            ref[0] = val.astype(ref.dtype)
+        else:
+            obuf[...] = val.astype(obuf.dtype)
+            ref[0] = obuf[pl.ds(start, TY), :]
+
+
+def _emit_tile_outputs(refs, sources, cens, interior, start, fuse, dt,
+                       obuf):
     """Shared v1/v2 epilogue: mask each slab source to the x-interior,
     optionally fold the Euler update in (`fuse`: advanced fields out), and
     write the tile's owned rows — the (1, TY, Z) output block — from slab
     row `start`."""
+    pairs = []
     for ref, s, cen in zip(refs, sources, cens):
         if fuse:
             src = jnp.where(interior, _pad_edges(s), 0.0).astype(cen.dtype)
             val = cen + dt * src
         else:
             val = jnp.where(interior, _pad_edges(s), 0.0).astype(ref.dtype)
-        ref[0] = jax.lax.dynamic_slice(val, (start, 0),
-                                       (ref.shape[1], val.shape[1]))
+        pairs.append((ref, val))
+    _write_owned(pairs, obuf, start, refs[0].shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -214,51 +345,53 @@ def _emit_tile_outputs(refs, sources, cens, interior, start, fuse, dt):
 def _kernel_blocked(t1_ref, t2_ref,
                     um_ref, uc_ref, up_ref, vm_ref, vc_ref, vp_ref,
                     wm_ref, wc_ref, wp_ref,
-                    su_ref, sv_ref, sw_ref, *, X, Y, TY, S, H, fuse, dt):
+                    su_ref, sv_ref, sw_ref, *obuf, X, Y, TY, S, align, fuse,
+                    dt):
     t = pl.program_id(0)
     i = pl.program_id(1)
     args = [r[0] for r in (um_ref, uc_ref, up_ref, vm_ref, vc_ref, vp_ref,
                            wm_ref, wc_ref, wp_ref)]
-    su, sv, sw = _source_slices(*args, 0.0 + t1_ref[0], t1_ref[1],
-                                t1_ref[2:], t2_ref[2:])
+    su, sv, sw = _source_slices(*args, *_coeffs(t1_ref, t2_ref))
     interior = (i >= 1) & (i <= X - 2)
     _emit_tile_outputs((su_ref, sv_ref, sw_ref), (su, sv, sw),
                        (args[1], args[4], args[7]), interior,
-                       _own_start(t, Y, TY, S, H), fuse, dt)
+                       _own_start(t, Y, TY, S, align), fuse, dt,
+                       obuf[0] if obuf else None)
 
 
-def advect_blocked(u, v, w, p: AdvectParams, *, interpret: bool = True,
+def advect_blocked(u, v, w, p: AdvectParams, *,
+                   interpret: Optional[bool] = None,
                    y_tile: int | None = None, tiling: str = "grid",
                    fuse_update: bool = False, dt: float = 1.0):
     _check_tiling(tiling)
     _check_y_tile(y_tile)
+    interpret = resolve_interpret(interpret, u)
     X, Y, Z = u.shape
     if tiling == "host" and y_tile is not None and y_tile < Y:
         fn = lambda a, b, c: advect_blocked(a, b, c, p, interpret=interpret,
                                             fuse_update=fuse_update, dt=dt)
         return _y_tiled_host(fn, u, v, w, y_tile=y_tile, halo=1)
-    TY, S, n_ty = _grid_geometry(Y, y_tile, 1)
+    align = 1 if interpret else _SUBLANE
+    TY, S, n_ty = _grid_geometry(Y, y_tile, 1, align)
+    E = pl.Element
     slice_spec = lambda off: pl.BlockSpec(
-        (1, S, Z),
+        (E(1), E(S), E(Z)),
         lambda t, i, off=off: (jnp.clip(i + off, 0, X - 1),
-                               _slab_lo(t, Y, TY, S, 1), 0),
-        indexing_mode=pl.Unblocked())
-    # pack scalars+z-metrics into one (Z+2,) vector per metric for simplicity
-    t1 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc1])
-    t2 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc2])
-    tz_spec = pl.BlockSpec((Z + 2,), lambda t, i: (0,))
-    out_spec = pl.BlockSpec((1, TY, Z),
-                            lambda t, i: (i, _out_lo(t, Y, TY), 0),
-                            indexing_mode=pl.Unblocked())
+                               _slab_lo(t, Y, TY, S, align), 0))
+    t1, t2 = _pack_coeffs(p)
+    tz_spec = pl.BlockSpec(t1.shape, lambda t, i: (0, 0))
+    out_spec = pl.BlockSpec((E(1), E(TY), E(Z)),
+                            lambda t, i: (i, _out_lo(t, Y, TY, align), 0))
     out_shape = [jax.ShapeDtypeStruct((X, Y, Z), u.dtype)] * 3
     fn = pl.pallas_call(
-        functools.partial(_kernel_blocked, X=X, Y=Y, TY=TY, S=S, H=1,
-                          fuse=fuse_update, dt=dt),
+        functools.partial(_kernel_blocked, X=X, Y=Y, TY=TY, S=S,
+                          align=align, fuse=fuse_update, dt=dt),
         grid=(n_ty, X),
         in_specs=[tz_spec, tz_spec] + [slice_spec(o) for _ in range(3)
                                        for o in (-1, 0, 1)],
         out_specs=[out_spec] * 3,
         out_shape=out_shape,
+        scratch_shapes=_out_scratch(TY, S, Z, u.dtype),
         interpret=interpret,
     )
     return fn(t1, t2, u, u, u, v, v, v, w, w, w)
@@ -271,7 +404,7 @@ def advect_blocked(u, v, w, p: AdvectParams, *, interpret: bool = True,
 
 def _kernel_dataflow(t1_ref, t2_ref, u_ref, v_ref, w_ref,
                      su_ref, sv_ref, sw_ref,
-                     ubuf, vbuf, wbuf, *, X, Y, TY, S, H, fuse, dt):
+                     ubuf, vbuf, wbuf, *obuf, X, Y, TY, S, align, fuse, dt):
     t = pl.program_id(0)
     i = pl.program_id(1)
     # 1) shift register: store the newly-arrived slice at ring position i%3.
@@ -289,12 +422,12 @@ def _kernel_dataflow(t1_ref, t2_ref, u_ref, v_ref, w_ref,
     args = [ubuf[m], ubuf[c], ubuf[pslot],
             vbuf[m], vbuf[c], vbuf[pslot],
             wbuf[m], wbuf[c], wbuf[pslot]]
-    su, sv, sw = _source_slices(*args, 0.0 + t1_ref[0], t1_ref[1],
-                                t1_ref[2:], t2_ref[2:])
+    su, sv, sw = _source_slices(*args, *_coeffs(t1_ref, t2_ref))
     interior = (i >= 2) & (i <= X - 1)
     _emit_tile_outputs((su_ref, sv_ref, sw_ref), (su, sv, sw),
                        (args[1], args[4], args[7]), interior,
-                       _own_start(t, Y, TY, S, H), fuse, dt)
+                       _own_start(t, Y, TY, S, align), fuse, dt,
+                       obuf[0] if obuf else None)
 
 
 def _y_tiled_host(fn, u, v, w, *, y_tile: int, halo: int):
@@ -321,39 +454,34 @@ def _y_tiled_host(fn, u, v, w, *, y_tile: int, halo: int):
     return tuple(jnp.concatenate(a, axis=1) for a in outs)
 
 
-def advect_dataflow(u, v, w, p: AdvectParams, *, interpret: bool = True,
+def advect_dataflow(u, v, w, p: AdvectParams, *,
+                    interpret: Optional[bool] = None,
                     y_tile: int | None = None, tiling: str = "grid",
                     fuse_update: bool = False, dt: float = 1.0,
                     _fetch_halo: int = 1):
     _check_tiling(tiling)
     _check_y_tile(y_tile)
+    interpret = resolve_interpret(interpret, u)
     X, Y, Z = u.shape
     if tiling == "host" and y_tile is not None and y_tile < Y:
         fn = lambda a, b, c: advect_dataflow(a, b, c, p, interpret=interpret,
                                              fuse_update=fuse_update, dt=dt)
         return _y_tiled_host(fn, u, v, w, y_tile=y_tile, halo=1)
-    H = _fetch_halo
-    TY, S, n_ty = _grid_geometry(Y, y_tile, H)
-    in_spec = pl.BlockSpec((1, S, Z),
-                           lambda t, i: (jnp.minimum(i, X - 1),
-                                         _slab_lo(t, Y, TY, S, H), 0),
-                           indexing_mode=pl.Unblocked())
-    out_spec = pl.BlockSpec((1, TY, Z),
-                            lambda t, i: (jnp.clip(i - 1, 0, X - 1),
-                                          _out_lo(t, Y, TY), 0),
-                            indexing_mode=pl.Unblocked())
-    t1 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc1])
-    t2 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc2])
-    tz_spec = pl.BlockSpec((Z + 2,), lambda t, i: (0,))
+    align = 1 if interpret else _SUBLANE
+    TY, S, n_ty = _grid_geometry(Y, y_tile, _fetch_halo, align)
+    in_spec, out_spec, _ = _slab_specs(X, Y, Z, TY, S, 1, align)
+    t1, t2 = _pack_coeffs(p)
+    tz_spec = pl.BlockSpec(t1.shape, lambda t, i: (0, 0))
     out_shape = [jax.ShapeDtypeStruct((X, Y, Z), u.dtype)] * 3
     fn = pl.pallas_call(
-        functools.partial(_kernel_dataflow, X=X, Y=Y, TY=TY, S=S, H=H,
-                          fuse=fuse_update, dt=dt),
+        functools.partial(_kernel_dataflow, X=X, Y=Y, TY=TY, S=S,
+                          align=align, fuse=fuse_update, dt=dt),
         grid=(n_ty, X + 1),
         in_specs=[tz_spec, tz_spec, in_spec, in_spec, in_spec],
         out_specs=[out_spec] * 3,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((3, S, Z), u.dtype) for _ in range(3)],
+        scratch_shapes=([pltpu.VMEM((3, S, Z), u.dtype) for _ in range(3)]
+                        + _out_scratch(TY, S, Z, u.dtype)),
         interpret=interpret,
     )
     return fn(t1, t2, u, v, w)
@@ -364,7 +492,8 @@ def advect_dataflow(u, v, w, p: AdvectParams, *, interpret: bool = True,
 # ---------------------------------------------------------------------------
 
 
-def advect_wide(u, v, w, p: AdvectParams, *, interpret: bool = True,
+def advect_wide(u, v, w, p: AdvectParams, *,
+                interpret: Optional[bool] = None,
                 y_tile: int | None = None, tiling: str = "grid",
                 fuse_update: bool = False, dt: float = 1.0):
     _check_tiling(tiling)
@@ -402,7 +531,7 @@ def advect_wide(u, v, w, p: AdvectParams, *, interpret: bool = True,
 
 
 def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
-                  *refs, X, Y, TY, S, T, dt):
+                  *refs, X, Y, TY, S, T, dt, align):
     """T stacked 3-slice rings: level k holds the step-k fields.
 
     At grid step (t, i) the newly-arrived input slice x=i of tile t's slab
@@ -418,14 +547,14 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
     same wall swallows the previous tile's stale ring content at each tile
     switch, so the ring needs no explicit per-tile reset.
 
-    `ym_ref` is the slab's row-interior mask (1.0 = the row's source may be
-    applied); all-ones reproduces the plain boundary behaviour, while the
-    distributed depth-T halo exchange passes its global-interior mask so
-    wrapped ppermute rows stay frozen walls. `xm_ref` is the per-slice
-    analogue for the x dimension: slice j's sources are applied only when
-    xm[j] is nonzero, so a 2D (x, y) decomposition can freeze wrapped
-    x-halo planes the same way (the slab-edge wall at j=0 / j=X-1 stays
-    structural either way).
+    `ym_ref` is the slab's ``(S, 1)`` row-interior mask (1.0 = the row's
+    source may be applied); all-ones reproduces the plain boundary
+    behaviour, while the distributed depth-T halo exchange passes its
+    global-interior mask so wrapped ppermute rows stay frozen walls.
+    `xm_ref` is the ``(X, 1)`` per-slice analogue for the x dimension:
+    slice j's sources are applied only when xm[j] is nonzero, so a 2D
+    (x, y) decomposition can freeze wrapped x-halo planes the same way
+    (the slab-edge wall at j=0 / j=X-1 stays structural either way).
 
     The finite guard deliberately does NOT live in this kernel: probing
     the output slice with `isfinite` inside the loop body changes the
@@ -434,12 +563,14 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
     below — so this kernel's outputs stay bitwise-identical whether or
     not the caller asked for guarding.
     """
-    ou_ref, ov_ref, ow_ref, ubuf, vbuf, wbuf = refs
+    ou_ref, ov_ref, ow_ref, ubuf, vbuf, wbuf = refs[:6]
+    obuf = refs[6] if len(refs) > 6 else None
     t = pl.program_id(0)
     i = pl.program_id(1)
     slot = jax.lax.rem(i, 3)
     m, c = jax.lax.rem(i + 1, 3), jax.lax.rem(i + 2, 3)
-    row_ok = (ym_ref[...] > 0.0)[:, None]
+    row_ok = ym_ref[...] > 0.0
+    coeffs = _coeffs(t1_ref, t2_ref)
     for buf, ref in ((ubuf, u_ref), (vbuf, v_ref), (wbuf, w_ref)):
         buf[0, slot] = ref[0]
     outs = None
@@ -448,10 +579,8 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
         args = [ubuf[k - 1, m], ubuf[k - 1, c], ubuf[k - 1, slot],
                 vbuf[k - 1, m], vbuf[k - 1, c], vbuf[k - 1, slot],
                 wbuf[k - 1, m], wbuf[k - 1, c], wbuf[k - 1, slot]]
-        su, sv, sw = _source_slices(*args, 0.0 + t1_ref[0], t1_ref[1],
-                                    t1_ref[2:], t2_ref[2:])
-        x_ok = xm_ref[pl.ds(jnp.clip(j, 0, X - 1), 1)][0] > 0.0
-        interior = (j >= 1) & (j <= X - 2) & x_ok
+        su, sv, sw = _source_slices(*args, *coeffs)
+        interior = (j >= 1) & (j <= X - 2) & _plane_ok(xm_ref, j, X)
         new = []
         for cen, s in ((args[1], su), (args[4], sv), (args[7], sw)):
             src = jnp.where(interior & row_ok, _pad_edges(s),
@@ -461,22 +590,23 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
             ubuf[k, slot], vbuf[k, slot], wbuf[k, slot] = new
         else:
             outs = new
-    start = _own_start(t, Y, TY, S, T)
-    for ref, val in zip((ou_ref, ov_ref, ow_ref), outs):
-        ref[0] = jax.lax.dynamic_slice(val, (start, 0), (TY, val.shape[1]))
+    _write_owned(zip((ou_ref, ov_ref, ow_ref), outs), obuf,
+                 _own_start(t, Y, TY, S, align), TY)
 
 
 def _kernel_finite_guard(u_ref, v_ref, w_ref, gf_ref):
     """Per-x-slice finite-guard: flag = 1.0 iff the (Y, Z) slice of all
     three fields is entirely finite. One grid step per x-slice keeps the
-    VMEM working set at 3*Y*Z words regardless of X."""
+    VMEM working set at 3*Y*Z words regardless of X; the flags live in
+    SMEM (one scalar word per slice — a rank-1 VMEM block of one word is
+    not a layout Mosaic takes)."""
     ok = jnp.float32(1.0)
     for ref in (u_ref, v_ref, w_ref):
         ok = ok * jnp.all(jnp.isfinite(ref[0])).astype(jnp.float32)
-    gf_ref[0] = ok
+    gf_ref[pl.program_id(0)] = ok
 
 
-def finite_guard(u, v, w, *, interpret: bool = True):
+def finite_guard(u, v, w, *, interpret: Optional[bool] = None):
     """Scan the three fields for non-finite cells in ONE extra read pass.
 
     Returns f32 flags of shape ``(X,)``: ``flags[i] == 1.0`` iff x-slice
@@ -496,14 +626,15 @@ def finite_guard(u, v, w, *, interpret: bool = True):
         _kernel_finite_guard,
         grid=(X,),
         in_specs=[pl.BlockSpec((1, Y, Z), lambda i: (i, 0, 0))] * 3,
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((X,), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret, u),
     )(u, v, w)
 
 
 def advect_fused(u, v, w, p: AdvectParams, *, T: int = 4, dt: float = 1.0,
-                 interpret: bool = True, y_tile: int | None = None,
+                 interpret: Optional[bool] = None,
+                 y_tile: int | None = None,
                  tiling: str = "grid", y_interior_mask=None,
                  x_interior_mask=None, guard: bool = False):
     """v4: advance the fields T explicit-Euler steps in ONE HBM pass.
@@ -533,6 +664,7 @@ def advect_fused(u, v, w, p: AdvectParams, *, T: int = 4, dt: float = 1.0,
         raise ValueError(f"T must be >= 1, got {T}")
     _check_tiling(tiling)
     _check_y_tile(y_tile)
+    interpret = resolve_interpret(interpret, u)
     X, Y, Z = u.shape
     if tiling == "host" and y_tile is not None and y_tile < Y:
         if y_interior_mask is not None or x_interior_mask is not None:
@@ -544,39 +676,24 @@ def advect_fused(u, v, w, p: AdvectParams, *, T: int = 4, dt: float = 1.0,
         if guard:
             return ou, ov, ow, finite_guard(ou, ov, ow, interpret=interpret)
         return ou, ov, ow
-    TY, S, n_ty = _grid_geometry(Y, y_tile, T)
-    ym = (jnp.ones((Y,), jnp.float32) if y_interior_mask is None
-          else jnp.asarray(y_interior_mask, jnp.float32))
-    if ym.shape != (Y,):
-        raise ValueError(f"y_interior_mask must have shape ({Y},), "
-                         f"got {ym.shape}")
-    xm = (jnp.ones((X,), jnp.float32) if x_interior_mask is None
-          else jnp.asarray(x_interior_mask, jnp.float32))
-    if xm.shape != (X,):
-        raise ValueError(f"x_interior_mask must have shape ({X},), "
-                         f"got {xm.shape}")
-    in_spec = pl.BlockSpec((1, S, Z),
-                           lambda t, i: (jnp.minimum(i, X - 1),
-                                         _slab_lo(t, Y, TY, S, T), 0),
-                           indexing_mode=pl.Unblocked())
-    out_spec = pl.BlockSpec((1, TY, Z),
-                            lambda t, i: (jnp.clip(i - T, 0, X - 1),
-                                          _out_lo(t, Y, TY), 0),
-                            indexing_mode=pl.Unblocked())
-    ym_spec = pl.BlockSpec((S,), lambda t, i: (_slab_lo(t, Y, TY, S, T),),
-                           indexing_mode=pl.Unblocked())
-    xm_spec = pl.BlockSpec((X,), lambda t, i: (0,))
-    t1 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc1])
-    t2 = jnp.concatenate([p.tcx[None], p.tcy[None], p.tzc2])
-    tz_spec = pl.BlockSpec((Z + 2,), lambda t, i: (0,))
+    align = 1 if interpret else _SUBLANE
+    TY, S, n_ty = _grid_geometry(Y, y_tile, T, align)
+    xm, ym = _masks(x_interior_mask, y_interior_mask, X, Y)
+    in_spec, out_spec, ym_spec = _slab_specs(X, Y, Z, TY, S, T, align)
+    xm_spec = pl.BlockSpec((X, 1), lambda t, i: (0, 0))
+    t1, t2 = _pack_coeffs(p)
+    tz_spec = pl.BlockSpec(t1.shape, lambda t, i: (0, 0))
     fn = pl.pallas_call(
-        functools.partial(_kernel_fused, X=X, Y=Y, TY=TY, S=S, T=T, dt=dt),
+        functools.partial(_kernel_fused, X=X, Y=Y, TY=TY, S=S, T=T, dt=dt,
+                          align=align),
         grid=(n_ty, X + T),
         in_specs=[tz_spec, tz_spec, xm_spec, ym_spec,
                   in_spec, in_spec, in_spec],
         out_specs=[out_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((X, Y, Z), u.dtype)] * 3,
-        scratch_shapes=[pltpu.VMEM((T, 3, S, Z), u.dtype) for _ in range(3)],
+        scratch_shapes=([pltpu.VMEM((T, 3, S, Z), u.dtype)
+                         for _ in range(3)]
+                        + _out_scratch(TY, S, Z, u.dtype)),
         interpret=interpret,
     )
     ou, ov, ow = fn(t1, t2, xm, ym, u, v, w)
@@ -600,7 +717,8 @@ def _batch_axis(leaf, base_ndim: int):
 
 
 def advect_fused_batched(u, v, w, p, *, T: int = 4, dt: float = 1.0,
-                         interpret: bool = True, y_tile: int | None = None,
+                         interpret: Optional[bool] = None,
+                         y_tile: int | None = None,
                          tiling: str = "grid", y_interior_mask=None,
                          x_interior_mask=None, guard: bool = False):
     """Batched mega-launch: advance B independent (X, Y, Z) domains with
@@ -633,11 +751,12 @@ def advect_fused_batched(u, v, w, p, *, T: int = 4, dt: float = 1.0,
     `guard=True` additionally returns slot-stacked finite-guard flags
     ``(B, X)`` (see `finite_guard`): ``flags[b].min() > 0`` iff slot b's
     advanced fields are entirely finite — the serving engine's per-slot
-    quarantine signal, one extra vmapped guard pass over the mega-
-    launch's outputs that leaves the field outputs bitwise-identical to
-    an unguarded call. The flag output is rank 2, so the main kernel's
-    `count_pallas_hbm_bytes` is unchanged; `count_guard_bytes` isolates
-    the guard pass's traffic, == `guard_bytes_model(batch=B)`.
+    quarantine signal, one extra guard pass over the mega-launch's
+    outputs (viewed as B*X slices) that leaves the field outputs
+    bitwise-identical to an unguarded call. The flag output is rank 1,
+    so the main kernel's `count_pallas_hbm_bytes` is unchanged;
+    `count_guard_bytes` isolates the guard pass's traffic,
+    == `guard_bytes_model(batch=B)`.
     """
     for name, f in (("u", u), ("v", v), ("w", w)):
         if f.ndim != 4:
@@ -647,6 +766,7 @@ def advect_fused_batched(u, v, w, p, *, T: int = 4, dt: float = 1.0,
         raise ValueError(f"field shapes differ: {u.shape} {v.shape} "
                          f"{w.shape}")
     B, X, Y, Z = u.shape
+    interpret = resolve_interpret(interpret, u)
     p_axes = AdvectParams(_batch_axis(p.tcx, 0), _batch_axis(p.tcy, 0),
                           _batch_axis(p.tzc1, 1), _batch_axis(p.tzc2, 1))
     xm = (jnp.ones((X,), jnp.float32) if x_interior_mask is None
@@ -658,11 +778,15 @@ def advect_fused_batched(u, v, w, p, *, T: int = 4, dt: float = 1.0,
     def one(uu, vv, ww, pp, xmm, ymm):
         return advect_fused(uu, vv, ww, pp, T=T, dt=dt, interpret=interpret,
                             y_tile=y_tile, tiling=tiling,
-                            y_interior_mask=ymm, x_interior_mask=xmm,
-                            guard=guard)
+                            y_interior_mask=ymm, x_interior_mask=xmm)
 
-    return jax.vmap(one, in_axes=(0, 0, 0, p_axes, xm_ax, ym_ax))(
+    ou, ov, ow = jax.vmap(one, in_axes=(0, 0, 0, p_axes, xm_ax, ym_ax))(
         u, v, w, p, xm, ym)
+    if not guard:
+        return ou, ov, ow
+    flat = [f.reshape(B * X, Y, Z) for f in (ou, ov, ow)]
+    flags = finite_guard(*flat, interpret=interpret).reshape(B, X)
+    return ou, ov, ow, flags
 
 
 # ---------------------------------------------------------------------------
@@ -675,7 +799,7 @@ def _pad_r(s, r: int):
 
 
 def _kernel_stencil_fused(*refs, X, Y, TY, S, T, dt, n_fields, n_params,
-                          radius, stages, source):
+                          radius, stages, source, align):
     """Generalised temporal-blocking ring: `stages*T` stacked levels of
     `2*radius+1` slots per field, driven by a StencilSpec's source callback.
 
@@ -701,7 +825,8 @@ def _kernel_stencil_fused(*refs, X, Y, TY, S, T, dt, n_fields, n_params,
     xm_ref, ym_ref = refs[P], refs[P + 1]
     f_refs = refs[P + 2:P + 2 + F]
     out_refs = refs[P + 2 + F:P + 2 + 2 * F]
-    bufs = refs[P + 2 + 2 * F:]
+    bufs = refs[P + 2 + 2 * F:P + 2 + 3 * F]
+    obuf = refs[P + 2 + 3 * F] if len(refs) > P + 2 + 3 * F else None
     r = radius
     W = 2 * r + 1
     L = stages * T
@@ -709,7 +834,7 @@ def _kernel_stencil_fused(*refs, X, Y, TY, S, T, dt, n_fields, n_params,
     t = pl.program_id(0)
     i = pl.program_id(1)
     pv = tuple(pr[...] for pr in p_refs)
-    row_ok = (ym_ref[...] > 0.0)[:, None]
+    row_ok = ym_ref[...] > 0.0
     slot = jax.lax.rem(i, W)
     for buf, ref in zip(bufs, f_refs):
         buf[0, slot] = ref[0]
@@ -725,8 +850,7 @@ def _kernel_stencil_fused(*refs, X, Y, TY, S, T, dt, n_fields, n_params,
             return v[r + dj:v.shape[0] - r + dj, r + dk:v.shape[1] - r + dk]
 
         srcs = source(sh, pv)
-        x_ok = xm_ref[pl.ds(jnp.clip(j, 0, X - 1), 1)][0] > 0.0
-        interior = (j >= r) & (j <= X - 1 - r) & x_ok
+        interior = (j >= r) & (j <= X - 1 - r) & _plane_ok(xm_ref, j, X)
         cslot = jax.lax.rem(i + (W - r), W)
         half_level = stages == 2 and k % 2 == 1
         step_dt = 0.5 * dt if half_level else dt
@@ -744,13 +868,13 @@ def _kernel_stencil_fused(*refs, X, Y, TY, S, T, dt, n_fields, n_params,
                 bufs[fi][k, slot] = val
         else:
             outs = new
-    start = _own_start(t, Y, TY, S, D)
-    for ref, val in zip(out_refs, outs):
-        ref[0] = jax.lax.dynamic_slice(val, (start, 0), (TY, val.shape[1]))
+    _write_owned(zip(out_refs, outs), obuf, _own_start(t, Y, TY, S, align),
+                 TY)
 
 
 def stencil_fused(fields, params, spec, *, T: int = 4, dt: float = 1.0,
-                  interpret: bool = True, y_tile: int | None = None,
+                  interpret: Optional[bool] = None,
+                  y_tile: int | None = None,
                   y_interior_mask=None, x_interior_mask=None):
     """Spec-driven v4: advance a StencilSpec's fields T integrator steps in
     ONE HBM pass — the generalisation of `advect_fused` to any operator.
@@ -783,17 +907,10 @@ def stencil_fused(fields, params, spec, *, T: int = 4, dt: float = 1.0,
     r = spec.radius
     D = spec.halo(T)
     L = spec.stages * T
-    TY, S, n_ty = _grid_geometry(Y, y_tile, D)
-    ym = (jnp.ones((Y,), jnp.float32) if y_interior_mask is None
-          else jnp.asarray(y_interior_mask, jnp.float32))
-    if ym.shape != (Y,):
-        raise ValueError(f"y_interior_mask must have shape ({Y},), "
-                         f"got {ym.shape}")
-    xm = (jnp.ones((X,), jnp.float32) if x_interior_mask is None
-          else jnp.asarray(x_interior_mask, jnp.float32))
-    if xm.shape != (X,):
-        raise ValueError(f"x_interior_mask must have shape ({X},), "
-                         f"got {xm.shape}")
+    interpret = resolve_interpret(interpret, fields[0])
+    align = 1 if interpret else _SUBLANE
+    TY, S, n_ty = _grid_geometry(Y, y_tile, D, align)
+    xm, ym = _masks(x_interior_mask, y_interior_mask, X, Y)
     pv = tuple(spec.pack_params(params))
     for p in pv:
         if p.ndim != 1:
@@ -801,28 +918,21 @@ def stencil_fused(fields, params, spec, *, T: int = 4, dt: float = 1.0,
                 f"spec {spec.name!r}: pack_params must return 1-D vectors, "
                 f"got shape {p.shape}")
     p_specs = [pl.BlockSpec(p.shape, lambda t, i: (0,)) for p in pv]
-    in_spec = pl.BlockSpec((1, S, Z),
-                           lambda t, i: (jnp.minimum(i, X - 1),
-                                         _slab_lo(t, Y, TY, S, D), 0),
-                           indexing_mode=pl.Unblocked())
-    out_spec = pl.BlockSpec((1, TY, Z),
-                            lambda t, i: (jnp.clip(i - D, 0, X - 1),
-                                          _out_lo(t, Y, TY), 0),
-                            indexing_mode=pl.Unblocked())
-    ym_spec = pl.BlockSpec((S,), lambda t, i: (_slab_lo(t, Y, TY, S, D),),
-                           indexing_mode=pl.Unblocked())
-    xm_spec = pl.BlockSpec((X,), lambda t, i: (0,))
+    in_spec, out_spec, ym_spec = _slab_specs(X, Y, Z, TY, S, D, align)
+    xm_spec = pl.BlockSpec((X, 1), lambda t, i: (0, 0))
     fn = pl.pallas_call(
         functools.partial(_kernel_stencil_fused, X=X, Y=Y, TY=TY, S=S, T=T,
                           dt=dt, n_fields=spec.n_fields, n_params=len(pv),
-                          radius=r, stages=spec.stages, source=spec.source),
+                          radius=r, stages=spec.stages, source=spec.source,
+                          align=align),
         grid=(n_ty, X + D),
         in_specs=p_specs + [xm_spec, ym_spec] + [in_spec] * spec.n_fields,
         out_specs=[out_spec] * spec.n_fields,
         out_shape=[jax.ShapeDtypeStruct((X, Y, Z), fields[0].dtype)
                    ] * spec.n_fields,
-        scratch_shapes=[pltpu.VMEM((L, 2 * r + 1, S, Z), fields[0].dtype)
-                        for _ in range(spec.n_fields)],
+        scratch_shapes=([pltpu.VMEM((L, 2 * r + 1, S, Z), fields[0].dtype)
+                         for _ in range(spec.n_fields)]
+                        + _out_scratch(TY, S, Z, fields[0].dtype)),
         interpret=interpret,
     )
     out = fn(*pv, xm, ym, *fields)
@@ -830,7 +940,7 @@ def stencil_fused(fields, params, spec, *, T: int = 4, dt: float = 1.0,
 
 
 def stencil_fused_batched(fields, params, spec, *, T: int = 4,
-                          dt: float = 1.0, interpret: bool = True,
+                          dt: float = 1.0, interpret: Optional[bool] = None,
                           y_tile: int | None = None,
                           y_interior_mask=None, x_interior_mask=None):
     """Batched mega-launch of the spec kernel: B independent domains of any
@@ -920,50 +1030,49 @@ def band_checksum(band):
     return jnp.sum(bits, dtype=jnp.uint32).reshape((1,))
 
 
-def _band_slice(ref, dim: int, lo: int, size: int):
-    """`size` planes (dim=0) or rows (dim=1) of `ref` starting at `lo`."""
-    if dim == 0:
-        return ref.at[pl.ds(lo, size)]
-    return ref.at[:, pl.ds(lo, size)]
+def _band_messages(f, dim: int, sched):
+    """The boundary bands of `f` that one exchange sends, in
+    `_kernel_band_dma`'s message order: per `_band_schedule` hop, the
+    tail (to the successor's hi halo), then the head (to the
+    predecessor's lo halo). Each is viewed lane-dense, ``(rows, 128)``,
+    wherever its size allows: Mosaic slices an HBM ref only in whole
+    lane tiles, which a Z=64 minor dim is not."""
+    L = f.shape[dim]
+    out = []
+    for _, cnt, _, _ in sched:
+        for lo in (L - cnt, 0):
+            idx = [slice(None)] * f.ndim
+            idx[dim] = slice(lo, lo + cnt)
+            band = f[tuple(idx)]
+            lanes = 128 if band.size % 128 == 0 else band.shape[-1]
+            out.append((band.reshape(-1, lanes), band.shape))
+    return out
 
 
-def _halo_slice(ref, slot, dim: int, lo: int, size: int):
-    """`size` planes/rows of the `slot` recv slab of a double-buffered
-    `(2,) + band` output ref, starting at halo-local offset `lo`. `slot`
-    may be a traced value (the dynamic DMA parity)."""
-    if dim == 0:
-        return ref.at[slot, pl.ds(lo, size)]
-    return ref.at[slot, :, pl.ds(lo, size)]
-
-
-def _kernel_band_dma(step_ref, u_ref, v_ref, w_ref,
-                     uhi_ref, ulo_ref, vhi_ref, vlo_ref, whi_ref, wlo_ref,
-                     *scratch, axis, mesh_axes, n, depth, dim, L, sched):
+def _kernel_band_dma(step_ref, *refs, axis, mesh_axes, n, sched):
     """One depth-T band exchange along mesh axis `axis`, issued as async
     remote DMA from INSIDE the kernel — the paper's §IV move of the
     transfer schedule out of the tooling's hands and into the kernel's.
 
-    Per field, side and `_band_schedule` hop, a boundary band is staged
-    through a VMEM send slab (`make_async_copy`) and then
-    `make_async_remote_copy`'d into the k-away ring neighbour's
-    DOUBLE-BUFFERED recv slab, at the hop's `hi_off`/`lo_off` recv
-    offset (halo-local). All sends (3 fields x 2 sides x hops) are
-    started before any wait: the DMAs fly concurrently and the issue
-    order follows the fused ring's consumption order (the x-lo band
-    feeds the ring's earliest grid steps). The entry barrier is the
-    capacity handshake: every hop partner has entered this block's
-    exchange — and therefore vacated the slot being written — before any
-    band lands.
+    `refs` holds the send bands (3 fields x hops x 2 sides, in
+    `_band_messages` order, in HBM), then one double-buffered recv slab
+    per band (same order, in HBM), then the send and recv DMA
+    semaphores. Each band is `make_async_remote_copy`'d HBM to HBM into
+    the recv slab of the same message index on its k-away ring
+    neighbour: the tail lands in the successor's hi message, the head in
+    the predecessor's lo message. All sends start before any wait, so
+    the DMAs fly concurrently. The entry barrier is the capacity
+    handshake: every hop partner has entered this block's exchange —
+    and so holds the recv slabs being written — before any band lands.
 
     The recv slot is `step_ref[0] % 2` — a TRACED value read from SMEM,
     so a pipelined multi-block driver (`stencil.distributed.
     make_distributed_run`) threads the block counter through ONE traced
-    program and alternates parity without retracing: block k+1's bands
-    always have a vacant slot to land in while block k's interior
-    computes. Scope honesty: this call still waits all its DMAs before
-    returning, so realising that cross-block landing needs the driver's
-    ROADMAPped boundary-first continuation — what is delivered here is
-    the dynamic parity and the multi-hop schedule.
+    program and alternates parity without retracing. Scope honesty: this
+    call still waits all its DMAs before returning, so realising a
+    cross-block landing needs the driver's ROADMAPped boundary-first
+    continuation — what is delivered here is the dynamic parity and the
+    multi-hop schedule.
 
     The traffic is ring-symmetric (for every hop k, everyone sends its
     tail forward-k and its head backward-k), so each device's descriptor
@@ -972,8 +1081,9 @@ def _kernel_band_dma(step_ref, u_ref, v_ref, w_ref,
     signals.
     """
     hops = len(sched)
-    sbufs = scratch[:hops]
-    stage_sem, send_sem, recv_sem = scratch[hops:]
+    nb = 3 * 2 * hops
+    sends, recvs = refs[:nb], refs[nb:2 * nb]
+    send_sem, recv_sem = refs[2 * nb:]
     slot = jax.lax.rem(step_ref[0], 2)
     coords = [jax.lax.axis_index(a) for a in mesh_axes]
     barrier = pltpu.get_barrier_semaphore()
@@ -984,29 +1094,15 @@ def _kernel_band_dma(step_ref, u_ref, v_ref, w_ref,
                                    device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(barrier, 2 * hops)
     rdmas = []
-    for fi, (f_ref, hi_ref, lo_ref) in enumerate(
-            ((u_ref, uhi_ref, ulo_ref), (v_ref, vhi_ref, vlo_ref),
-             (w_ref, whi_ref, wlo_ref))):
-        # side 0: my tail -> the k-away successor's hi slab (it reads those
-        # planes/rows first); side 1: my head -> the k-away predecessor's
-        # lo slab. Offsets are `_band_schedule`'s, rebased halo-local.
-        for hk, (k, cnt, hi_off, lo_off) in enumerate(sched):
-            fwd = dma_neighbor_coords(mesh_axes, coords, axis, k, n)
-            bwd = dma_neighbor_coords(mesh_axes, coords, axis, -k, n)
-            for si, (src_lo, dst_ref, dst_dev, dst_off) in enumerate(
-                    ((L - cnt, hi_ref, fwd, hi_off),
-                     (0, lo_ref, bwd, lo_off - (depth + L)))):
-                stage = pltpu.make_async_copy(
-                    _band_slice(f_ref, dim, src_lo, cnt),
-                    sbufs[hk].at[fi, si], stage_sem.at[fi, si, hk])
-                stage.start()
-                stage.wait()
+    for fi in range(3):
+        for hk, (k, _, _, _) in enumerate(sched):
+            for si, delta in enumerate((k, -k)):
+                b = (fi * hops + hk) * 2 + si
                 rdma = pltpu.make_async_remote_copy(
-                    src_ref=sbufs[hk].at[fi, si],
-                    dst_ref=_halo_slice(dst_ref, slot, dim, dst_off, cnt),
-                    send_sem=send_sem.at[fi, si, hk],
-                    recv_sem=recv_sem.at[fi, si, hk],
-                    device_id=dst_dev,
+                    src_ref=sends[b], dst_ref=recvs[b].at[slot],
+                    send_sem=send_sem.at[b], recv_sem=recv_sem.at[b],
+                    device_id=dma_neighbor_coords(mesh_axes, coords, axis,
+                                                  delta, n),
                     device_id_type=pltpu.DeviceIdType.MESH)
                 rdma.start()
                 rdmas.append(rdma)
@@ -1030,9 +1126,9 @@ def halo_band_exchange_dma(u, v, w, *, axis: str, mesh_axes, n: int,
     the x-then-y corner ordering are engine-independent. Multi-hop: when
     `depth` exceeds the local extent, `_band_schedule` splits each side
     into ceil(depth/L) band messages and the kernel issues one
-    `make_async_remote_copy` per (field, side, hop), each landing at its
-    schedule recv offset, so arbitrarily deep halos move without falling
-    back to the collective engine (the caller still bounds
+    `make_async_remote_copy` per (field, side, hop); the hops' bands are
+    joined in global order here, so arbitrarily deep halos move without
+    falling back to the collective engine (the caller still bounds
     T <= global extent - 2 — past that no interior cell exists whose
     cone the ring can serve).
 
@@ -1048,44 +1144,39 @@ def halo_band_exchange_dma(u, v, w, *, axis: str, mesh_axes, n: int,
         raise ValueError(f"dim must be 0 (x-planes) or 1 (y-rows), got {dim}")
     if depth < 1:
         raise ValueError(f"depth must be >= 1, got {depth}")
-    L = u.shape[dim]
-    sched = _band_schedule(L, depth)
-    band_shape = ((depth,) + u.shape[1:] if dim == 0
-                  else (u.shape[0], depth) + u.shape[2:])
-
-    def stage_shape(cnt):
-        return ((cnt,) + u.shape[1:] if dim == 0
-                else (u.shape[0], cnt) + u.shape[2:])
-
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    out_shape = [jax.ShapeDtypeStruct((2,) + band_shape, u.dtype)
-                 for _ in range(6)]
+    sched = _band_schedule(u.shape[dim], depth)
+    msgs = [m for f in (u, v, w) for m in _band_messages(f, dim, sched)]
+    sends = [band for band, _ in msgs]
+    nb = len(sends)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     fn = pl.pallas_call(
         functools.partial(_kernel_band_dma, axis=axis,
-                          mesh_axes=tuple(mesh_axes), n=n, depth=depth,
-                          dim=dim, L=L, sched=tuple(sched)),
-        in_specs=[smem_spec, any_spec, any_spec, any_spec],
-        out_specs=[any_spec] * 6,
-        out_shape=out_shape,
-        scratch_shapes=(
-            # one staged-send slab per hop, sized to that hop's band
-            [pltpu.VMEM((3, 2) + stage_shape(cnt), u.dtype)
-             for _, cnt, _, _ in sched]
-            + [pltpu.SemaphoreType.DMA((3, 2, len(sched))),  # staging
-               pltpu.SemaphoreType.DMA((3, 2, len(sched))),  # remote send
-               pltpu.SemaphoreType.DMA((3, 2, len(sched)))]  # remote recv
-        ),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=collective_id),
+                          mesh_axes=tuple(mesh_axes), n=n,
+                          sched=tuple(sched)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [any_spec] * nb,
+        out_specs=[any_spec] * nb,
+        out_shape=[jax.ShapeDtypeStruct((2,) + b.shape, b.dtype)
+                   for b in sends],
+        scratch_shapes=[pltpu.SemaphoreType.DMA((nb,)),    # remote send
+                        pltpu.SemaphoreType.DMA((nb,))],   # remote recv
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
     )
     block = jnp.asarray(block_index, jnp.int32)
-    outs = fn(block.reshape((1,)), u, v, w)
+    outs = fn(block.reshape((1,)), *sends)
     # dynamic parity: traced block counters (the pipelined driver's
     # fori_loop induction variable) select the recv slot without retracing
     slot = jax.lax.rem(block, 2)
-    sel = [jax.lax.dynamic_index_in_dim(o, slot, 0, keepdims=False)
-           for o in outs]
-    return ((sel[0], sel[1]), (sel[2], sel[3]), (sel[4], sel[5]))
+    got = [jax.lax.dynamic_index_in_dim(o, slot, 0, keepdims=False)
+           .reshape(shape) for o, (_, shape) in zip(outs, msgs)]
+    hops = len(sched)
+    bands = []
+    for fi in range(3):
+        msg = got[fi * 2 * hops:(fi + 1) * 2 * hops]
+        his, los = msg[0::2], msg[1::2]
+        # hi: farthest predecessor first so global coordinates ascend
+        bands.append((jnp.concatenate(his[::-1], axis=dim),
+                      jnp.concatenate(los, axis=dim)))
+    return tuple(bands)
 
 
 # ---------------------------------------------------------------------------
@@ -1116,27 +1207,6 @@ def fused_register_bytes(T: int, y_rows: int, Z: int, itemsize: int = 4,
     levels = T if n_levels is None else n_levels
     rows = y_rows if y_tile is None else min(y_tile + 2 * h, y_rows)
     return n_fields * (n_slots * levels) * rows * Z * itemsize
-
-
-def dma_slab_bytes(shape, depth: int, dim: int, itemsize: int = 4, *,
-                   n_fields: int = 3) -> tuple[int, int]:
-    """Static sizes of the remote-DMA exchange's on-chip slabs for one
-    phase over a `shape` shard: ``(staged_send, recv)`` bytes, exactly
-    the scratch/out shapes `halo_band_exchange_dma` declares —
-    per-hop ``(n_fields, 2 sides) x stage_shape(cnt)`` VMEM staging
-    slabs (the hop band counts partition `depth`, so the sum is
-    depth-exact regardless of hop count) and ``n_fields x 2 sides x
-    2 recv slots`` of the full depth band. The analysis layer's
-    `vmem.distributed_block_plan` budgets these against
-    `roofline.VMEM_PER_CORE` before anything compiles."""
-    other = 1
-    for d, s in enumerate(shape):
-        if d != dim:
-            other *= s
-    staged = sum(n_fields * 2 * cnt * other * itemsize
-                 for _, cnt, _, _ in _band_schedule(shape[dim], depth))
-    recv = n_fields * 2 * 2 * depth * other * itemsize
-    return staged, recv
 
 
 def _n_y_tiles(Y: int, y_tile: int | None) -> int:

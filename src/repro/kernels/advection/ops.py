@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the PW-advection kernel ladder.
 
 `pw_advect(..., variant=...)` selects the Fig. 3 rung; `interpret` toggles
-Pallas interpret mode (CPU validation) vs compiled TPU execution. `y_tile`
+Pallas interpret mode (CPU validation) vs compiled TPU execution, and
+defaults to compiled on a TPU backend, interpret elsewhere. `y_tile`
 runs the in-grid 2D `(y_tile, x)` tiling by default (`tiling="grid"`, one
 kernel launch, no HBM halo restaging); `tiling="host"` keeps the retained
 per-block host loop for comparison. `fuse_update=True` makes the v1-v3
@@ -32,7 +33,7 @@ VARIANTS = {
 @functools.partial(jax.jit, static_argnames=("variant", "interpret", "y_tile",
                                              "tiling", "fuse_update", "dt"))
 def pw_advect(u, v, w, params: REF.AdvectParams, *, variant: str = "dataflow",
-              interpret: bool = True,
+              interpret: Optional[bool] = None,
               y_tile: Optional[int] = None,
               tiling: str = "grid",
               fuse_update: bool = False,
@@ -56,7 +57,7 @@ def pw_advect(u, v, w, params: REF.AdvectParams, *, variant: str = "dataflow",
                    static_argnames=("T", "dt", "interpret", "y_tile",
                                     "tiling"))
 def pw_advect_fused(u, v, w, params: REF.AdvectParams, *, T: int = 4,
-                    dt: float = 1.0, interpret: bool = True,
+                    dt: float = 1.0, interpret: Optional[bool] = None,
                     y_tile: Optional[int] = None,
                     tiling: str = "grid"
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:

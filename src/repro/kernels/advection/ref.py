@@ -82,7 +82,7 @@ def _with_f64(fn, fields, p: AdvectParams):
     """
     f_np = [np.asarray(t, np.float64) for t in fields]
     p_np = [np.asarray(t, np.float64) for t in p]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f64 = [jnp.asarray(t) for t in f_np]
         p64 = AdvectParams(*(jnp.asarray(t) for t in p_np))
         return fn(*f64, p64)

@@ -6,20 +6,15 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions.
-
-    `axis_types` (and `jax.sharding.AxisType`) only exist on newer jax; the
-    pinned 0.4.x simply has no explicit/auto axis distinction, so omitting
-    the kwarg there is semantically identical to Auto everywhere.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def _auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis Auto: the drivers here place arrays
+    with `NamedSharding` / `with_sharding_constraint` and run `shard_map`,
+    which assume Auto axes rather than the Explicit default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_stencil_mesh(nx: int, ny: int, *, x_axis: str = "x",
@@ -27,8 +22,10 @@ def make_stencil_mesh(nx: int, ny: int, *, x_axis: str = "x",
     """(nx, ny) device mesh for the 2D-decomposed stencil step: each shard
     owns an (X/nx, Y/ny, Z) slab under
     `stencil.distributed.make_distributed_step(axis=y_axis, x_axis=x_axis)`.
+    The mesh takes the first nx*ny of this process's devices.
     """
-    return compat_make_mesh((nx, ny), (x_axis, y_axis))
+    return _auto_mesh((nx, ny), (x_axis, y_axis),
+                      devices=jax.devices()[:nx * ny])
 
 
 def ring_neighbor(idx, n: int, delta: int):
@@ -85,14 +82,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
     """Whatever this host offers (smoke tests / examples on CPU)."""
     n = len(jax.devices())
     data = n // model
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def tp_degree(mesh) -> int:

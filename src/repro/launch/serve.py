@@ -15,6 +15,10 @@ recovery counters print as the health surface:
 
 `--lose-device-at` is the DEPRECATED single-fault alias — it builds a
 one-device-loss plan.
+
+On a TPU the forecast jobs run the compiled fused kernel (interpret mode
+only where JAX has no TPU), and compiled programs persist in the cache
+`launch.compile_cache` chooses.
 """
 from __future__ import annotations
 
@@ -33,12 +37,19 @@ from repro.training import step as TS
 
 
 def _run_stencil(args) -> None:
+    from repro.kernels.advection.advection import resolve_interpret
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.stencil_engine import (StencilRequest,
                                               StencilServingEngine)
     from repro.stencil.advection import AdvectionDomain, stratus_fields
 
     from repro.serving.faults import Fault, FaultPlan
 
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    mode = "interpret" if resolve_interpret() else "compiled"
+    print(f"[serve] device {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}, fused kernel {mode}")
     X, Y, Z, T = (12, 16, 64, 2) if args.smoke else (64, 256, 64, 4)
     dom = AdvectionDomain(X, Y, Z, variant="fused", fuse_T=T, dt=0.005)
     plan = None

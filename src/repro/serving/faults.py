@@ -73,7 +73,7 @@ _MODES = ("nan", "inf")
 _COUNTERS = ("faults_injected", "faults_skipped", "device_losses",
              "quarantines", "rollbacks", "retries", "degradations",
              "reshards", "cache_evictions", "snapshots",
-             "replayed_blocks")
+             "replayed_blocks", "unverified_blocks")
 
 
 class ExchangeStalled(RuntimeError):
@@ -442,9 +442,9 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
                               T: int = 1, dt: float = 1.0,
                               axis: str = "data",
                               x_axis: Optional[str] = None,
-                              local_kernel: str = "reference",
+                              local_kernel: Optional[str] = None,
                               y_tile: Optional[int] = None,
-                              interpret: bool = True,
+                              interpret: Optional[bool] = None,
                               injector: Optional[FaultInjector] = None,
                               ladder: Optional[DegradationLadder] = None,
                               max_retries: int = 3,
@@ -471,9 +471,9 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
         rung halves the y-shard count instead of exhausting.
       * halo_corruption  — a band of the faulted field is damaged ON THE
         WIRE for that block (`corrupt_halo` in the emulated engines);
-        the checksummed exchange (`verify_integrity`, default on in
-        interpret mode) flags it and the driver rolls back to the last
-        checkpoint and replays — bounded: `replayed_blocks` <=
+        the checksummed exchange (`verify_integrity`, default on wherever
+        the block's engine carries checksum words) flags it and the
+        driver rolls back to the last checkpoint and replays — bounded: `replayed_blocks` <=
         `rollbacks * checkpoint_every`. On a 1-shard mesh there is no
         wire, so the damage lands on the slab edge rows the band would
         have been (still injected, never skipped).
@@ -506,6 +506,12 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
     the step parity alternates with the block index, it is never pinned
     to slot 0). Returns ``(u, v, w), injector`` so callers can assert on
     `health()`.
+
+    `interpret` and `local_kernel` default from the mesh as in
+    `make_distributed_step`. `verify_integrity=None` verifies every block
+    whose exchange engine carries checksum words (both ppermute
+    transports; not the compiled remote-DMA kernel), and each block that
+    runs without verification counts in `health()["unverified_blocks"]`.
     """
     import jax.numpy as jnp
 
@@ -523,7 +529,12 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
                          f"got {checkpoint_every}")
     if max_replays < 0:
         raise ValueError(f"max_replays must be >= 0, got {max_replays}")
-    verify = interpret if verify_integrity is None else verify_integrity
+    interpret, local_kernel = D.resolve_modes(mesh, interpret, local_kernel)
+
+    def verify_for(rung_):
+        """Whether blocks on exchange `rung_` run checksummed."""
+        carries = D.carries_checksums(rung_, interpret)
+        return carries if verify_integrity is None else verify_integrity
 
     X, Y, _ = np.shape(u)
     n_y = mesh.shape[axis]
@@ -538,7 +549,7 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
             cur_mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
             local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
             exchange=rng_, dma_block_index=parity,
-            verify_integrity=verify, corrupt_halo=corrupt)
+            verify_integrity=verify_for(rng_), corrupt_halo=corrupt)
 
     def get_step(parity, corrupt):
         if corrupt is not None:           # one-off, never cached
@@ -670,10 +681,11 @@ def resilient_distributed_run(mesh, params, u, v, w, *, n_blocks: int,
                 else:
                     rung = nxt
 
-        if verify:
+        if verify_for(rung):
             cand, flags = out[:3], out[3]
         else:
             cand, flags = out, None
+            injector.record("unverified_blocks")
 
         bad = None
         if flags is not None and int(np.sum(np.asarray(flags))) > 0:

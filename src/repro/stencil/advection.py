@@ -35,19 +35,33 @@ PAPER_GRIDS = {
 }
 
 
-def stratus_fields(X: int, Y: int, Z: int, seed: int = 0,
-                   dtype=jnp.float32) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Smooth, divergence-ish wind fields standing in for the stratus case."""
+def _stratus_f64(X: int, Y: int, Z: int, seed: int):
+    """Yield the stratus (u, v, w) in float64, one at a time (a 268M-cell
+    field is 2 GiB at this precision)."""
     rng = np.random.default_rng(seed)
     kx = np.linspace(0, 2 * np.pi, X)[:, None, None]
     ky = np.linspace(0, 2 * np.pi, Y)[None, :, None]
     kz = np.linspace(0, np.pi, Z)[None, None, :]
-    u = 5.0 * np.sin(kx + 0.5) * np.cos(ky) * np.sin(kz + 0.1)
-    v = 4.0 * np.cos(kx) * np.sin(ky + 0.3) * np.sin(kz)
-    w = 0.5 * np.sin(kx) * np.sin(ky) * np.cos(kz)
-    for f in (u, v, w):
+    for smooth in (lambda: 5.0 * np.sin(kx + 0.5) * np.cos(ky)
+                   * np.sin(kz + 0.1),
+                   lambda: 4.0 * np.cos(kx) * np.sin(ky + 0.3) * np.sin(kz),
+                   lambda: 0.5 * np.sin(kx) * np.sin(ky) * np.cos(kz)):
+        f = smooth()
         f += 0.01 * rng.normal(size=f.shape)
-    return tuple(jnp.asarray(f, dtype) for f in (u, v, w))
+        yield f
+
+
+def stratus_fields(X: int, Y: int, Z: int, seed: int = 0,
+                   dtype=jnp.float32) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Smooth, divergence-ish wind fields standing in for the stratus case."""
+    return tuple(jnp.asarray(f, dtype) for f in _stratus_f64(X, Y, Z, seed))
+
+
+def stratus_fields_host(X: int, Y: int, Z: int, seed: int = 0,
+                        dtype=np.float32):
+    """`stratus_fields` as host numpy arrays, for placing a grid straight
+    into a sharding or layout with `jax.device_put`."""
+    return tuple(np.asarray(f, dtype) for f in _stratus_f64(X, Y, Z, seed))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,7 +72,7 @@ class AdvectionDomain:
     Y: int
     Z: int
     variant: str = "dataflow"
-    interpret: bool = True
+    interpret: Optional[bool] = None  # None: compiled on TPU, else interpret
     dtype: str = "float32"
     fuse_T: int = 4                   # fused (v4): Euler steps per HBM pass
     y_tile: Optional[int] = None      # y-tiles (VMEM-bounded register)

@@ -64,12 +64,12 @@ exchange with the kernel's in-grid `(y_tile, x)` tiling: the shard's slab
 streams through ONE kernel launch whose VMEM register is bounded by
 `y_tile` while the wrapped (periodic) halo rows/planes are frozen via the
 kernel's `(x_interior_mask, y_interior_mask)` — the same global-interior
-masks the reference loop applies per substep. Because `pallas_call` has no
-shard_map replication rule on the pinned jax, any step using a Pallas
-kernel per shard is built with ``check_rep=False``: outputs are fully
-sharded along the mesh axes anyway so no replication information is lost,
-but shard_map will no longer error if a future edit accidentally consumes
-an unreduced value — the distributed equivalence tests are the guard.
+masks the reference loop applies per substep. A `pallas_call` declares no
+varying-across-mesh-axes (vma) type for its outputs, so any step using a
+Pallas kernel per shard is built with ``check_vma=False``: outputs are
+fully sharded along the mesh axes anyway so no information is lost, but
+shard_map will no longer error if a future edit accidentally consumes an
+unreduced value — the distributed equivalence tests are the guard.
 
 `overlap=True` splits each shard's update into an interior pass (owned
 slab only — no data dependence on any exchange, the §IV DMA/compute
@@ -92,7 +92,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.advection import advection as K
@@ -140,7 +139,7 @@ def _corrupt_band(g, dim: int, rows: int, value: float):
     return g.at[tuple(idx)].set(value)
 
 
-def _exchange_halos(f, axis: str, n: int, depth: int = 1, dim: int = 1,
+def _exchange_halos(f, axis: str, depth: int = 1, dim: int = 1,
                     *, integrity_out=None, corrupt=None):
     """Fetch `depth` rows (dim=1) or planes (dim=0) per side from the ring
     of shards on mesh axis `axis`. Returns (hi_from_prev, lo_from_next):
@@ -148,9 +147,8 @@ def _exchange_halos(f, axis: str, n: int, depth: int = 1, dim: int = 1,
     lo = the `depth` rows just above it (heads of my successors).
 
     Multi-hop: when `depth` exceeds the local extent L, hop k (a
-    distance-k ppermute with a static pair table — `n` is passed in
-    because `jax.lax.axis_size` does not exist on the pinned jax) fetches
-    the k-away neighbour's share directly: hop 1 moves min(L, depth) rows,
+    distance-k ppermute with a static pair table) fetches the k-away
+    neighbour's share directly: hop 1 moves min(L, depth) rows,
     hop k moves min(L, depth-(k-1)L), so ceil(depth/L) permutes per side
     carry exactly `depth` rows total — bytes-on-wire are hop-count
     independent. The ring is periodic; rows that wrap past the global
@@ -167,6 +165,7 @@ def _exchange_halos(f, axis: str, n: int, depth: int = 1, dim: int = 1,
     wire corruption would.
     """
     L = f.shape[dim]
+    n = jax.lax.axis_size(axis)
     hops = -(-depth // L)
 
     def part(g, lo, hi):
@@ -203,7 +202,7 @@ def _exchange_halos(f, axis: str, n: int, depth: int = 1, dim: int = 1,
             jnp.concatenate(lo_parts, axis=dim))
 
 
-def _exchange_remote_dma_emulated(f, axis: str, n: int, depth: int,
+def _exchange_remote_dma_emulated(f, axis: str, depth: int,
                                   dim: int, *, integrity_out=None,
                                   corrupt=None):
     """Interpret-mode transport for the `remote_dma` engine: the DMA
@@ -222,6 +221,7 @@ def _exchange_remote_dma_emulated(f, axis: str, n: int, depth: int,
     after the (optional) injected wire damage to the hop-1 hi band.
     """
     L = f.shape[dim]
+    n = jax.lax.axis_size(axis)
 
     def band(g, lo, hi):
         idx = [slice(None)] * g.ndim
@@ -290,17 +290,15 @@ def make_distributed_advect(mesh: Mesh, params: AdvectParams,
     x-then-y phases and the exchange engines via `make_distributed_step`.
     """
 
-    n_shards = mesh.shape[axis]
-
     def local(u, v, w):
         """Per-shard: exchange halos, compute interior meanwhile, patch edges."""
         # 1) launch halo exchange (6 edge planes, tiny vs the slab)
-        halos = [_exchange_halos(f, axis, n_shards) for f in (u, v, w)]
+        halos = [_exchange_halos(f, axis) for f in (u, v, w)]
         # 2) interior compute — no dependence on `halos`, so XLA overlaps the
         #    collective-permutes with this stencil (the §IV overlap on ICI)
         interior = pw_advect_ref(u, v, w, params)
         # 3) boundary patch: rebuild the two edge y-bands with halo rows
-        n = n_shards
+        n = mesh.shape[axis]
         idx = jax.lax.axis_index(axis)
 
         def with_halo(f, h):
@@ -325,13 +323,24 @@ def make_distributed_advect(mesh: Mesh, params: AdvectParams,
         return tuple(out)
 
     spec = P(None, axis, None)
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=(spec, spec, spec))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=(spec, spec, spec))
     return jax.jit(fn)
 
 
+def resolve_modes(mesh: Mesh, interpret: Optional[bool],
+                   local_kernel: Optional[str]) -> Tuple[bool, str]:
+    """The Pallas mode and per-shard kernel a caller left open, derived
+    from the mesh: on TPU devices the compiled fused kernel; anywhere else
+    the Pallas interpreter and the jnp reference loop."""
+    interpret = K.resolve_interpret(interpret, mesh=mesh)
+    if local_kernel is None:
+        local_kernel = "reference" if interpret else "fused"
+    return interpret, local_kernel
+
+
 def _check_step_config(T: int, local_kernel: str, exchange: str,
-                       interpret: bool) -> None:
+                       interpret: bool, mesh: Mesh) -> None:
     """Shared build-time validation for the step and run drivers."""
     if T < 1:
         raise ValueError(f"T must be >= 1, got {T}")
@@ -342,14 +351,22 @@ def _check_step_config(T: int, local_kernel: str, exchange: str,
         raise ValueError(f"exchange must be one of {EXCHANGES}, "
                          f"got {exchange!r}")
     if exchange == "remote_dma" and not interpret:
-        backend = jax.default_backend()
-        if backend != "tpu":
+        platform = mesh.devices.flat[0].platform
+        if platform != "tpu":
             raise RuntimeError(
                 f"exchange='remote_dma' in compiled mode issues "
                 f"pltpu.make_async_remote_copy from inside a Pallas kernel "
-                f"and needs a TPU backend (Mosaic); this process is running "
-                f"{backend!r}. Use exchange='collective', or interpret=True "
-                "for the schedule-faithful emulation.")
+                f"and needs TPU devices (Mosaic); this mesh holds "
+                f"{platform!r} devices. Use exchange='collective', or "
+                "interpret=True for the schedule-faithful emulation.")
+
+
+def carries_checksums(exchange: str, interpret: bool) -> bool:
+    """Whether `exchange` can ride checksum words on its band messages:
+    both ppermute transports do (the collective engine, and the remote-DMA
+    emulation in interpret mode); the compiled Mosaic DMA kernel has no
+    checksum channel yet."""
+    return exchange != "remote_dma" or interpret
 
 
 def _check_integrity_config(verify_integrity: bool, corrupt_halo,
@@ -358,7 +375,7 @@ def _check_integrity_config(verify_integrity: bool, corrupt_halo,
     """Build-time validation of the integrity layer's knobs. `n_fields`
     bounds `corrupt_halo`'s field index — 3 (u, v, w) on the legacy
     path, `spec.n_fields` on a spec-driven build."""
-    if exchange == "remote_dma" and not interpret:
+    if not carries_checksums(exchange, interpret):
         if verify_integrity:
             raise RuntimeError(
                 "verify_integrity=True rides checksum words on the "
@@ -444,18 +461,16 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
             raise ValueError(
                 f"halo depth T={T} exceeds the decomposable global X "
                 f"extent ({X_g} planes, interior {X_g - 2}); lower T")
-        if local_kernel == "fused" or (exchange == "remote_dma"
-                                       and not interpret):
-            # static VMEM budget: ring registers + DMA slabs summed
-            # against VMEM_PER_CORE at trace time, so an over-budget
-            # config fails BEFORE compile with the buffer named
-            # (the analysis layer's vmem pass, generalising the
-            # serving-only serving_max_batch check to every rung)
+        if local_kernel == "fused":
+            # static VMEM budget: the ring register summed against
+            # VMEM_PER_CORE at trace time, so an over-budget config
+            # fails BEFORE compile with the buffer named (the analysis
+            # layer's vmem pass, generalising the serving-only
+            # serving_max_batch check to every rung)
             from repro.analysis import vmem as _vmem
             _vmem.distributed_block_plan(
                 (Xl, Yl, Z), T=T, itemsize=u.dtype.itemsize,
-                local_kernel=local_kernel, exchange=exchange,
-                interpret=interpret, y_tile=y_tile, nx=n_x, ny=n_y,
+                local_kernel=local_kernel, y_tile=y_tile, nx=n_x, ny=n_y,
                 context="distributed block").check()
         iy = jax.lax.axis_index(axis)
         ix = jax.lax.axis_index(x_axis) if dx else None
@@ -482,7 +497,7 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
                 if interpret:
                     return tuple(
                         _exchange_remote_dma_emulated(
-                            f, ax_name, n, T, dim,
+                            f, ax_name, T, dim,
                             integrity_out=integrity_out,
                             corrupt=(_corrupt_for(fi)
                                      if corrupt_halo is not None else None))
@@ -493,7 +508,7 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
                     collective_id=cid)
                 return tuple(jnp.concatenate([hi, f, lo], axis=dim)
                              for f, (hi, lo) in zip(fields, bands))
-            hs = [_exchange_halos(f, ax_name, n, depth=T, dim=dim,
+            hs = [_exchange_halos(f, ax_name, depth=T, dim=dim,
                                   integrity_out=integrity_out,
                                   corrupt=(_corrupt_for(fi)
                                            if corrupt_halo is not None
@@ -558,20 +573,18 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
 def _wrap_shard_map(local, mesh: Mesh, axis: str, x_axis: Optional[str],
                     local_kernel: str, exchange: str, interpret: bool,
                     *, integrity: bool = False, n_scalars: int = 0,
-                    check_rep_off: bool = False):
-    """jit(shard_map(local)) with the repo's spec/check_rep conventions.
+                    donate: bool = False):
+    """jit(shard_map(local)) with the repo's spec/check_vma conventions.
 
     `integrity` appends the per-shard mismatch flag to the out_specs
     (one `_flag_shape` entry per shard, laid out along the mesh axes);
     `n_scalars` appends replicated scalar inputs (the run core's traced
-    block bounds); `check_rep_off` forces check_rep=False — the traced-
-    bounds `fori_loop` lowers to `while`, which has no shard_map
-    replication rule on the pinned jax.
+    block bounds).
     """
     spec = (P(None, axis, None) if x_axis is None
             else P(x_axis, axis, None))
     flag_spec = P(axis) if x_axis is None else P(x_axis, axis)
-    # check_rep=False whenever a Pallas kernel runs per shard (the fused
+    # check_vma=False whenever a Pallas kernel runs per shard (the fused
     # local kernel, or the compiled remote-DMA exchange) — rationale in the
     # module docstring, documented once there.
     uses_pallas = (local_kernel == "fused"
@@ -579,18 +592,51 @@ def _wrap_shard_map(local, mesh: Mesh, axis: str, x_axis: Optional[str],
     out_specs = (spec, spec, spec)
     if integrity:
         out_specs = out_specs + (flag_spec,)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(spec, spec, spec) + (P(),) * n_scalars,
-                   out_specs=out_specs,
-                   check_rep=not (uses_pallas or check_rep_off))
-    return jax.jit(fn)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec, spec) + (P(),) * n_scalars,
+                       out_specs=out_specs,
+                       check_vma=not uses_pallas)
+    return _jit_fields(fn, mesh, spec, 3, out_specs[3:], n_scalars,
+                       interpret, donate)
+
+
+def _jit_fields(fn, mesh: Mesh, spec, n_fields: int, extra_out_specs,
+                n_scalars: int, interpret: bool, donate: bool = False):
+    """jit a shard_mapped driver. Compiled, its field arguments and results
+    keep the kernels' row-major layout (`kernels.advection.field_format`),
+    so no field is relaid out on entry or exit; a caller places its fields
+    with `field_formats`. `donate` hands the field arguments' buffers to
+    the program."""
+    donate_argnums = tuple(range(n_fields)) if donate else ()
+    if interpret:
+        return jax.jit(fn, donate_argnums=donate_argnums)
+    fmt = K.field_format(NamedSharding(mesh, spec))
+    rep = NamedSharding(mesh, P())
+    return jax.jit(
+        fn, in_shardings=(fmt,) * n_fields + (rep,) * n_scalars,
+        out_shardings=((fmt,) * n_fields
+                       + tuple(NamedSharding(mesh, s)
+                               for s in extra_out_specs)),
+        donate_argnums=donate_argnums)
+
+
+def field_formats(mesh: Mesh, axis: str = "data",
+                  x_axis: Optional[str] = None):
+    """Where a compiled step or run on `mesh` takes its fields: the
+    sharding of the decomposition in the kernels' row-major layout.
+    `jax.device_put(field, field_formats(mesh, ...))` places a host array
+    there directly."""
+    spec = (P(None, axis, None) if x_axis is None
+            else P(x_axis, axis, None))
+    return K.field_format(NamedSharding(mesh, spec))
 
 
 def _check_spec_step_config(spec, T: int, local_kernel: str, exchange: str,
-                            interpret: bool, verify_integrity: bool = False,
+                            interpret: bool, mesh: Mesh,
+                            verify_integrity: bool = False,
                             corrupt_halo=None) -> None:
     """Build-time validation of the spec-driven distributed path."""
-    _check_step_config(T, local_kernel, exchange, interpret)
+    _check_step_config(T, local_kernel, exchange, interpret, mesh)
     if not isinstance(spec, SP.StencilSpec):
         raise ValueError(f"spec must be a StencilSpec, got {type(spec)!r}")
     if exchange == "remote_dma" and not interpret:
@@ -674,8 +720,7 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
             from repro.analysis import vmem as _vmem
             _vmem.distributed_block_plan(
                 (Xl, Yl, Z), T=T, itemsize=fields[0].dtype.itemsize,
-                local_kernel=local_kernel, exchange=exchange,
-                interpret=interpret, y_tile=y_tile, nx=n_x, ny=n_y,
+                local_kernel=local_kernel, y_tile=y_tile, nx=n_x, ny=n_y,
                 spec=spec, context="spec-driven distributed block"
             ).check()
         if dy and D > Y_g - 2 * r:
@@ -701,7 +746,7 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
 
         # ---- two-phase x-then-y exchange at depth D; same engine dispatch
         # and corner contract as `_build_local_block` (module docstring).
-        def _extend(fs, ax_name, n, dim):
+        def _extend(fs, ax_name, dim):
             def _corrupt_for(fi):
                 if corrupt_dim != dim or fi != int(corrupt_halo[0]):
                     return None
@@ -710,12 +755,12 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
             if exchange == "remote_dma":
                 return tuple(
                     _exchange_remote_dma_emulated(
-                        f, ax_name, n, D, dim,
+                        f, ax_name, D, dim,
                         integrity_out=integrity_out,
                         corrupt=(_corrupt_for(fi)
                                  if corrupt_halo is not None else None))
                     for fi, f in enumerate(fs))
-            hs = [_exchange_halos(f, ax_name, n, depth=D, dim=dim,
+            hs = [_exchange_halos(f, ax_name, depth=D, dim=dim,
                                   integrity_out=integrity_out,
                                   corrupt=(_corrupt_for(fi)
                                            if corrupt_halo is not None
@@ -734,9 +779,9 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
 
         ext = tuple(fields)
         if dx:
-            ext = _extend(ext, x_axis, n_x, 0)
+            ext = _extend(ext, x_axis, 0)
         if dy:
-            ext = _extend(ext, axis, n_y, 1)
+            ext = _extend(ext, axis, 1)
 
         # ---- global-interior masks: the wall is `radius` cells wide (a
         # radius-r stencil cannot carry values past r frozen cells).
@@ -778,9 +823,9 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
 
 def _wrap_spec_shard_map(local, mesh: Mesh, axis: str,
                          x_axis: Optional[str], local_kernel: str,
-                         n_fields: int, *, integrity: bool = False,
-                         n_scalars: int = 0,
-                         check_rep_off: bool = False):
+                         n_fields: int, interpret: bool, *,
+                         integrity: bool = False,
+                         n_scalars: int = 0):
     """`_wrap_shard_map` for an n-field spec program. `integrity`
     appends the per-shard mismatch flag to the out_specs — the same
     `_flag_shape` layout as the legacy path."""
@@ -790,19 +835,20 @@ def _wrap_spec_shard_map(local, mesh: Mesh, axis: str,
     out_specs = (p,) * n_fields
     if integrity:
         out_specs = out_specs + (flag_spec,)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(p,) * n_fields + (P(),) * n_scalars,
-                   out_specs=out_specs,
-                   check_rep=not (uses_pallas or check_rep_off))
-    return jax.jit(fn)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(p,) * n_fields + (P(),) * n_scalars,
+                       out_specs=out_specs,
+                       check_vma=not uses_pallas)
+    return _jit_fields(fn, mesh, p, n_fields, out_specs[n_fields:],
+                       n_scalars, interpret)
 
 
 def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
                           axis: str = "data", x_axis: Optional[str] = None,
                           T: int = 1, dt: float = 1.0,
-                          local_kernel: str = "reference",
+                          local_kernel: Optional[str] = None,
                           y_tile: Optional[int] = None,
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           overlap: bool = False,
                           exchange: str = "collective",
                           dma_block_index: int = 0,
@@ -840,7 +886,7 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     `exchange` selects the band transport (module docstring): "collective"
     is XLA-scheduled ppermute; "remote_dma" issues the bands from inside a
     Pallas kernel via `pltpu.make_async_remote_copy` in compiled mode
-    (TPU-only — any other backend raises RuntimeError at build time;
+    (TPU-only — a mesh of other devices raises RuntimeError at build time;
     multi-hop via one remote copy per `_band_schedule` hop, so T is
     bounded only by the global extent like the collective engine) and
     runs the schedule-faithful ppermute emulation in interpret mode
@@ -857,6 +903,12 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     composing with the kernel's in-grid `(y_tile, x)` tiling via `y_tile`
     — the shard slab keeps a VMEM-bounded register no matter how wide the
     shard is.
+
+    `interpret=None` and `local_kernel=None` derive from the mesh
+    (`resolve_modes`): on TPU devices the compiled fused kernel, anywhere
+    else the Pallas interpreter and the jnp reference loop. Compiled, the
+    program takes and returns its fields in the kernels' row-major layout
+    (`field_formats`).
 
     `overlap=True` additionally computes the halo-independent interior of
     each shard in a pass that consumes NO exchange output, so it can run
@@ -887,9 +939,10 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     ppermute transports (interpret mode or the collective engine); the
     compiled Mosaic DMA path rejects them at build time.
     """
+    interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
     if spec is not None:
         _check_spec_step_config(spec, T, local_kernel, exchange, interpret,
-                                verify_integrity, corrupt_halo)
+                                mesh, verify_integrity, corrupt_halo)
         spec_block = _build_spec_local_block(
             mesh, spec, spec_params, axis=axis, x_axis=x_axis, T=T, dt=dt,
             local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
@@ -900,11 +953,11 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
             return spec_block(fields, dma_block_index)
 
         return _wrap_spec_shard_map(spec_local, mesh, axis, x_axis,
-                                    local_kernel, spec.n_fields,
+                                    local_kernel, spec.n_fields, interpret,
                                     integrity=verify_integrity)
     _check_integrity_config(verify_integrity, corrupt_halo, exchange,
                             interpret)
-    _check_step_config(T, local_kernel, exchange, interpret)
+    _check_step_config(T, local_kernel, exchange, interpret, mesh)
     local_block = _build_local_block(
         mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
         local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
@@ -922,18 +975,18 @@ def _make_run_core(mesh: Mesh, params: AdvectParams, *, axis: str,
                    x_axis: Optional[str], T: int, dt: float,
                    local_kernel: str, y_tile: Optional[int],
                    interpret: bool, overlap: bool, exchange: str,
-                   verify_integrity: bool):
+                   verify_integrity: bool, donate: bool = False):
     """The span-generic run program: ``core(u, v, w, start, end)`` runs
     blocks [start, end) with BOTH bounds traced, so one trace serves the
     full run, every checkpoint interval, and every resume continuation —
     interval boundaries never retrace and the per-block wire/integrity
-    counts stay span-independent (the trace-once gate). Traced bounds
-    lower `fori_loop` to `while`, hence `check_rep_off` (see
-    `_wrap_shard_map`). With `verify_integrity` the core returns a fourth
-    output: per-shard mismatch counts ACCUMULATED over the span.
+    counts stay span-independent (the trace-once gate). With
+    `verify_integrity` the core returns a fourth output: per-shard
+    mismatch counts ACCUMULATED over the span. `donate` hands the field
+    arguments' buffers to the program.
     """
     _check_integrity_config(verify_integrity, None, exchange, interpret)
-    _check_step_config(T, local_kernel, exchange, interpret)
+    _check_step_config(T, local_kernel, exchange, interpret, mesh)
     local_block = _build_local_block(
         mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
         local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
@@ -954,7 +1007,7 @@ def _make_run_core(mesh: Mesh, params: AdvectParams, *, axis: str,
 
     return _wrap_shard_map(local, mesh, axis, x_axis, local_kernel,
                            exchange, interpret, integrity=verify_integrity,
-                           n_scalars=2, check_rep_off=True)
+                           n_scalars=2, donate=donate)
 
 
 def _run_state(u, v, w, block: int, flags) -> dict:
@@ -1005,16 +1058,17 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
                          n_blocks: int, axis: str = "data",
                          x_axis: Optional[str] = None,
                          T: int = 1, dt: float = 1.0,
-                         local_kernel: str = "reference",
+                         local_kernel: Optional[str] = None,
                          y_tile: Optional[int] = None,
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          overlap: bool = False,
                          exchange: str = "collective",
                          verify_integrity: bool = False,
                          checkpoint_every: Optional[int] = None,
                          checkpoint_dir=None,
                          keep_last: int = 3,
-                         spec=None, spec_params=None):
+                         spec=None, spec_params=None,
+                         donate: bool = False):
     """Returns run(u, v, w): `n_blocks` substep-blocks (n_blocks * T Euler
     substeps, ONE depth-T exchange per block) in ONE traced program — the
     pipelined multi-block driver the remote-DMA engine's double-buffered
@@ -1057,6 +1111,10 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
     returned run is a pure jitted program (traceable — the byte-counting
     gates `jax.make_jaxpr` it).
 
+    `donate=True` hands the field arguments' buffers to the program (they
+    are deleted by the call): the loop state then lives in them, which is
+    what lets a grid the size of one chip's HBM fit once in and once out.
+
     All other arguments mean what they mean on `make_distributed_step`.
     """
     if n_blocks < 1:
@@ -1064,6 +1122,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
     if (checkpoint_every is None) != (checkpoint_dir is None):
         raise ValueError("checkpoint_every and checkpoint_dir come "
                          "together: both or neither")
+    interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
     if spec is not None:
         if checkpoint_every is not None:
             raise ValueError(
@@ -1071,7 +1130,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
                 "(the snapshot leaf dict is (u, v, w)-specific); run "
                 "without spec= or without checkpoint_every=")
         _check_spec_step_config(spec, T, local_kernel, exchange, interpret,
-                                verify_integrity, None)
+                                mesh, verify_integrity, None)
         spec_block = _build_spec_local_block(
             mesh, spec, spec_params, axis=axis, x_axis=x_axis, T=T, dt=dt,
             local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
@@ -1095,7 +1154,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
 
         spec_core = _wrap_spec_shard_map(
             spec_local, mesh, axis, x_axis, local_kernel, spec.n_fields,
-            integrity=verify_integrity, n_scalars=2, check_rep_off=True)
+            interpret, integrity=verify_integrity, n_scalars=2)
 
         def spec_run(*fields):
             return spec_core(*fields, 0, n_blocks)
@@ -1104,7 +1163,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
         mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
         local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
         overlap=overlap, exchange=exchange,
-        verify_integrity=verify_integrity)
+        verify_integrity=verify_integrity, donate=donate)
 
     if checkpoint_every is None:
         def run(u, v, w):
@@ -1137,9 +1196,9 @@ def resume_distributed_run(mesh: Mesh, params: AdvectParams, u, v, w, *,
                            axis: str = "data",
                            x_axis: Optional[str] = None,
                            T: int = 1, dt: float = 1.0,
-                           local_kernel: str = "reference",
+                           local_kernel: Optional[str] = None,
                            y_tile: Optional[int] = None,
-                           interpret: bool = True,
+                           interpret: Optional[bool] = None,
                            overlap: bool = False,
                            exchange: str = "collective",
                            verify_integrity: bool = False,
@@ -1164,6 +1223,7 @@ def resume_distributed_run(mesh: Mesh, params: AdvectParams, u, v, w, *,
 
     if n_blocks < 1:
         raise ValueError(f"n_blocks must be >= 1, got {n_blocks}")
+    interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
     core = _make_run_core(
         mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
         local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,

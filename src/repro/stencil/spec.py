@@ -223,7 +223,7 @@ def spec_multistep_ref_f64(fields, params, spec: StencilSpec, T: int,
     f_np = [np.asarray(t, np.float64) for t in fields]
     p_np = jax.tree_util.tree_map(lambda t: np.asarray(t, np.float64),
                                   params)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f64 = tuple(jnp.asarray(t) for t in f_np)
         p64 = jax.tree_util.tree_map(jnp.asarray, p_np)
         out = spec_multistep(f64, p64, spec, T, dt)

@@ -197,13 +197,12 @@ def test_distributed_step_fused_local_kernel_single_shard():
     shard must match the global T-substep oracle."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import compat_make_mesh
     from repro.stencil.distributed import (make_distributed_step,
                                            reference_global_step)
     X, Y, Z = 6, 20, 12
     u, v, w = fields((X, Y, Z), seed=7)
     p = default_params(Z)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P(None, "data", None))
     for T, y_tile in ((1, None), (2, 6)):
         fn = make_distributed_step(mesh, p, T=T, dt=DT,

@@ -1,10 +1,10 @@
 """Tiling-contract linter: every Pallas block mapping checked statically
-against the (8, 128) tile, Unblocked bounds, and in-place alias windows.
+against the (8, 128) tile, Element bounds, and in-place alias windows.
 
 All fast tier (1-device): the repo's own kernels lint error-free (the
 lane/sublane warnings on deliberately-tiny interpret grids are warnings,
-not errors); a fabricated Unblocked kernel whose index map walks past
-the operand extent is flagged "unblocked-oob" with the offending grid
+not errors); a fabricated Element kernel whose index map walks past
+the operand extent is flagged "element-oob" with the offending grid
 point and dim; aliased in-place windows that diverge are flagged
 "alias-window"; a lane-aligned kernel produces no lane warnings. The
 linter only TRACES (`jax.make_jaxpr`) — the broken fixtures never run.
@@ -27,16 +27,15 @@ def _copy_kernel(src_ref, dst_ref):
     dst_ref[...] = src_ref[...]
 
 
-def _unblocked_copy(x, *, n, stride, block, base=0):
-    """`n` grid steps, each copying a `block` window read at Unblocked
-    element offset ``base + g * stride``. A stride (or base) walking
+def _element_copy(x, *, n, stride, block, base=0):
+    """`n` grid steps, each copying a `block` window read at Element
+    row offset ``base + g * stride``. A stride (or base) walking
     past the operand extent fabricates the OOB the linter must catch;
     the program is traced, never run."""
-    spec = pl.BlockSpec(block,
-                        lambda g: (base + g * stride, 0),
-                        indexing_mode=pl.Unblocked())
-    out_spec = pl.BlockSpec(block, lambda g: (0, 0),
-                            indexing_mode=pl.Unblocked())
+    rows, lanes = block
+    spec = pl.BlockSpec((pl.Element(rows), lanes),
+                        lambda g: (base + g * stride, 0))
+    out_spec = pl.BlockSpec((pl.Element(rows), lanes), lambda g: (0, 0))
     return pl.pallas_call(
         _copy_kernel, grid=(n,),
         in_specs=[spec], out_specs=out_spec,
@@ -58,7 +57,7 @@ def test_repo_fused_kernel_is_error_free():
 def test_lane_aligned_kernel_has_no_lane_warnings():
     x = jnp.zeros((64, LANE), jnp.float32)
     report = lint_tiling(
-        lambda a: _unblocked_copy(a, n=2, stride=SUBLANE,
+        lambda a: _element_copy(a, n=2, stride=SUBLANE,
                                   block=(SUBLANE, LANE)), x)
     assert not report.errors
     assert not [w for w in report.warnings
@@ -68,7 +67,7 @@ def test_lane_aligned_kernel_has_no_lane_warnings():
 def test_misaligned_block_warns_not_errors():
     x = jnp.zeros((64, LANE), jnp.float32)
     report = lint_tiling(
-        lambda a: _unblocked_copy(a, n=1, stride=0, block=(3, 100)), x)
+        lambda a: _element_copy(a, n=1, stride=0, block=(3, 100)), x)
     assert not report.errors
     kinds = {w.kind for w in report.warnings}
     assert "lane" in kinds and "sublane" in kinds
@@ -78,18 +77,18 @@ def test_unblocked_oob_is_an_error():
     x = jnp.zeros((64, LANE), jnp.float32)
     # grid point 1 reads rows [60, 68) of a 64-row operand
     report = lint_tiling(
-        lambda a: _unblocked_copy(a, n=2, stride=60,
+        lambda a: _element_copy(a, n=2, stride=60,
                                   block=(SUBLANE, LANE)), x)
-    errs = [e for e in report.errors if e.kind == "unblocked-oob"]
+    errs = [e for e in report.errors if e.kind == "element-oob"]
     assert errs, report.issues
     assert "extent 64" in errs[0].detail and "(1,)" in errs[0].detail
-    with pytest.raises(AssertionError, match="unblocked-oob"):
+    with pytest.raises(AssertionError, match="element-oob"):
         report.raise_if_errors()
     # a negative element offset is equally out of bounds
     neg = lint_tiling(
-        lambda a: _unblocked_copy(a, n=1, stride=0, base=-8,
+        lambda a: _element_copy(a, n=1, stride=0, base=-8,
                                   block=(SUBLANE, LANE)), x)
-    assert any(e.kind == "unblocked-oob" for e in neg.errors)
+    assert any(e.kind == "element-oob" for e in neg.errors)
 
 
 def _aliased_shift(x, *, shift):
@@ -102,14 +101,12 @@ def _aliased_shift(x, *, shift):
 
     return pl.pallas_call(
         kernel, grid=(n,),
-        in_specs=[pl.BlockSpec((SUBLANE, LANE),
-                               lambda g: (g * SUBLANE, 0),
-                               indexing_mode=pl.Unblocked())],
-        out_specs=pl.BlockSpec((SUBLANE, LANE),
+        in_specs=[pl.BlockSpec((pl.Element(SUBLANE), LANE),
+                               lambda g: (g * SUBLANE, 0))],
+        out_specs=pl.BlockSpec((pl.Element(SUBLANE), LANE),
                                functools.partial(
                                    lambda g, s: (g * SUBLANE + s, 0),
-                                   s=shift),
-                               indexing_mode=pl.Unblocked()),
+                                   s=shift)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         input_output_aliases={0: 0},
         interpret=True)(x)
@@ -130,7 +127,7 @@ def test_grid_cap_falls_back_to_corners():
     # only the LAST grid point (g=39, rows [78, 86)) exceeds 64 rows
     x = jnp.zeros((64, LANE), jnp.float32)
     report = lint_tiling(
-        lambda a: _unblocked_copy(a, n=40, stride=2,
+        lambda a: _element_copy(a, n=40, stride=2,
                                   block=(SUBLANE, LANE)),
         x, max_grid_points=4)
-    assert any(e.kind == "unblocked-oob" for e in report.errors)
+    assert any(e.kind == "element-oob" for e in report.errors)

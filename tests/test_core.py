@@ -137,8 +137,7 @@ def test_parse_real_compiled_module():
         sys.path.insert(0, "src")
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import hlo as H
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("model",))
+        mesh = jax.make_mesh((8,), ("model",))
         s = NamedSharding(mesh, P(None, "model"))
         f = lambda a, b: (a @ b).sum()
         a = jax.ShapeDtypeStruct((128, 256), jnp.float32)

@@ -4,7 +4,7 @@ corner-exchange regression (the x-then-y two-phase contract), and the
 multi-hop depth-T exchange that lifts the old T <= local-extent limit.
 
 Subprocess idiom (`tests/_subproc.run_ok`): meshes come from
-`launch.mesh.compat_make_mesh` on 4 forced host devices, and the child env
+`jax.make_mesh` on 4 forced host devices, and the child env
 pins JAX_PLATFORMS=cpu so jax never probes libtpu (the old timeout flake).
 A cheap single-device wiring test stays in the fast tier.
 """
@@ -133,14 +133,13 @@ MULTIHOP_CODE = textwrap.dedent("""
                                            reference_global_step)
     from repro.stencil.advection import stratus_fields
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
 
     # Yl = 4 per shard: T=6 needs 2 ppermute hops, T=10 needs 3; T=14 is
     # the global bound (Y-2), T=15 must raise. Both local kernels.
     X, Y, Z = 6, 16, 12
     u, v, w = stratus_fields(X, Y, Z)
     p = default_params(Z)
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     sh = NamedSharding(mesh, P(None, "data", None))
     # overlap=True composed with multi-hop: the interior/boundary select
     # must hold when the T-deep bands swallow whole shards (T > Yl)
@@ -162,7 +161,7 @@ MULTIHOP_CODE = textwrap.dedent("""
     # multi-hop along x too: Xl=2 per shard on a (4, 1) mesh, T=3 -> 2 hops
     X2 = 8
     u2, v2, w2 = stratus_fields(X2, Y, Z)
-    mesh2 = compat_make_mesh((4, 1), ("x", "y"))
+    mesh2 = jax.make_mesh((4, 1), ("x", "y"))
     sh2 = NamedSharding(mesh2, P("x", "y", None))
     fn = make_distributed_step(mesh2, p, axis="y", x_axis="x", T=3, dt=0.01,
                                local_kernel="fused")

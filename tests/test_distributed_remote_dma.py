@@ -107,7 +107,6 @@ def test_dma_kernel_traces_under_shard_map():
     would break the compiled path silently until the next TPU run."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.advection.advection import halo_band_exchange_dma
@@ -125,8 +124,8 @@ def test_dma_kernel_traces_under_shard_map():
                 collective_id=dim)
             (uh, ul), _, _ = bands
             return uh + ul
-        fn = shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
-                       out_specs=spec, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
+                           out_specs=spec, check_vma=False)
         jax.make_jaxpr(fn)(*[jnp.zeros((6, 8, 16), jnp.float32)] * 3)
 
 
@@ -137,7 +136,6 @@ def test_dma_kernel_traces_with_traced_block_index():
     TracerIntegerConversionError here."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.advection.advection import halo_band_exchange_dma
@@ -153,8 +151,8 @@ def test_dma_kernel_traces_with_traced_block_index():
         (uh, ul), _, _ = bands
         return uh + ul
 
-    fn = shard_map(local, mesh=mesh, in_specs=(spec,) * 3 + (P(),),
-                   out_specs=spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 3 + (P(),),
+                       out_specs=spec, check_vma=False)
     jax.make_jaxpr(fn)(*[jnp.zeros((6, 8, 16), jnp.float32)] * 3,
                        jnp.int32(3))
 
@@ -235,7 +233,6 @@ MULTIHOP_EMULATION_CODE = textwrap.dedent("""
                                            reference_global_step)
     from repro.stencil.advection import stratus_fields
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
 
     # Yl = 4 per shard: T=6 takes 2 band messages (hops) per side, T=10
     # takes 3 — the emulation's per-hop recv-slab offsets must reproduce
@@ -244,7 +241,7 @@ MULTIHOP_EMULATION_CODE = textwrap.dedent("""
     X, Y, Z = 6, 16, 12
     u, v, w = stratus_fields(X, Y, Z)
     p = default_params(Z)
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     sh = NamedSharding(mesh, P(None, "data", None))
     args = [jax.device_put(t, sh) for t in (u, v, w)]
     for T in (6, 10, 14):

@@ -19,8 +19,7 @@ CODE = textwrap.dedent("""
     from repro.stencil.advection import stratus_fields
     from repro.kernels.advection.ref import default_params
 
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     for (X, Y, Z) in [(8, 32, 16), (5, 16, 24)]:
         u, v, w = stratus_fields(X, Y, Z)
         p = default_params(Z)
@@ -52,9 +51,8 @@ FUSED_CODE = textwrap.dedent("""
                                            reference_global_step)
     from repro.stencil.advection import stratus_fields
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
 
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     sh_done = False
     for (X, Y, Z) in [(6, 16, 12), (5, 24, 16)]:
         for T in (1, 2, 4):
@@ -95,12 +93,11 @@ KERNEL_CODE = textwrap.dedent("""
                                            reference_global_step)
     from repro.stencil.advection import stratus_fields
     from repro.kernels.advection.ref import default_params
-    from repro.launch.mesh import compat_make_mesh
 
     # local_kernel="fused": the per-shard slab streams through the v4
     # Pallas kernel (global-interior mask freezing the wrapped rows),
     # composed with the kernel's in-grid (y_tile, x) tiling.
-    mesh = compat_make_mesh((4,), ("data",))
+    mesh = jax.make_mesh((4,), ("data",))
     sh = NamedSharding(mesh, P(None, "data", None))
     for (X, Y, Z) in [(6, 16, 12), (5, 24, 16)]:
         for T in (1, 2, 4):

@@ -27,6 +27,7 @@ Contracts pinned here (the fault_sweep.py gates, at test-sized grids):
 """
 import logging
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -429,13 +430,12 @@ def test_injector_stall_arming_and_counters():
 def test_resilient_distributed_run_degrades_bitwise():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import compat_make_mesh
     from repro.stencil.distributed import make_distributed_step
 
     Xd, Yd, Zd = 6, 20, 12
     u, v, w = stratus_fields(Xd, Yd, Zd, seed=3)
     p = default_params(Zd)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     sh = NamedSharding(mesh, P(None, "data", None))
     uu, vv, ww = (np.asarray(a) for a in (u, v, w))
 
@@ -471,13 +471,12 @@ def test_resilient_distributed_run_degrades_bitwise():
 
 
 def _one_shard_setup(seed=3):
-    from repro.launch.mesh import compat_make_mesh
     from repro.stencil.distributed import make_distributed_step
 
     Xd, Yd, Zd = 6, 20, 12
     u, v, w = stratus_fields(Xd, Yd, Zd, seed=seed)
     p = default_params(Zd)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     uu, vv, ww = (np.asarray(a) for a in (u, v, w))
     step = make_distributed_step(mesh, p, T=1, dt=DT)
     cu, cv, cw = uu, vv, ww

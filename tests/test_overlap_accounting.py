@@ -270,8 +270,8 @@ def test_domain_pipeline_efficiency_plumbing():
 # --- compiled-mode backend gate --------------------------------------------
 
 def test_remote_dma_compiled_requires_tpu():
-    """On this (CPU) backend, building the compiled remote-DMA step must
-    fail loudly at build time — not at first call — and say why."""
+    """On a CPU mesh, building the compiled remote-DMA step must fail
+    loudly at build time — not at first call — and say why."""
     import jax
     from repro.kernels.advection.ref import default_params
     from repro.launch.mesh import make_stencil_mesh
@@ -280,7 +280,7 @@ def test_remote_dma_compiled_requires_tpu():
     if jax.default_backend() == "tpu":
         pytest.skip("this asserts the NON-TPU error path")
     mesh = make_stencil_mesh(1, 1)
-    with pytest.raises(RuntimeError, match="TPU backend"):
+    with pytest.raises(RuntimeError, match="needs TPU devices"):
         make_distributed_step(mesh, default_params(8), axis="y", x_axis="x",
                               T=2, exchange="remote_dma", interpret=False)
 

@@ -10,8 +10,7 @@ CODE = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np, sys
     from repro.distributed.pipeline import pipeline_apply, bubble_fraction
 
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",))
     rng = np.random.default_rng(0)
     L, D = 8, 16           # 8 layers -> 2 per stage
     W = jnp.asarray(rng.normal(size=(L, D, D)) * 0.3, jnp.float32)

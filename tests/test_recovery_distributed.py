@@ -26,6 +26,7 @@ dma_block_index parity regression), and elastic shrink/regrow bitwise.
 """
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -34,7 +35,7 @@ from _subproc import run_ok
 from repro.core import roofline as R
 from repro.kernels.advection.advection import band_checksum
 from repro.kernels.advection.ref import default_params
-from repro.launch.mesh import compat_make_mesh, resize_stencil_mesh
+from repro.launch.mesh import resize_stencil_mesh
 from repro.stencil import distributed as D
 from repro.stencil.advection import stratus_fields
 
@@ -85,7 +86,7 @@ def test_band_checksum_contract():
 
 def _setup():
     u, v, w = stratus_fields(X, Y, Z, seed=0)
-    return compat_make_mesh((1,), ("data",)), default_params(Z), (u, v, w)
+    return jax.make_mesh((1,), ("data",)), default_params(Z), (u, v, w)
 
 
 def test_verified_step_one_device_bitwise_and_priced():

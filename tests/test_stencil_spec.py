@@ -202,8 +202,9 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
+import jax
 import jax.numpy as jnp
-from repro.launch.mesh import make_stencil_mesh, compat_make_mesh
+from repro.launch.mesh import make_stencil_mesh
 from repro.stencil import spec as SP
 from repro.stencil import distributed as D
 from repro.stencil.advection import stratus_fields
@@ -241,7 +242,7 @@ for a, b in zip(run(u, v, w, q), seq):
     assert np.array_equal(np.asarray(a), np.asarray(b))
 
 # rk2 diffusion: deeper exchange vs the single-device oracle
-mesh1 = compat_make_mesh((4,), ("data",))
+mesh1 = jax.make_mesh((4,), ("data",))
 dspec = SP.diffusion_spec("rk2")
 dp = SP.default_diffusion_params(Z)
 phi = SP.diffusion_field(X, Y, Z)
