@@ -393,6 +393,7 @@ def advect_blocked(u, v, w, p: AdvectParams, *,
         out_shape=out_shape,
         scratch_shapes=_out_scratch(TY, S, Z, u.dtype),
         interpret=interpret,
+        name="advect_blocked",
     )
     return fn(t1, t2, u, u, u, v, v, v, w, w, w)
 
@@ -483,6 +484,7 @@ def advect_dataflow(u, v, w, p: AdvectParams, *,
         scratch_shapes=([pltpu.VMEM((3, S, Z), u.dtype) for _ in range(3)]
                         + _out_scratch(TY, S, Z, u.dtype)),
         interpret=interpret,
+        name="advect_dataflow",
     )
     return fn(t1, t2, u, v, w)
 
@@ -629,6 +631,7 @@ def finite_guard(u, v, w, *, interpret: Optional[bool] = None):
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((X,), jnp.float32),
         interpret=resolve_interpret(interpret, u),
+        name="finite_guard",
     )(u, v, w)
 
 
@@ -695,6 +698,7 @@ def advect_fused(u, v, w, p: AdvectParams, *, T: int = 4, dt: float = 1.0,
                          for _ in range(3)]
                         + _out_scratch(TY, S, Z, u.dtype)),
         interpret=interpret,
+        name="advect_fused",
     )
     ou, ov, ow = fn(t1, t2, xm, ym, u, v, w)
     if guard:
@@ -934,6 +938,7 @@ def stencil_fused(fields, params, spec, *, T: int = 4, dt: float = 1.0,
                          for _ in range(spec.n_fields)]
                         + _out_scratch(TY, S, Z, fields[0].dtype)),
         interpret=interpret,
+        name="stencil_fused",
     )
     out = fn(*pv, xm, ym, *fields)
     return tuple(out) if isinstance(out, (tuple, list)) else (out,)
@@ -1160,6 +1165,7 @@ def halo_band_exchange_dma(u, v, w, *, axis: str, mesh_axes, n: int,
         scratch_shapes=[pltpu.SemaphoreType.DMA((nb,)),    # remote send
                         pltpu.SemaphoreType.DMA((nb,))],   # remote recv
         compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+        name="halo_band_exchange_dma",
     )
     block = jnp.asarray(block_index, jnp.int32)
     outs = fn(block.reshape((1,)), *sends)

@@ -527,9 +527,11 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
 
         fields = (u, v, w)
         if dx:
-            fields = _extend(fields, x_axis, n_x, 0, 0)
+            with jax.named_scope("exchange_x"):
+                fields = _extend(fields, x_axis, n_x, 0, 0)
         if dy:
-            fields = _extend(fields, axis, n_y, 1, 1)
+            with jax.named_scope("exchange_y"):
+                fields = _extend(fields, axis, n_y, 1, 1)
 
         # ---- global-interior masks over the slab coordinates
         x_int = y_int = None
@@ -541,8 +543,9 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
             y_int = (gy >= 1) & (gy <= Y_g - 2)
 
         # ---- boundary pass (consumes the exchange), trimmed to owned rows
-        us, vs, ws = _substeps(*fields, x_int, y_int, y_tile)
-        out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in (us, vs, ws))
+        with jax.named_scope("compute"):
+            us, vs, ws = _substeps(*fields, x_int, y_int, y_tile)
+            out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in (us, vs, ws))
         if not (overlap and (dx or dy)):
             return _with_flag(out)
 
@@ -556,7 +559,8 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
         if dy:
             ogy = iy * Yl + jnp.arange(Yl)
             oy_int = (ogy >= 1) & (ogy <= Y_g - 2)
-        inner = _substeps(u, v, w, ox_int, oy_int, y_tile)
+        with jax.named_scope("compute_interior"):
+            inner = _substeps(u, v, w, ox_int, oy_int, y_tile)
         sx = jnp.arange(Xl)
         ok_x = jnp.ones((Xl,), jnp.bool_) if not dx else (
             ((ix == 0) | (sx >= T)) & ((ix == n_x - 1) | (sx < Xl - T)))
@@ -779,9 +783,11 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
 
         ext = tuple(fields)
         if dx:
-            ext = _extend(ext, x_axis, 0)
+            with jax.named_scope("exchange_x"):
+                ext = _extend(ext, x_axis, 0)
         if dy:
-            ext = _extend(ext, axis, 1)
+            with jax.named_scope("exchange_y"):
+                ext = _extend(ext, axis, 1)
 
         # ---- global-interior masks: the wall is `radius` cells wide (a
         # radius-r stencil cannot carry values past r frozen cells).
@@ -793,8 +799,9 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
             gy = iy * Yl - dy + jnp.arange(Yl + 2 * dy)
             y_int = (gy >= r) & (gy <= Y_g - 1 - r)
 
-        outs = _substeps(ext, x_int, y_int, y_tile)
-        out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in outs)
+        with jax.named_scope("compute"):
+            outs = _substeps(ext, x_int, y_int, y_tile)
+            out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in outs)
         if not (overlap and (dx or dy)):
             return _with_flag(out)
 
@@ -807,7 +814,8 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
         if dy:
             ogy = iy * Yl + jnp.arange(Yl)
             oy_int = (ogy >= r) & (ogy <= Y_g - 1 - r)
-        inner = _substeps(tuple(fields), ox_int, oy_int, y_tile)
+        with jax.named_scope("compute_interior"):
+            inner = _substeps(tuple(fields), ox_int, oy_int, y_tile)
         sx = jnp.arange(Xl)
         ok_x = jnp.ones((Xl,), jnp.bool_) if not dx else (
             ((ix == 0) | (sx >= D)) & ((ix == n_x - 1) | (sx < Xl - D)))
@@ -996,12 +1004,15 @@ def _make_run_core(mesh: Mesh, params: AdvectParams, *, axis: str,
     def local(u, v, w, start, end):
         if verify_integrity:
             def body(k, carry):
-                uu, vv, ww, m = local_block(carry[0], carry[1], carry[2], k)
+                with jax.named_scope("block"):
+                    uu, vv, ww, m = local_block(carry[0], carry[1],
+                                                carry[2], k)
                 return (uu, vv, ww, carry[3] + m)
             init = (u, v, w, jnp.zeros(_flag_shape(x_axis), jnp.uint32))
         else:
             def body(k, carry):
-                return local_block(*carry, k)
+                with jax.named_scope("block"):
+                    return local_block(*carry, k)
             init = (u, v, w)
         return jax.lax.fori_loop(start, end, body, init)
 
@@ -1142,13 +1153,15 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
 
             if verify_integrity:
                 def body(k, carry):
-                    out = spec_block(carry[:-1], k)
+                    with jax.named_scope("block"):
+                        out = spec_block(carry[:-1], k)
                     return out[:-1] + (carry[-1] + out[-1],)
                 init = tuple(fields) + (
                     jnp.zeros(_flag_shape(x_axis), jnp.uint32),)
             else:
                 def body(k, carry):
-                    return spec_block(carry, k)
+                    with jax.named_scope("block"):
+                        return spec_block(carry, k)
                 init = tuple(fields)
             return jax.lax.fori_loop(start, end, body, init)
 

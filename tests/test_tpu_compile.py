@@ -12,10 +12,12 @@ y-tile `chip_smoke.py` uses, `finite_guard` at 268M, the serving
 mega-step (`advect_fused_batched`, guard on) at the `launch/serve.py
 --stencil` slot shape, the 1x1 `make_distributed_run` with donated
 fields, and the 2x2 `make_distributed_run` with both exchange engines.
-The topology is described inside a fixture, so only the worker that runs
-this file loads the TPU library, and the persistent compilation cache is
-off while these compile (a compile for a described chip cannot be read
-back from it).
+Each program's text also names its kernels (`name=` on every
+`pallas_call`) and the run's block phases (`jax.named_scope`), the names
+a device trace shows. The topology is described inside a fixture, so
+only the worker that runs this file loads the TPU library, and the
+persistent compilation cache is off while these compile (a compile for a
+described chip cannot be read back from it).
 """
 import jax
 import jax.numpy as jnp
@@ -77,6 +79,13 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _names(compiled, *names) -> bool:
+    """Every one of `names` is in the compiled program's text (a kernel's
+    `name=` becomes its instruction's name and its op_name)."""
+    text = compiled.as_text()
+    return all(n in text for n in names)
+
+
 def test_fused_268m_compiles(one_chip):
     fmt = K.field_format(one_chip)
     f = jax.ShapeDtypeStruct(GRID, jnp.float32, sharding=fmt)
@@ -86,6 +95,7 @@ def test_fused_268m_compiles(one_chip):
         out_shardings=(fmt,) * 3,
     ).lower(f, f, f, _params(one_chip)).compile()
     assert _n_kernels(c) == 1
+    assert _names(c, "%advect_fused", "advect_fused/pallas_call")
     # fields in the kernel's layout: three in, three out, nothing else
     assert c.memory_analysis().temp_size_in_bytes == 0
     assert _hbm(c) <= HBM_BYTES
@@ -96,6 +106,7 @@ def test_finite_guard_268m_compiles(one_chip):
     c = jax.jit(lambda u, v, w: K.finite_guard(u, v, w, interpret=False)
                 ).lower(f, f, f).compile()
     assert _n_kernels(c) == 1
+    assert _names(c, "%finite_guard", "finite_guard/pallas_call")
     assert c.memory_analysis().output_size_in_bytes == GRID[0] * 4
 
 
@@ -114,6 +125,8 @@ def test_serving_mega_step_compiles(one_chip):
             y_interior_mask=ym, guard=True),
     ).lower(f, f, f, p, xm, ym).compile()
     assert _n_kernels(c) == 2          # the fused mega-launch + the guard
+    assert _names(c, "%vmap_advect_fused_", "vmap(advect_fused)/pallas_call",
+                  "%finite_guard", "finite_guard/pallas_call")
 
 
 def test_run_268m_one_chip_fits(topo):
@@ -127,6 +140,8 @@ def test_run_268m_one_chip_fits(topo):
     c = jax.jit(run, in_shardings=(fmt,) * 3, out_shardings=(fmt,) * 3,
                 donate_argnums=(0, 1, 2)).lower(f, f, f).compile()
     assert _n_kernels(c) == 1
+    # one block's compute phase, named, inside the loop
+    assert _names(c, "%advect_fused", "block/compute/advect_fused")
     # donated fields carry the loop state: 6 GiB in, 6 GiB out, at Z=64
     # padded to the 128-lane row
     assert _hbm(c) <= 13 * 2 ** 30 < HBM_BYTES
@@ -147,4 +162,7 @@ def test_run_268m_2x2_compiles(topo, exchange):
     # the fused kernel, plus one remote-DMA kernel per exchange phase
     assert _n_kernels(c) == (3 if exchange == "remote_dma" else 1)
     assert ("collective-permute" in text) == (exchange == "collective")
+    assert _names(c, "block/exchange_x/", "block/exchange_y/",
+                  "block/compute/advect_fused")
+    assert ("halo_band_exchange_dma" in text) == (exchange == "remote_dma")
     assert _hbm(c) <= HBM_BYTES
