@@ -90,6 +90,8 @@ def _run_stencil(args) -> None:
           f"retries={h['retries']} quarantines={h['quarantines']} "
           f"rollbacks={h['rollbacks']} degradations={h['degradations']} "
           f"reshards={h['reshards']} exchange={h['exchange']}")
+    print(f"[serve] link: {h['bytes_to_device']} B to the device, "
+          f"{h['bytes_to_host']} B to the host")
     for t_line in h["transitions"]:
         print(f"  [health] {t_line}")
     for uid in sorted(done)[:4]:

@@ -23,8 +23,10 @@ tests/test_stencil_serving.py / tests/test_faults.py):
     ``(shape, T, dtype, n_blocks, exchange, mesh)`` with hit/miss
     counters and a bounded LRU (`max_entries`) — one trace per
     configuration, every later mega-step a hit.
-  * Intermediate states stream back per slot (`StencilRequest.states`,
-    one cropped (u, v, w) snapshot per fused step).
+  * The slot batch lives on the device across mega-steps. A prime uploads
+    only the job's own fields and writes its slot in place; after a
+    mega-step only each live slot's cropped state comes back
+    (`StencilRequest.states`, one cropped (u, v, w) per fused step).
   * Faults are injected from a deterministic `serving.faults.FaultPlan`
     at mega-step boundaries (the old `lose_device_at` hook is a
     deprecated one-fault alias) and recovery is LAYERED:
@@ -35,7 +37,8 @@ tests/test_stencil_serving.py / tests/test_faults.py):
         the step it goes non-finite; the guard is a SEPARATE pallas
         pass over the fused kernel's outputs, so every slot's fields —
         healthy or poisoned — stay bitwise-equal to an unguarded run;
-      - periodic snapshots of the full in-flight state (through
+      - periodic snapshots of the in-flight state in host memory (the
+        states already streamed back, by reference; through
         `training/checkpoint`'s atomic-write machinery when
         `snapshot_dir` is set) let ANY fault roll back and replay,
         resume bitwise-equal to an uninterrupted run; a fault that
@@ -60,6 +63,7 @@ tests/test_stencil_serving.py / tests/test_faults.py):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import OrderedDict
 from pathlib import Path
@@ -68,6 +72,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.analysis import vmem as AV
 from repro.kernels.advection import advection as K
@@ -86,6 +91,37 @@ from repro.training import checkpoint as CKPT
 _span = jax.profiler.TraceAnnotation
 
 
+def _bucket(n: int, cap: int) -> int:
+    """The power of two at or above `n`, at most `cap`. Slot writes and
+    crops move a job's fields padded to these extents, so a mix of any
+    extents shares a few programs: with X and Y powers of two, at most
+    ``(log2 X + 1) * (log2 Y + 1)`` of each per batch shape."""
+    return min(1 << max(n - 1, 0).bit_length(), cap)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_slot(batch, slot, fields):
+    """Write slot `slot` of the device batch in place: `fields` at the
+    origin, zero everywhere else. One compile per batch and field shape
+    (the field shapes are `_bucket` extents)."""
+    out = []
+    for f, x in zip(batch, fields):
+        slab = jnp.zeros(f.shape[1:], f.dtype)
+        slab = slab.at[:x.shape[0], :x.shape[1]].set(x)
+        out.append(lax.dynamic_update_index_in_dim(f, slab, slot, 0))
+    return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _crop_slot(batch, slot, extent):
+    """Slot `slot`'s ``(Xr, Yr, Z)`` corner of the device batch, as new
+    arrays. One compile per extent (a `_bucket` extent)."""
+    Xr, Yr = extent
+    return tuple(lax.dynamic_slice(f, (slot, 0, 0, 0),
+                                   (1, Xr, Yr, f.shape[3]))[0]
+                 for f in batch)
+
+
 @dataclasses.dataclass
 class StencilRequest:
     """One forecast job: initial fields + coefficients + a step budget.
@@ -96,6 +132,11 @@ class StencilRequest:
     a per-tenant `AdvectParams` (same Z) rides the slot's batched leaves.
     `status` walks pending -> running -> done, or -> quarantined (with
     `error` set and `out=None`) when the finite guard traps the slot.
+
+    Each `states` entry (and `out`, its last) is a tuple of arrays copied
+    from the device for this job and this step alone; they may come back
+    read-only, and the engine's snapshot keeps references to them, so
+    they are not to be written in place.
     """
     uid: int
     u: np.ndarray                        # (Xr, Yr, Z) initial fields
@@ -111,29 +152,29 @@ class StencilRequest:
 
 @dataclasses.dataclass
 class _InFlight:
-    """A live job's full padded slot state, detached for re-sharding."""
+    """A live job's cropped state, as the host holds it, detached for
+    re-sharding."""
     req: StencilRequest
     budget: int
-    u: np.ndarray
-    v: np.ndarray
-    w: np.ndarray
-    xm: np.ndarray
-    ym: np.ndarray
+    fields: Tuple[np.ndarray, np.ndarray, np.ndarray]
     params: Tuple[np.ndarray, ...]
     extent: Tuple[int, int]
 
 
 @dataclasses.dataclass
 class _Snapshot:
-    """Everything a rollback needs to replay from this boundary: the
-    padded batch arrays, the slot assignments, the queue, and the length
-    of every reachable request's streamed-state list (so replayed steps
-    do not double-append). `disk_step` is set when the arrays were also
-    written through `training/checkpoint.save` — the rollback then
-    restores them from DISK, exercising the same atomic-write machinery
-    the training tier trusts."""
+    """Everything a rollback needs to replay from this boundary: each
+    live slot's cropped fields (references to host states the engine
+    already holds, never copies), copies of the masks and coefficients
+    (`arrays`), the slot assignments, the queue, and the length of every
+    reachable request's streamed-state list (so replayed steps do not
+    double-append). `disk_step` is set when the padded batch was also
+    written through `training/checkpoint.save` (`arrays` then holds it
+    too) — the rollback then restores it from DISK, exercising the same
+    atomic-write machinery the training tier trusts."""
     steps_run: int
     B: int
+    fields: Dict[int, Tuple[np.ndarray, ...]]
     arrays: Dict[str, np.ndarray]
     extents: List[Tuple[int, int]]
     live: List[Tuple[int, int, int]]     # (slot, uid, budget)
@@ -204,16 +245,29 @@ class StencilServingEngine:
     the slot are padded and mask-frozen; Z must match exactly (the z axis
     has no interior mask — it is the vectorised lane dimension).
 
+    The padded batch `u`, `v`, `w` are device arrays ``(B, X, Y, Z)`` that
+    live across mega-steps; each mega-step donates them to the cached step
+    and keeps its outputs. Only two things cross the link with fields in
+    them: a prime uploads the job's own ``(Xr, Yr, Z)`` fields and writes
+    its slot in place, and after a mega-step each live slot's cropped
+    state is sliced on the device and downloaded into arrays of its own,
+    which become the job's next `states` entry. Both move the fields
+    padded to the extent's `_bucket` (the next power of two, at most the
+    slot), so traffic of mixed extents compiles a few slot programs, not
+    two per extent. The masks and per-slot coefficients stay host numpy
+    and go up each mega-step (a few KiB). `health()` counts the bytes
+    moved each way (`bytes_to_device`, `bytes_to_host`).
+
     Fault tolerance knobs: `fault_plan` (a `FaultPlan`, or a spec string
     for `FaultPlan.parse`) schedules deterministic faults at mega-step
     boundaries; `snapshot_every=k` rolls a recovery point every k
     mega-steps (default 1; None disables rollback and a tripped guard
-    quarantines immediately). A snapshot is a host-side copy of the
-    whole batch, as many bytes as a mega-step uploads, and it is not
-    small next to the launch: on a TPU v5e host, 13 slots of
-    16x1024x64, `engine.snapshot` took 207 ms a mega-step against 15 ms
-    of `engine.device`, 13x. `snapshot_dir` additionally round-trips
-    each snapshot through `training/checkpoint`'s atomic on-disk format;
+    quarantines immediately). The recovery point stays in host memory:
+    it holds references to each live slot's last downloaded state (or
+    its job's initial fields) and copies of the masks and coefficients,
+    so a snapshot copies no field. `snapshot_dir` additionally assembles
+    the padded batch and round-trips each snapshot through
+    `training/checkpoint`'s atomic on-disk format;
     `max_retries`/`backoff_s` bound the exchange-stall retry loop;
     `cache_max_entries` bounds the executable cache (LRU).
     """
@@ -243,9 +297,9 @@ class StencilServingEngine:
         # overhead BENCH_faults.json bounds at exactly one replayed
         # snapshot interval per rollback
         self.megasteps_executed = 0
-        # the guard is a separate pallas pass over the advanced fields,
-        # so it composes with any tiling mode (including host)
-        self._guard = True
+        # field, mask and coefficient bytes moved across the link
+        self.bytes_to_device = 0
+        self.bytes_to_host = 0
         if isinstance(fault_plan, str):
             fault_plan = FaultPlan.parse(fault_plan)
         self._injector = FaultInjector(fault_plan)
@@ -280,15 +334,19 @@ class StencilServingEngine:
                              context="serving engine slot rings").check()
         self.B = batch_size
         self.slots = SlotManager(batch_size)
+        # drop the old batch before the new one is made
+        self.u = self.v = self.w = None
         shape = (batch_size, d.X, d.Y, d.Z)
-        self.u = np.zeros(shape, dt)
-        self.v = np.zeros(shape, dt)
-        self.w = np.zeros(shape, dt)
+        self.u = jnp.zeros(shape, dt)
+        self.v = jnp.zeros(shape, dt)
+        self.w = jnp.zeros(shape, dt)
         self.xm = np.zeros((batch_size, d.X), np.float32)
         self.ym = np.zeros((batch_size, d.Y), np.float32)
         base = [np.asarray(leaf) for leaf in d.params]
         self._p = [np.stack([leaf] * batch_size) for leaf in base]
         self._extent: List[Tuple[int, int]] = [(0, 0)] * batch_size
+        # each live slot's state as downloaded after the last mega-step
+        self._fresh: Dict[int, Tuple[np.ndarray, ...]] = {}
 
     def _step_key(self):
         d = self.domain
@@ -297,25 +355,59 @@ class StencilServingEngine:
 
     def _build_step(self):
         d = self.domain
-        guard = self._guard
 
         def step(u, v, w, p, xm, ym):
             return K.advect_fused_batched(u, v, w, p, T=d.fuse_T, dt=d.dt,
                                           interpret=d.interpret,
                                           y_tile=d.y_tile, tiling=d.tiling,
                                           x_interior_mask=xm,
-                                          y_interior_mask=ym, guard=guard)
+                                          y_interior_mask=ym, guard=True)
 
-        return jax.jit(step)
+        # the batch is donated: the step writes its outputs over it, so
+        # the device never holds two batches beside the step's own
+        # working set
+        return jax.jit(step, donate_argnums=(0, 1, 2))
 
     # -- slot lifecycle ----------------------------------------------------
-    def _pack(self, slot: int, u, v, w, params: Optional[AdvectParams],
-              extent: Tuple[int, int]) -> None:
+    def _up_shape(self, extent: Tuple[int, int]) -> Tuple[int, int, int]:
+        """The shape a slot write of `extent` uploads and a crop of it
+        downloads: the extent's `_bucket`."""
+        d = self.domain
+        return (_bucket(extent[0], d.X), _bucket(extent[1], d.Y), d.Z)
+
+    def _move_bytes(self, extent: Tuple[int, int]) -> int:
+        """u, v and w of `extent`'s bucket, in bytes."""
+        return 3 * int(np.prod(self._up_shape(extent))) * np.dtype(
+            self.domain.dtype).itemsize
+
+    def _put(self, slot: int, fields) -> None:
+        """Upload `fields` (``(Xr, Yr, Z)`` each) and write them into
+        `slot` of the device batch, zero outside them; no fields zero
+        the slot. Fields short of their bucket are padded on the host
+        first."""
+        d = self.domain
+        if fields is None:
+            up = (np.zeros((0, 0, d.Z), d.dtype),) * 3
+        else:
+            Xr, Yr = np.shape(fields[0])[:2]
+            shape = self._up_shape((Xr, Yr))
+            up = []
+            for f in fields:
+                if np.shape(f) != shape:
+                    a = np.zeros(shape, d.dtype)
+                    a[:Xr, :Yr] = f
+                    f = a
+                up.append(f)
+        dev = tuple(jnp.asarray(f, d.dtype) for f in up)
+        self.bytes_to_device += sum(a.nbytes for a in dev)
+        self.u, self.v, self.w = jax.block_until_ready(
+            _write_slot((self.u, self.v, self.w), slot, dev))
+
+    def _pack(self, slot: int, fields, params, extent: Tuple[int, int]
+              ) -> None:
         d = self.domain
         Xr, Yr = extent
-        for dst, src in ((self.u, u), (self.v, v), (self.w, w)):
-            dst[slot] = 0.0
-            dst[slot, :Xr, :Yr] = np.asarray(src, dst.dtype)
+        self._put(slot, fields)
         # freeze everything outside the request's own interior: its
         # boundary ring behaves exactly like the unpadded kernel's
         # structural walls, so padding is bitwise-invisible
@@ -334,7 +426,12 @@ class StencilServingEngine:
         (``n_steps == 0`` — the job's output is its initial state and it
         never occupies the slot)."""
         d = self.domain
-        nbytes = 3 * np.size(req.u) * np.dtype(d.dtype).itemsize
+        # what the prime moves: the upload, or the copy into `out` of a
+        # job complete at prime time
+        shp = np.shape(req.u)
+        nbytes = (0 if len(shp) != 3
+                  else self._move_bytes(shp[:2]) if req.n_steps
+                  else 3 * np.size(req.u) * np.dtype(d.dtype).itemsize)
         with _span("engine.prime", uid=req.uid, bytes=nbytes):
             if req.n_steps < 0:
                 raise ValueError(f"n_steps must be >= 0, got {req.n_steps} "
@@ -365,28 +462,21 @@ class StencilServingEngine:
                 raise ValueError(f"request {req.uid} params are not for "
                                  f"Z={d.Z}")
             req.states = []
-            crop = (np.asarray(req.u, np.dtype(d.dtype)).copy(),
-                    np.asarray(req.v, np.dtype(d.dtype)).copy(),
-                    np.asarray(req.w, np.dtype(d.dtype)).copy())
             if req.n_steps == 0:
-                req.out = crop
+                req.out = tuple(np.array(f, np.dtype(d.dtype))
+                                for f in (req.u, req.v, req.w))
                 req.status = "done"
                 return True
-            self._pack(slot, req.u, req.v, req.w, req.params, (Xr, Yr))
+            self._pack(slot, (req.u, req.v, req.w), req.params, (Xr, Yr))
             self.slots.occupy(slot, req, req.n_steps)
             req.status = "running"
             return False
 
     def _resume(self, slot: int, flight: _InFlight) -> None:
         """Re-pack a job displaced by a re-shard, from its in-flight state."""
-        nbytes = flight.u.nbytes + flight.v.nbytes + flight.w.nbytes
+        nbytes = self._move_bytes(flight.extent)
         with _span("engine.prime", uid=flight.req.uid, bytes=nbytes):
-            self.u[slot], self.v[slot], self.w[slot] = (flight.u, flight.v,
-                                                        flight.w)
-            self.xm[slot], self.ym[slot] = flight.xm, flight.ym
-            for dst, leaf in zip(self._p, flight.params):
-                dst[slot] = leaf
-            self._extent[slot] = flight.extent
+            self._pack(slot, flight.fields, flight.params, flight.extent)
             self.slots.occupy(slot, flight.req, flight.budget)
 
     def _clear(self, slot: int) -> None:
@@ -396,49 +486,56 @@ class StencilServingEngine:
         self.ym[slot] = 0.0
         self._extent[slot] = (0, 0)
 
-    def _crop(self, slot: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        Xr, Yr = self._extent[slot]
-        return (self.u[slot, :Xr, :Yr].copy(),
-                self.v[slot, :Xr, :Yr].copy(),
-                self.w[slot, :Xr, :Yr].copy())
+    def _download(self, slots: List[int]) -> Dict[int, Tuple[np.ndarray, ...]]:
+        """Each of `slots`' cropped ``(u, v, w)``: its bucket sliced on the
+        device and copied into host arrays of its own, of which the job
+        keeps its extent."""
+        crops = {s: _crop_slot((self.u, self.v, self.w), s,
+                               self._up_shape(self._extent[s])[:2])
+                 for s in slots}
+        for c in crops.values():          # every copy in flight at once
+            for a in c:
+                a.copy_to_host_async()
+        out = {}
+        for s, c in crops.items():
+            Xr, Yr = self._extent[s]
+            got = [np.asarray(a) for a in c]
+            self.bytes_to_host += sum(a.nbytes for a in got)
+            out[s] = tuple(a[:Xr, :Yr] for a in got)
+        return out
 
     # -- the mega-step -----------------------------------------------------
     def _host_arrays(self) -> Tuple[np.ndarray, ...]:
-        """The batch as the host holds it: fields, masks, coefficients."""
-        return (self.u, self.v, self.w, self.xm, self.ym, *self._p)
+        """What the host keeps of the batch: masks and coefficients."""
+        return (self.xm, self.ym, *self._p)
 
     def _mega_step(self) -> None:
         misses = self.cache.misses
         fn = self.cache.get(self._step_key(), self._build_step)
+        host = self._host_arrays()
+        nbytes = sum(a.nbytes for a in host)
         # each phase waits for its own work, so its time does not fall
         # into the next span; the kernel could not start before its
         # inputs arrived, nor the copies before its outputs, so the waits
         # add no work
-        with _span("engine.upload",
-                   bytes=sum(a.nbytes for a in self._host_arrays())):
-            p = AdvectParams(*[jnp.asarray(leaf) for leaf in self._p])
-            args = (jnp.asarray(self.u), jnp.asarray(self.v),
-                    jnp.asarray(self.w), p,
-                    jnp.asarray(self.xm), jnp.asarray(self.ym))
-            # one wait on the whole tuple: the transfers overlap
-            jax.block_until_ready(args)
+        with _span("engine.upload", bytes=nbytes):
+            xm, ym, *p = jax.block_until_ready(
+                [jnp.asarray(a) for a in host])
+            self.bytes_to_device += nbytes
         with _span("engine.device", cache_miss=int(self.cache.misses
                                                    > misses)):
-            res = jax.block_until_ready(fn(*args))
-        with _span("engine.download", bytes=sum(a.nbytes for a in res)):
-            if self._guard:
-                ou, ov, ow, gf = res
-                # a slot is healthy iff every x-slice flag word of its
-                # guard pass is 1.0 — the post-kernel isfinite pass
-                self._last_ok = np.asarray(gf).min(axis=1) > 0.0
-            else:
-                ou, ov, ow = res
-                self._last_ok = np.ones((self.B,), bool)
-            # np.array, not np.asarray: the device result is a read-only
-            # view and the next prime writes into these buffers in place
-            self.u = np.array(ou)
-            self.v = np.array(ov)
-            self.w = np.array(ow)
+            self.u, self.v, self.w, gf = jax.block_until_ready(
+                fn(self.u, self.v, self.w, AdvectParams(*p), xm, ym))
+        live = self.slots.live_slots()
+        crops = sum(self._move_bytes(self._extent[s]) for s in live)
+        with _span("engine.download", bytes=gf.nbytes + crops):
+            gf.copy_to_host_async()
+            self._fresh = self._download(live)
+            # a slot is healthy iff every x-slice flag word of its
+            # guard pass is 1.0 — the post-kernel isfinite pass
+            flags = np.asarray(gf)
+            self.bytes_to_host += flags.nbytes
+            self._last_ok = flags.min(axis=1) > 0.0
         self.steps_run += 1
         self.megasteps_executed += 1
 
@@ -499,18 +596,20 @@ class StencilServingEngine:
                     inj.skip(idx, f"slot {f.slot} not live at step "
                                   f"{self.steps_run}")
                     continue
-                arr = {"u": self.u, "v": self.v, "w": self.w}[f.field]
+                arr = getattr(self, f.field)
                 Xr, Yr = self._extent[f.slot]
                 if f.kind == "nan_poison":
                     # one interior cell: the stencil spreads it, the
                     # guard flags the whole slot this same step
-                    arr[f.slot, 1, 1, 0] = f.value()
+                    arr = arr.at[f.slot, 1, 1, 0].set(f.value())
                 else:
                     # a corrupted halo band: the mask freezes the
                     # boundary ring, so the poison SITS there (caught by
                     # the guard) but cannot re-enter the interior —
                     # one-shot, rollback + replay is clean
-                    arr[f.slot, :min(f.depth, Xr), :Yr, :] = f.value()
+                    arr = arr.at[f.slot, :min(f.depth, Xr), :Yr, :].set(
+                        f.value())
+                setattr(self, f.field, arr)
                 inj.mark_fired(idx)
                 inj.note(f"step {self.steps_run}: {f.kind} slot {f.slot} "
                          f"field {f.field} ({f.mode})")
@@ -540,24 +639,42 @@ class StencilServingEngine:
             out[r.uid] = r
         return out
 
+    def _held(self, slot: int) -> Tuple[np.ndarray, ...]:
+        """What the device holds in live `slot` at a mega-step boundary:
+        its job's last downloaded state, or its initial fields before
+        its first step."""
+        req = self.slots.request(slot)
+        return req.states[-1] if req.states else (req.u, req.v, req.w)
+
     def _take_snapshot(self, queue: List[Any], done: Dict[int, Any]) -> None:
-        with _span("engine.snapshot",
-                   bytes=sum(a.nbytes for a in self._host_arrays())):
-            arrays = {"u": self.u.copy(), "v": self.v.copy(),
-                      "w": self.w.copy(), "xm": self.xm.copy(),
-                      "ym": self.ym.copy()}
+        d = self.domain
+        live = self.slots.live_slots()
+        host = self._host_arrays()
+        nbytes = sum(a.nbytes for a in host)
+        if self._snapshot_dir is not None:
+            nbytes += 3 * self.B * d.X * d.Y * d.Z * np.dtype(d.dtype).itemsize
+        with _span("engine.snapshot", bytes=nbytes):
+            arrays = {"xm": self.xm.copy(), "ym": self.ym.copy()}
             for i, leaf in enumerate(self._p):
                 arrays[f"p{i}"] = leaf.copy()
+            fields = {s: self._held(s) for s in live}
             reqs = self._reachable(queue)
             disk_step = None
             if self._snapshot_dir is not None:
+                shape = (self.B, d.X, d.Y, d.Z)
+                for k in range(3):
+                    a = np.zeros(shape, d.dtype)
+                    for s, f in fields.items():
+                        Xr, Yr = self._extent[s]
+                        a[s, :Xr, :Yr] = f[k]
+                    arrays["uvw"[k]] = a
                 CKPT.save(self._snapshot_dir, arrays, self.steps_run)
                 disk_step = self.steps_run
             self._snap = _Snapshot(
-                steps_run=self.steps_run, B=self.B, arrays=arrays,
-                extents=list(self._extent),
+                steps_run=self.steps_run, B=self.B, fields=fields,
+                arrays=arrays, extents=list(self._extent),
                 live=[(s, self.slots.request(s).uid, self.slots.budget(s))
-                      for s in self.slots.live_slots()],
+                      for s in live],
                 reqs=reqs,
                 states_len={uid: (len(r.states) if r.states is not None
                                   else -1)
@@ -569,21 +686,23 @@ class StencilServingEngine:
                   reason: str) -> None:
         """Restore the last snapshot and replay from it. Quarantined
         jobs stay quarantined (their slot comes back empty); everything
-        else — arrays, slot assignments, budgets, streamed states, the
-        queue, the step counter — returns to the boundary, so the replay
-        is bitwise-indistinguishable from a run that never faulted."""
+        else — the device batch, slot assignments, budgets, streamed
+        states, the queue, the step counter — returns to the boundary, so
+        the replay is bitwise-indistinguishable from a run that never
+        faulted."""
         snap = self._snap
         assert snap is not None
-        arrays = snap.arrays
+        arrays, fields = snap.arrays, snap.fields
         if self._snapshot_dir is not None and snap.disk_step is not None:
             # restore through the checkpoint machinery: the atomic
             # on-disk copy is the recovery point, not host memory
             arrays, _ = CKPT.restore(self._snapshot_dir, snap.arrays,
                                      step=snap.disk_step)
+            fields = {s: tuple(arrays[k][s, :snap.extents[s][0],
+                                         :snap.extents[s][1]]
+                               for k in "uvw")
+                      for s, _, _ in snap.live}
         self._alloc(snap.B)
-        self.u[:] = arrays["u"]
-        self.v[:] = arrays["v"]
-        self.w[:] = arrays["w"]
         self.xm[:] = arrays["xm"]
         self.ym[:] = arrays["ym"]
         for i in range(len(self._p)):
@@ -592,9 +711,8 @@ class StencilServingEngine:
         for slot, uid, budget in snap.live:
             if uid in self._quarantined:
                 self._clear(slot)
-                for arr in (self.u, self.v, self.w):
-                    arr[slot] = 0.0
                 continue
+            self._put(slot, fields[slot])
             self.slots.occupy(slot, snap.reqs[uid], budget)
         for uid, req in snap.reqs.items():
             if uid in self._quarantined:
@@ -626,8 +744,7 @@ class StencilServingEngine:
         self._quarantined.add(req.uid)
         self.slots.release(slot)
         self._clear(slot)
-        for arr in (self.u, self.v, self.w):
-            arr[slot] = 0.0
+        self._put(slot, None)
         self._injector.record("quarantines")
         self._injector.note(f"quarantined uid {req.uid} (slot {slot}): "
                             f"{reason}")
@@ -638,23 +755,24 @@ class StencilServingEngine:
         """Re-shard the engine onto `new_batch_size` slots (a simulated
         device loss took the rest, or devices returned — resharding UP
         works the same way): live jobs are detached with their in-flight
-        state, the batch arrays are re-allocated (a NEW cache key — the
-        next mega-step records a miss and re-traces), and as many jobs
-        as fit are re-packed immediately. Jobs that no longer fit are
-        returned for the caller (`run`) to resume — state intact, budget
-        intact — when slots free up. Slot independence makes the re-pack
-        bitwise-invisible to every job's output."""
+        state as the host holds it (a lost device cannot be read back),
+        the batch is re-allocated (a NEW cache key — the next mega-step
+        records a miss and re-traces), and as many jobs as fit are
+        re-packed immediately.
+        Jobs that no longer fit are returned for the caller (`run`) to
+        resume — state intact, budget intact — when slots free up. Slot
+        independence makes the re-pack bitwise-invisible to every job's
+        output."""
         if new_batch_size < 1:
             raise ValueError(f"new_batch_size must be >= 1, got "
                              f"{new_batch_size}")
+        live = self.slots.live_slots()
         flights = [
             _InFlight(req=self.slots.request(s), budget=self.slots.budget(s),
-                      u=self.u[s].copy(), v=self.v[s].copy(),
-                      w=self.w[s].copy(), xm=self.xm[s].copy(),
-                      ym=self.ym[s].copy(),
+                      fields=self._held(s),
                       params=tuple(leaf[s].copy() for leaf in self._p),
                       extent=self._extent[s])
-            for s in self.slots.live_slots()]
+            for s in live]
         self._alloc(new_batch_size)
         for slot, flight in enumerate(flights[:new_batch_size]):
             self._resume(slot, flight)
@@ -736,11 +854,10 @@ class StencilServingEngine:
                         done[req.uid] = req
                 for s in self.slots.live_slots():
                     req = self.slots.request(s)
-                    Xr, Yr = self._extent[s]
-                    with _span("engine.crop", uid=req.uid,
-                               bytes=3 * Xr * Yr * self.domain.Z
-                               * self.u.itemsize):
-                        state = self._crop(s)
+                    # the state came down in `engine.download`; this is
+                    # the job's bookkeeping and moves no bytes
+                    with _span("engine.crop", uid=req.uid, bytes=0):
+                        state = self._fresh.pop(s)
                         req.states.append(state)
                         if self.slots.tick(s):
                             req.out = state
@@ -758,12 +875,16 @@ class StencilServingEngine:
         """The fault/recovery counters surface: everything the injector
         recorded (faults seen, retries, quarantines, rollbacks,
         degradations, reshards, snapshots) plus the live exchange rung,
-        the quarantined uids, and the executable-cache stats. Printed by
-        `launch/serve.py` and gated by `benchmarks/fault_sweep.py`."""
+        the quarantined uids, the executable-cache stats, and the running
+        totals of field, mask and coefficient bytes moved to and from the
+        device. Printed by `launch/serve.py` and gated by
+        `benchmarks/fault_sweep.py`."""
         h = self._injector.health()
         h["exchange"] = self._ladder.current
         h["quarantined_uids"] = sorted(self._quarantined)
         h["cache"] = self.cache_stats()
+        h["bytes_to_device"] = self.bytes_to_device
+        h["bytes_to_host"] = self.bytes_to_host
         return h
 
     def guard_bytes_per_step(self) -> int:

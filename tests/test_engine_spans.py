@@ -6,6 +6,7 @@ read with `jax.profiler.ProfileData`. Each span's parent is the
 innermost engine span around it on the same thread line.
 """
 import collections
+import math
 
 import jax
 import numpy as np
@@ -90,20 +91,35 @@ def test_spans_nest_under_the_span_that_caused_them(spans):
         assert all(a[4] <= b[3] for a, b in zip(kids, kids[1:]))
 
 
+def _job_bytes(Xr, Yr):
+    # the job's u, v, w, padded to the power of two at or above each
+    # extent (at most the slot's): what a prime or download moves
+    def bucket(n, cap):
+        return min(2 ** math.ceil(math.log2(n)), cap)
+    return 3 * bucket(Xr, X) * bucket(Yr, Y) * Z * 4
+
+
 def test_per_job_spans_carry_uid_and_bytes(spans):
-    for name in ("engine.prime", "engine.crop"):
+    # a prime uploads the job's fields; a crop only files the state the
+    # download brought back, and moves nothing
+    for name, per_job in (("engine.prime", _job_bytes),
+                          ("engine.crop", lambda Xr, Yr: 0)):
         got = sorted((sp[5]["uid"], sp[5]["bytes"]) for sp in spans
                      if sp[2] == name)
-        assert got == [(uid, 3 * Xr * Yr * Z * 4)
+        assert got == [(uid, per_job(Xr, Yr))
                        for uid, (Xr, Yr) in enumerate(EXTENTS)], name
-    fields = 3 * B * X * Y * Z * 4          # u, v, w of every slot
-    # the batch as the host holds it: fields, masks, each slot's
-    # coefficients; what comes back: fields and the guard's flags
-    host = (fields + B * (X + Y) * 4
+    # what the host keeps of the batch, uploaded each mega-step and
+    # copied by each snapshot: masks and each slot's coefficients
+    host = (B * (X + Y) * 4
             + B * sum(np.asarray(leaf).nbytes for leaf in _dom().params))
-    for name, want in (("engine.upload", host), ("engine.snapshot", host),
-                       ("engine.download", fields + B * X * 4)):
-        assert {sp[5]["bytes"] for sp in spans if sp[2] == name} == {want}
+    for name in ("engine.upload", "engine.snapshot"):
+        assert {sp[5]["bytes"] for sp in spans if sp[2] == name} == {host}
+    # what comes back: the guard's flags and each live slot's crop
+    down = sorted((sp[3], sp[5]["bytes"]) for sp in spans
+                  if sp[2] == "engine.download")
+    assert [b for _, b in down] == [
+        B * X * 4 + sum(_job_bytes(*e) for e in EXTENTS[k:k + B])
+        for k in range(0, len(EXTENTS), B)]
     (run,) = [sp for sp in spans if sp[2] == "engine.run"]
     assert run[5]["requests"] == len(EXTENTS)
     steps = sorted(sp[5]["step"] for sp in spans

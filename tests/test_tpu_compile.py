@@ -10,8 +10,9 @@ layouts, VMEM and HBM footprint; it says nothing about results or time
 Covered, at real size: `advect_fused` on the 268M-cell grid with the
 y-tile `chip_smoke.py` uses, `finite_guard` at 268M, the serving
 mega-step (`advect_fused_batched`, guard on) at the `launch/serve.py
---stencil` slot shape, the 1x1 `make_distributed_run` with donated
-fields, and the 2x2 `make_distributed_run` with both exchange engines.
+--stencil` slot shape, the serving engine's donated mega-step, slot
+write and crop at the ensemble cell's slot shape, the 1x1
+`make_distributed_run` with donated fields, and the 2x2 `make_distributed_run` with both exchange engines.
 Each program's text also names its kernels (`name=` on every
 `pallas_call`) and the run's block phases (`jax.named_scope`), the names
 a device trace shows. The topology is described inside a fixture, so
@@ -127,6 +128,40 @@ def test_serving_mega_step_compiles(one_chip):
     assert _n_kernels(c) == 2          # the fused mega-launch + the guard
     assert _names(c, "%vmap_advect_fused_", "vmap(advect_fused)/pallas_call",
                   "%finite_guard", "finite_guard/pallas_call")
+
+
+def test_serving_device_batch_is_updated_in_place(one_chip):
+    """At the ensemble cell's size (13 slots of 16x1024x64, y_tile 128)
+    the engine's mega-step writes its fields over the donated batch, a
+    prime writes one slot of it in place, and a crop makes one slot's
+    fields alone: the device holds one batch besides the step's own
+    working set."""
+    from repro.serving import stencil_engine as E
+    from repro.stencil.advection import AdvectionDomain
+
+    B, X, Y, Z = 13, 16, 1024, 64
+    eng = E.StencilServingEngine(
+        AdvectionDomain(X, Y, Z, variant="fused", fuse_T=T, dt=0.005,
+                        y_tile=128, interpret=False), batch_size=B)
+    fields = 3 * B * X * Y * Z * 4
+    f = jax.ShapeDtypeStruct((B, X, Y, Z), jnp.float32, sharding=one_chip)
+    p = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((B,) + a.shape, a.dtype,
+                                       sharding=one_chip),
+        default_params(Z))
+    xm = jax.ShapeDtypeStruct((B, X), jnp.float32, sharding=one_chip)
+    ym = jax.ShapeDtypeStruct((B, Y), jnp.float32, sharding=one_chip)
+    step = eng._build_step().lower(f, f, f, p, xm, ym).compile()
+    assert _n_kernels(step) == 2
+    assert step.memory_analysis().alias_size_in_bytes == fields
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    job = jax.ShapeDtypeStruct((X, Y, Z), jnp.float32, sharding=one_chip)
+    write = E._write_slot.lower((f,) * 3, slot, (job,) * 3).compile()
+    assert write.memory_analysis().alias_size_in_bytes == fields
+    crop = E._crop_slot.lower((f,) * 3, slot, (X, Y)).compile()
+    # one slot's three fields, and the output tuple's small table
+    out = crop.memory_analysis().output_size_in_bytes
+    assert fields // B <= out < fields // B + 4096
 
 
 def test_run_268m_one_chip_fits(topo):
