@@ -1143,7 +1143,9 @@ def halo_band_exchange_dma(u, v, w, *, axis: str, mesh_axes, n: int,
     `dynamic_index_in_dim` on the outputs), so the pipelined multi-block
     driver alternates slots inside one traced program instead of
     rebuilding per block. `collective_id` must differ between the x and y
-    phases so their barrier semaphores stay distinct.
+    phases so their barrier semaphores stay distinct. The kernel is named
+    by its phase (`halo_band_exchange_dma_x` for dim 0, `_y` for dim 1),
+    so a device trace tells the two apart.
     """
     if dim not in (0, 1):
         raise ValueError(f"dim must be 0 (x-planes) or 1 (y-rows), got {dim}")
@@ -1165,7 +1167,7 @@ def halo_band_exchange_dma(u, v, w, *, axis: str, mesh_axes, n: int,
         scratch_shapes=[pltpu.SemaphoreType.DMA((nb,)),    # remote send
                         pltpu.SemaphoreType.DMA((nb,))],   # remote recv
         compiler_params=pltpu.CompilerParams(collective_id=collective_id),
-        name="halo_band_exchange_dma",
+        name=f"halo_band_exchange_dma_{'xy'[dim]}",
     )
     block = jnp.asarray(block_index, jnp.int32)
     outs = fn(block.reshape((1,)), *sends)

@@ -12,7 +12,8 @@ y-tile `chip_smoke.py` uses, `finite_guard` at 268M, the serving
 mega-step (`advect_fused_batched`, guard on) at the `launch/serve.py
 --stencil` slot shape, the serving engine's donated mega-step, slot
 write and crop at the ensemble cell's slot shape, the 1x1
-`make_distributed_run` with donated fields, and the 2x2 `make_distributed_run` with both exchange engines.
+`make_distributed_run` with donated fields, the 2x2 `make_distributed_run` with both exchange engines, and the 2x2
+remote-DMA cell's 4096x2048x64 run with donated fields.
 Each program's text also names its kernels (`name=` on every
 `pallas_call`) and the run's block phases (`jax.named_scope`), the names
 a device trace shows. The topology is described inside a fixture, so
@@ -200,4 +201,25 @@ def test_run_268m_2x2_compiles(topo, exchange):
     assert _names(c, "block/exchange_x/", "block/exchange_y/",
                   "block/compute/advect_fused")
     assert ("halo_band_exchange_dma" in text) == (exchange == "remote_dma")
+    assert _hbm(c) <= HBM_BYTES
+
+
+def test_run_537m_2x2_remote_dma_cell_fits(topo):
+    """The `integ-537m-2x2-dma` cell's program: 4096x2048x64 split 2x2,
+    remote-DMA exchange, donated, 3 blocks a call. Each phase's DMA
+    kernel carries its own name, so a device trace tells them apart."""
+    grid = (4096, 2048, 64)
+    mesh = _mesh(topo, 2, 2)
+    fmt = D.field_formats(mesh, axis="y", x_axis="x")
+    run = D.make_distributed_run(mesh, default_params(grid[2]), n_blocks=3,
+                                 axis="y", x_axis="x", T=T, dt=0.01,
+                                 local_kernel="fused", y_tile=Y_TILE,
+                                 exchange="remote_dma", donate=True)
+    f = jax.ShapeDtypeStruct(grid, jnp.float32, sharding=fmt)
+    c = jax.jit(run, in_shardings=(fmt,) * 3, out_shardings=(fmt,) * 3,
+                donate_argnums=(0, 1, 2)).lower(f, f, f).compile()
+    assert _n_kernels(c) == 3
+    assert _names(c, "%halo_band_exchange_dma_x", "%halo_band_exchange_dma_y",
+                  "block/compute/advect_fused")
+    # per chip: 3 GiB of fields in (aliased out), ~6 GiB of extended slabs
     assert _hbm(c) <= HBM_BYTES
