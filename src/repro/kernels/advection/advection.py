@@ -101,7 +101,9 @@ the 8-row sublane tile (`_grid_geometry(align=8)` rounds the fetch halo up),
 coefficient rows ``(1, Z+2)`` and mask columns ``(N, 1)`` instead of rank-1
 vectors, tiled outputs copied out of a VMEM slab at a dynamic row offset,
 and the guard's flags in SMEM. Fields stay in the row-major HBM layout
-`field_format` names.
+`field_format` names. The fused kernel keeps its ring lane-full: Z rounded
+up to the 128-lane vreg, sources computed on whole slices at offset 0 with
+neighbours by `pltpu.roll`, one coefficient row ``(1, lanes)`` each.
 
 Validated with interpret=True against ref.pw_advect_ref, the f64 oracle, and
 the multi-step f64 oracle (fused) across shape/dtype/T/y_tile sweeps in
@@ -127,6 +129,7 @@ from repro.launch.mesh import dma_neighbor_coords
 TILINGS = ("grid", "host")
 _WIDE_HALO = 8   # sublane-rounded fetch halo: keeps wide's (8,128) contract
 _SUBLANE = 8     # f32 sublane tile: compiled slab/output row offsets align
+_LANE = 128      # vreg lane width: the compiled fused ring's Z rounds up
 
 
 def resolve_interpret(interpret: Optional[bool] = None, *arrays,
@@ -532,8 +535,54 @@ def advect_wide(u, v, w, p: AdvectParams, *,
 # ---------------------------------------------------------------------------
 
 
-def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
-                  *refs, X, Y, TY, S, T, dt, align):
+def _ring_lanes(Z: int, interpret: bool) -> int:
+    """Lane width of the fused kernel's ring: Z rounded up to the 128-lane
+    vreg when compiled, so a z-neighbour is one lane rotate of whole
+    vregs; Z itself in interpret mode (a lane tile of 1)."""
+    lane = 1 if interpret else _LANE
+    return -(-Z // lane) * lane
+
+
+def _pack_coeff_rows(p: AdvectParams, Zl: int):
+    """(tcx, tcy, tzc1, tzc2) as four ``(1, Zl)`` rows: the scalars
+    broadcast over every lane, the z-metrics zero-padded past Z. One row
+    per coefficient, since Mosaic broadcasts a row over sublanes but not
+    a ``(1, 1)`` value over both axes, nor a row sliced at a lane offset."""
+    row = lambda a: jnp.broadcast_to(a, (Zl,))[None, :]
+    pad = lambda a: jnp.pad(a, (0, Zl - a.shape[0]))[None, :]
+    return row(p.tcx), row(p.tcy), pad(p.tzc1), pad(p.tzc2)
+
+
+def _neighbours(f):
+    """(f[y-1], f[y+1], f[z-1], f[z+1]) of a ``(rows, lanes)`` slab at
+    offset 0: one sublane or lane rotation each. Wrapped values land in
+    the slab's edge rows and edge lanes, which the caller masks."""
+    S, Zl = f.shape
+    return (pltpu.roll(f, 1, 0), pltpu.roll(f, S - 1, 0),
+            pltpu.roll(f, 1, 1), pltpu.roll(f, Zl - 1, 1))
+
+
+def _source_rolled(um, uc, up, vm, vc, vp, wm, wc, wp, tcx, tcy, t1, t2):
+    """`_source_slices` on the whole slab at offset 0: the same expression
+    tree, term by term and operand by operand, with each y/z neighbour a
+    rotation of the centre slice (`_neighbours`, once per field) in place
+    of an offset slice. Valid on rows 1..S-2 and lanes 1..Z-2; the
+    coefficients are the ``(1, Zl)`` rows of `_pack_coeff_rows`."""
+    nu, nv, nw = _neighbours(uc), _neighbours(vc), _neighbours(wc)
+
+    def source(fm, fc, fp, nb):
+        y_m, y_p, z_m, z_p = nb
+        fx = tcx * (um * (fc + fm) - up * (fc + fp))
+        fy = tcy * (nv[0] * (fc + y_m) - nv[1] * (fc + y_p))
+        fz = (t1 * nw[2] * (fc + z_m) - t2 * nw[3] * (fc + z_p))
+        return fx + fy + fz
+
+    return (source(um, uc, up, nu), source(vm, vc, vp, nv),
+            source(wm, wc, wp, nw))
+
+
+def _kernel_fused(tcx_ref, tcy_ref, t1_ref, t2_ref, xm_ref, ym_ref,
+                  u_ref, v_ref, w_ref, *refs, X, Y, Z, TY, S, T, dt, align):
     """T stacked 3-slice rings: level k holds the step-k fields.
 
     At grid step (t, i) the newly-arrived input slice x=i of tile t's slab
@@ -542,6 +591,13 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
     j+k-1, so for every level the (x-1, x, x+1) operands sit at ring slots
     ((i+1)%3, (i+2)%3, i%3) and every level writes slot i%3 — the same
     rotation as v2, T-deep.
+
+    Each ring slice is ``(S, Zl)``: the slab's Z lanes at offset 0, padded
+    to `_ring_lanes`. A level computes its sources on the whole slice
+    (`_source_rolled`: y/z neighbours by rotation) and one mask keeps them
+    to rows 1..S-2 and lanes 1..Z-2 — exactly the cells an offset slice
+    re-padded with zero edges would cover; every value a rotation wraps,
+    and every pad lane, lands outside it.
 
     Startup/tail slices (x<0 or x>X-1) are garbage but provably walled off:
     a level's x=0 / x=X-1 output is a masked copy of its centre operand, and
@@ -567,31 +623,35 @@ def _kernel_fused(t1_ref, t2_ref, xm_ref, ym_ref, u_ref, v_ref, w_ref,
     """
     ou_ref, ov_ref, ow_ref, ubuf, vbuf, wbuf = refs[:6]
     obuf = refs[6] if len(refs) > 6 else None
+    Zl = ubuf.shape[-1]
     t = pl.program_id(0)
     i = pl.program_id(1)
     slot = jax.lax.rem(i, 3)
     m, c = jax.lax.rem(i + 1, 3), jax.lax.rem(i + 2, 3)
-    row_ok = ym_ref[...] > 0.0
-    coeffs = _coeffs(t1_ref, t2_ref)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, Zl), 1)
+    row_ok = (ym_ref[...] > 0.0) & (rows >= 1) & (rows <= S - 2)
+    col_ok = (cols >= 1) & (cols <= Z - 2)
+    coeffs = (tcx_ref[...], tcy_ref[...], t1_ref[...], t2_ref[...])
     for buf, ref in ((ubuf, u_ref), (vbuf, v_ref), (wbuf, w_ref)):
-        buf[0, slot] = ref[0]
+        buf[0, slot, :, 0:Z] = ref[0]
     outs = None
     for k in range(1, T + 1):
         j = i - k
         args = [ubuf[k - 1, m], ubuf[k - 1, c], ubuf[k - 1, slot],
                 vbuf[k - 1, m], vbuf[k - 1, c], vbuf[k - 1, slot],
                 wbuf[k - 1, m], wbuf[k - 1, c], wbuf[k - 1, slot]]
-        su, sv, sw = _source_slices(*args, *coeffs)
+        su, sv, sw = _source_rolled(*args, *coeffs)
         interior = (j >= 1) & (j <= X - 2) & _plane_ok(xm_ref, j, X)
+        ok = jnp.broadcast_to(interior & row_ok, (S, Zl)) & col_ok
         new = []
         for cen, s in ((args[1], su), (args[4], sv), (args[7], sw)):
-            src = jnp.where(interior & row_ok, _pad_edges(s),
-                            0.0).astype(cen.dtype)
+            src = jnp.where(ok, s, 0.0).astype(cen.dtype)
             new.append(cen + dt * src)
         if k < T:
             ubuf[k, slot], vbuf[k, slot], wbuf[k, slot] = new
         else:
-            outs = new
+            outs = [o[:, 0:Z] for o in new]
     _write_owned(zip((ou_ref, ov_ref, ow_ref), outs), obuf,
                  _own_start(t, Y, TY, S, align), TY)
 
@@ -684,23 +744,24 @@ def advect_fused(u, v, w, p: AdvectParams, *, T: int = 4, dt: float = 1.0,
     xm, ym = _masks(x_interior_mask, y_interior_mask, X, Y)
     in_spec, out_spec, ym_spec = _slab_specs(X, Y, Z, TY, S, T, align)
     xm_spec = pl.BlockSpec((X, 1), lambda t, i: (0, 0))
-    t1, t2 = _pack_coeffs(p)
-    tz_spec = pl.BlockSpec(t1.shape, lambda t, i: (0, 0))
+    Zl = _ring_lanes(Z, interpret)
+    coeffs = _pack_coeff_rows(p, Zl)
+    c_spec = pl.BlockSpec((1, Zl), lambda t, i: (0, 0))
     fn = pl.pallas_call(
-        functools.partial(_kernel_fused, X=X, Y=Y, TY=TY, S=S, T=T, dt=dt,
-                          align=align),
+        functools.partial(_kernel_fused, X=X, Y=Y, Z=Z, TY=TY, S=S, T=T,
+                          dt=dt, align=align),
         grid=(n_ty, X + T),
-        in_specs=[tz_spec, tz_spec, xm_spec, ym_spec,
-                  in_spec, in_spec, in_spec],
+        in_specs=[c_spec] * 4 + [xm_spec, ym_spec,
+                                 in_spec, in_spec, in_spec],
         out_specs=[out_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((X, Y, Z), u.dtype)] * 3,
-        scratch_shapes=([pltpu.VMEM((T, 3, S, Z), u.dtype)
+        scratch_shapes=([pltpu.VMEM((T, 3, S, Zl), u.dtype)
                          for _ in range(3)]
                         + _out_scratch(TY, S, Z, u.dtype)),
         interpret=interpret,
         name="advect_fused",
     )
-    ou, ov, ow = fn(t1, t2, xm, ym, u, v, w)
+    ou, ov, ow = fn(*coeffs, xm, ym, u, v, w)
     if guard:
         return ou, ov, ow, finite_guard(ou, ov, ow, interpret=interpret)
     return ou, ov, ow

@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.advection import advection as K
 from repro.kernels.advection.advection import (advect_dataflow, advect_fused,
+                                               advect_fused_batched,
                                                fused_register_bytes,
                                                hbm_bytes_model)
 from repro.kernels.advection.ref import (default_params, pw_multistep_ref_f64,
@@ -162,6 +164,103 @@ def test_fused_x_interior_mask_contract_checks():
     with pytest.raises(ValueError):   # host tiling cannot thread the mask
         advect_fused(u, v, w, p, T=2, y_tile=6, tiling="host",
                      x_interior_mask=jnp.ones((X,)))
+
+
+# --- the lane-full ring: sources by rotation on the whole slab ------------
+
+def _shard_walls(X, Y):
+    """Interior masks of a 2x2-decomposed shard: wrapped halo planes and
+    rows frozen on both sides, as `make_distributed_run` passes them."""
+    xm = np.ones((X,), np.float32)
+    xm[:2] = xm[-1:] = 0.0
+    ym = np.ones((Y,), np.float32)
+    ym[:3] = ym[-2:] = 0.0
+    return jnp.asarray(xm), jnp.asarray(ym)
+
+
+def _masked_f32_ref(u, v, w, p, T, xm, ym):
+    """T plain f32 Euler steps of `pw_advect_ref`, each source applied only
+    where both interior masks are nonzero."""
+    from repro.kernels.advection.ref import pw_advect_ref
+    m = ((xm[:, None] > 0) & (ym[None, :] > 0))[:, :, None]
+    for _ in range(T):
+        su, sv, sw = pw_advect_ref(u, v, w, p)
+        u, v, w = (f + DT * jnp.where(m, s, 0.0)
+                   for f, s in ((u, su), (v, sv), (w, sw)))
+    return u, v, w
+
+
+def _abs_err(got, want):
+    return max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("Z", [64, 128])
+@pytest.mark.parametrize("T", [1, 4])
+def test_fused_rolled_ring_matches_f32_reference(Z, T):
+    """The 2x2 path's local block — y-tiled, both interior masks — and the
+    batched serving launch against the plain f32 reference."""
+    X, Y = 6, 24
+    u, v, w = fields((X, Y, Z), seed=11)
+    p = default_params(Z)
+    xm, ym = _shard_walls(X, Y)
+    want = _masked_f32_ref(u, v, w, p, T, xm, ym)
+    got = advect_fused(u, v, w, p, T=T, dt=DT, y_tile=8,
+                       x_interior_mask=xm, y_interior_mask=ym)
+    assert _abs_err(got, want) < 1e-6, (Z, T)
+    # two slots, one with the shard's walls and one without
+    u2, v2, w2 = fields((X, Y, Z), seed=12)
+    ones = (jnp.ones((X,)), jnp.ones((Y,)))
+    bxm, bym = jnp.stack([xm, ones[0]]), jnp.stack([ym, ones[1]])
+    out = advect_fused_batched(jnp.stack([u, u2]), jnp.stack([v, v2]),
+                               jnp.stack([w, w2]), p, T=T, dt=DT, y_tile=8,
+                               x_interior_mask=bxm, y_interior_mask=bym)
+    want2 = _masked_f32_ref(u2, v2, w2, p, T, *ones)
+    assert _abs_err([o[0] for o in out], want) < 1e-6, (Z, T)
+    assert _abs_err([o[1] for o in out], want2) < 1e-6, (Z, T)
+
+
+@pytest.mark.parametrize("Z", [64, 100])
+@pytest.mark.parametrize("T,y_tile", [(1, None), (4, 8)])
+def test_fused_lane_padded_ring_in_interpret_mode(monkeypatch, Z, T,
+                                                  y_tile):
+    """The compiled kernel's ring, forced on the CPU: Z padded to 128
+    lanes. The interpreter fills scratch with NaN, so a pad lane or a
+    wrapped rotation leaking into any output would show; none does, and
+    the result is the unpadded ring's, bit for bit."""
+    X, Y = 6, 24
+    u, v, w = fields((X, Y, Z), seed=13)
+    p = default_params(Z)
+    xm, ym = _shard_walls(X, Y)
+    kw = dict(T=T, dt=DT, y_tile=y_tile, x_interior_mask=xm,
+              y_interior_mask=ym)
+    base = advect_fused(u, v, w, p, **kw)
+    monkeypatch.setattr(K, "_ring_lanes", lambda Z, interpret: 128)
+    padded = advect_fused(u, v, w, p, **kw)
+    for a, b in zip(padded, base):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    want = _masked_f32_ref(u, v, w, p, T, xm, ym)
+    assert _abs_err(padded, want) < 1e-6, (Z, T, y_tile)
+
+
+@pytest.mark.parametrize("lanes", [None, 128])
+@pytest.mark.parametrize("y_tile", [None, 4])
+def test_fused_wall_cells_bitwise_unchanged(monkeypatch, lanes, y_tile):
+    """Every face of the domain — x, y and z, first and last — keeps its
+    initial values through T substeps, on the plain and the 128-lane ring,
+    tiled or not: where the rotations wrap, the mask holds."""
+    if lanes is not None:
+        monkeypatch.setattr(K, "_ring_lanes", lambda Z, interpret: lanes)
+    shape = (6, 13, 10)
+    u, v, w = fields(shape, seed=14)
+    out = advect_fused(u, v, w, default_params(shape[2]), T=3, dt=DT,
+                       y_tile=y_tile)
+    faces = (np.s_[0], np.s_[-1], np.s_[:, 0], np.s_[:, -1],
+             np.s_[:, :, 0], np.s_[:, :, -1])
+    for f0, fT in zip((u, v, w), out):
+        assert not np.array_equal(np.asarray(fT), np.asarray(f0))
+        for face in faces:
+            np.testing.assert_array_equal(np.asarray(fT)[face],
+                                          np.asarray(f0)[face])
 
 
 # --- VMEM budget: the Y-tiled register is bounded irrespective of Y --------

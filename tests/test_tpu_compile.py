@@ -88,14 +88,38 @@ def _names(compiled, *names) -> bool:
     return all(n in text for n in names)
 
 
+def _fused_rings(traced):
+    """The ring scratch of every `advect_fused` kernel in a traced program:
+    one ``(T, 3, S, lanes)`` shape per field, in order."""
+    from repro.analysis.jaxpr import walk_jaxpr
+    rings = []
+
+    def visit(eqn):
+        if (eqn.primitive.name == "pallas_call"
+                and "advect_fused" in str(eqn.params.get("name"))):
+            n = eqn.params["grid_mapping"].num_scratch_operands
+            scratch = eqn.params["jaxpr"].invars[-n:]
+            rings.extend(tuple(v.aval.shape) for v in scratch[:3])
+
+    walk_jaxpr(traced.jaxpr.jaxpr, visit)
+    return rings
+
+
+# the compiled ring: slab rows 128 + 2 x the T=4 halo rounded up to the
+# 8-row sublane tile; Z=64 widened to the 128-lane vreg
+RING_268M = (T, 3, Y_TILE + 2 * 8, 128)
+
+
 def test_fused_268m_compiles(one_chip):
     fmt = K.field_format(one_chip)
     f = jax.ShapeDtypeStruct(GRID, jnp.float32, sharding=fmt)
-    c = jax.jit(
+    tr = jax.jit(
         lambda u, v, w, p: K.advect_fused(u, v, w, p, T=T, dt=0.01,
                                           interpret=False, y_tile=Y_TILE),
         out_shardings=(fmt,) * 3,
-    ).lower(f, f, f, _params(one_chip)).compile()
+    ).trace(f, f, f, _params(one_chip))
+    assert _fused_rings(tr) == [RING_268M] * 3
+    c = tr.lower().compile()
     assert _n_kernels(c) == 1
     assert _names(c, "%advect_fused", "advect_fused/pallas_call")
     # fields in the kernel's layout: three in, three out, nothing else
@@ -121,11 +145,13 @@ def test_serving_mega_step_compiles(one_chip):
         default_params(Z))
     xm = jax.ShapeDtypeStruct((B, X), jnp.float32, sharding=one_chip)
     ym = jax.ShapeDtypeStruct((B, Y), jnp.float32, sharding=one_chip)
-    c = jax.jit(
+    tr = jax.jit(
         lambda u, v, w, p, xm, ym: K.advect_fused_batched(
             u, v, w, p, T=T, dt=0.005, interpret=False, x_interior_mask=xm,
             y_interior_mask=ym, guard=True),
-    ).lower(f, f, f, p, xm, ym).compile()
+    ).trace(f, f, f, p, xm, ym)
+    assert _fused_rings(tr) == [(T, 3, Y, 128)] * 3    # untiled slab
+    c = tr.lower().compile()
     assert _n_kernels(c) == 2          # the fused mega-launch + the guard
     assert _names(c, "%vmap_advect_fused_", "vmap(advect_fused)/pallas_call",
                   "%finite_guard", "finite_guard/pallas_call")
@@ -216,8 +242,11 @@ def test_run_537m_2x2_remote_dma_cell_fits(topo):
                                  local_kernel="fused", y_tile=Y_TILE,
                                  exchange="remote_dma", donate=True)
     f = jax.ShapeDtypeStruct(grid, jnp.float32, sharding=fmt)
-    c = jax.jit(run, in_shardings=(fmt,) * 3, out_shardings=(fmt,) * 3,
-                donate_argnums=(0, 1, 2)).lower(f, f, f).compile()
+    tr = jax.jit(run, in_shardings=(fmt,) * 3, out_shardings=(fmt,) * 3,
+                 donate_argnums=(0, 1, 2)).trace(f, f, f)
+    # each chip's 1032-row extended slab runs the 268M grid's tiles
+    assert _fused_rings(tr) == [RING_268M] * 3
+    c = tr.lower().compile()
     assert _n_kernels(c) == 3
     assert _names(c, "%halo_band_exchange_dma_x", "%halo_band_exchange_dma_y",
                   "block/compute/advect_fused")
