@@ -1,5 +1,12 @@
-"""Distributed PW advection: the 2D-decomposed depth-T halo exchange, with
-two interchangeable exchange engines and optional compute overlap.
+"""The distributed stencil driver: the 2D-decomposed depth-T halo exchange,
+with two interchangeable exchange engines and optional compute overlap.
+
+One driver serves every operator. `_operator` turns `spec=None` (PW
+advection of (u, v, w) through the hand-written `advect_fused`) or a
+`StencilSpec` (its fields through `stencil_fused`) into a field count, a
+wall width, a halo depth and a per-shard update; the local block, the
+shard_map wrapper and the run core below move that tuple of fields and
+never ask which operator it is.
 
 Each shard of the (nx, ny) mesh owns an (X/nx, Y/ny, Z) slab
 (`make_distributed_step(axis="y", x_axis="x")`; an axis of size 1 exchanges
@@ -42,8 +49,9 @@ byte-identical bands through byte-identical phases, so
     (Mosaic semaphores have no CPU lowering); in interpret mode the
     engine runs a schedule-faithful emulation — the same per-hop band
     messages and recv-slab assembly offsets (`_band_schedule`),
-    transported by ppermute — which the tests and BENCH_overlap.json /
-    BENCH_pipeline.json gate BITWISE-equal to the collective engine.
+    transported by ppermute — which `tests/test_distributed_remote_dma.py`
+    and `tests/test_pipeline_driver.py` hold BITWISE-equal to the
+    collective engine.
 
 The slot parity is exploited by the pipelined multi-block driver
 `make_distributed_run(n_blocks=K)`: ONE jitted program runs K
@@ -79,15 +87,14 @@ everything else from the interior pass.
 `roofline.overlap_efficiency_model` prices how much of the exchange each
 engine hides behind that interior pass, and
 `RooflineTerms.collective_exposed_s` is the wire time left on the critical
-path — the quantity BENCH_overlap.json sweeps.
+path — the quantity `benchmarks/overlap_sweep.py` sweeps.
 
 Runs under `shard_map` over any mesh axes (smoke-tested on the host mesh;
 `launch.mesh.make_stencil_mesh` builds the (nx, ny) production shape).
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -267,9 +274,9 @@ def remote_dma_schedule_wire_bytes(Xl: int, Yl: int, Z: int, itemsize: int,
     the summed `_band_schedule` message sizes over both sides of the
     two-phase x-then-y exchange (phase 2 operands are x-EXTENDED — the
     corner blocks). Computed from the messages the engine issues, NOT from
-    `roofline.halo_wire_bytes_model`'s closed form; the overlap tests and
-    BENCH_overlap.json gate the two EXACTLY equal, pinning the DMA
-    schedule to the priced model."""
+    `roofline.halo_wire_bytes_model`'s closed form;
+    `tests/test_overlap_accounting.py` holds the two EXACTLY equal,
+    pinning the DMA schedule to the priced model."""
     total = 0
     if nx > 1:
         total += sum(2 * cnt * Yl * Z
@@ -339,9 +346,107 @@ def resolve_modes(mesh: Mesh, interpret: Optional[bool],
     return interpret, local_kernel
 
 
-def _check_step_config(T: int, local_kernel: str, exchange: str,
-                       interpret: bool, mesh: Mesh) -> None:
-    """Shared build-time validation for the step and run drivers."""
+class _Operator(NamedTuple):
+    """What the driver needs to know of the operator it moves."""
+    n_fields: int
+    r: int              # wall width: the outermost r cells never change
+    D: int              # halo depth one exchange serves (T substeps)
+    substeps: Callable  # (fields, x_int, y_int, y_tile) -> fields
+    compiled_dma: bool  # the compiled Mosaic DMA kernel can carry it
+    checkpoints: bool   # `_run_state` can snapshot its fields
+    vmem: dict          # extra keywords of its VMEM plan
+
+
+def _operator(params: AdvectParams, spec, spec_params, T: int, dt: float,
+              local_kernel: str, interpret: bool) -> _Operator:
+    """The operator a driver moves, and the one place that branches on
+    `spec`: `spec=None` is PW advection of (u, v, w) through the
+    hand-written `advect_fused`; a `StencilSpec` moves its `n_fields`
+    fields through `stencil_fused`, exchanging at depth `spec.halo(T)`.
+    The reference loop (`local_kernel="reference"`) applies the same
+    global-interior mask every substep."""
+    if spec is None:
+        n, r, D, rk2 = 3, 1, T, False
+
+        def fused(fields, **kw):
+            return K.advect_fused(*fields, params, **kw)
+
+        def sources(fields):
+            return pw_advect_ref(*fields, params)
+        vmem = dict(context="distributed block")
+    else:
+        if not isinstance(spec, SP.StencilSpec):
+            raise ValueError(f"spec must be a StencilSpec, "
+                             f"got {type(spec)!r}")
+        n, r, D = spec.n_fields, spec.radius, spec.halo(T)
+        rk2 = spec.integrator == "rk2"
+
+        def fused(fields, **kw):
+            return K.stencil_fused(fields, spec_params, spec, **kw)
+
+        def sources(fields):
+            return SP.spec_sources(fields, spec_params, spec)
+        vmem = dict(spec=spec, context="spec-driven distributed block")
+
+    def euler(fields, srcs, m, h):
+        return tuple(f + h * jnp.where(m, s, 0.0)
+                     for f, s in zip(fields, srcs))
+
+    def substeps(fields, x_int, y_int, tile):
+        """T masked integrator steps on a (halo'd) slab; None mask =
+        all-interior (the slab edge is then the true boundary, walled
+        structurally)."""
+        if local_kernel == "fused":
+            return fused(
+                fields, T=T, dt=dt, interpret=interpret, y_tile=tile,
+                x_interior_mask=(None if x_int is None
+                                 else x_int.astype(jnp.float32)),
+                y_interior_mask=(None if y_int is None
+                                 else y_int.astype(jnp.float32)))
+        m = jnp.ones((), jnp.bool_)
+        if x_int is not None:
+            m = m & x_int[:, None, None]
+        if y_int is not None:
+            m = m & y_int[None, :, None]
+        for _ in range(T):
+            if rk2:
+                half = euler(fields, sources(fields), m, 0.5 * dt)
+                fields = euler(fields, sources(half), m, dt)
+            else:
+                fields = euler(fields, sources(fields), m, dt)
+        return fields
+
+    return _Operator(n, r, D, substeps, compiled_dma=spec is None,
+                     checkpoints=spec is None, vmem=vmem)
+
+
+def _check_config(op: _Operator, T: int, local_kernel: str, exchange: str,
+                  interpret: bool, mesh: Mesh, verify_integrity: bool = False,
+                  corrupt_halo=None) -> None:
+    """Build-time validation shared by the step and run drivers.
+    `corrupt_halo`'s field index is bounded by the operator's field
+    count."""
+    if not carries_checksums(exchange, interpret):
+        if verify_integrity:
+            raise RuntimeError(
+                "verify_integrity=True rides checksum words on the "
+                "ppermute transports (collective engine and the "
+                "remote-DMA emulation); the compiled Mosaic DMA kernel "
+                "carries no checksum channel yet. Use interpret=True or "
+                "exchange='collective'.")
+        if corrupt_halo is not None:
+            raise RuntimeError(
+                "corrupt_halo injects wire damage in the ppermute "
+                "transports; the compiled Mosaic DMA kernel has no "
+                "injection hook. Use interpret=True.")
+    if corrupt_halo is not None:
+        fi, depth, _ = corrupt_halo
+        if not (0 <= int(fi) < op.n_fields):
+            raise ValueError(f"corrupt_halo field index must be "
+                             f"0..{op.n_fields - 1}, got {fi}")
+        if int(depth) < 1:
+            raise ValueError(f"corrupt_halo depth must be >= 1, "
+                             f"got {depth}")
     if T < 1:
         raise ValueError(f"T must be >= 1, got {T}")
     if local_kernel not in ("reference", "fused"):
@@ -359,6 +464,12 @@ def _check_step_config(T: int, local_kernel: str, exchange: str,
                 f"and needs TPU devices (Mosaic); this mesh holds "
                 f"{platform!r} devices. Use exchange='collective', or "
                 "interpret=True for the schedule-faithful emulation.")
+        if not op.compiled_dma:
+            raise RuntimeError(
+                "spec-driven steps have no compiled Mosaic DMA kernel yet "
+                "(the hand-written halo_band_exchange_dma is 3-field "
+                "advection-specific); use exchange='collective', or "
+                "interpret=True for the schedule-faithful emulation.")
 
 
 def carries_checksums(exchange: str, interpret: bool) -> bool:
@@ -369,109 +480,58 @@ def carries_checksums(exchange: str, interpret: bool) -> bool:
     return exchange != "remote_dma" or interpret
 
 
-def _check_integrity_config(verify_integrity: bool, corrupt_halo,
-                            exchange: str, interpret: bool,
-                            n_fields: int = 3) -> None:
-    """Build-time validation of the integrity layer's knobs. `n_fields`
-    bounds `corrupt_halo`'s field index — 3 (u, v, w) on the legacy
-    path, `spec.n_fields` on a spec-driven build."""
-    if not carries_checksums(exchange, interpret):
-        if verify_integrity:
-            raise RuntimeError(
-                "verify_integrity=True rides checksum words on the "
-                "ppermute transports (collective engine and the "
-                "remote-DMA emulation); the compiled Mosaic DMA kernel "
-                "carries no checksum channel yet. Use interpret=True or "
-                "exchange='collective'.")
-        if corrupt_halo is not None:
-            raise RuntimeError(
-                "corrupt_halo injects wire damage in the ppermute "
-                "transports; the compiled Mosaic DMA kernel has no "
-                "injection hook. Use interpret=True.")
-    if corrupt_halo is not None:
-        fi, depth, _ = corrupt_halo
-        if not (0 <= int(fi) < n_fields):
-            raise ValueError(f"corrupt_halo field index must be "
-                             f"0..{n_fields - 1}, got {fi}")
-        if int(depth) < 1:
-            raise ValueError(f"corrupt_halo depth must be >= 1, "
-                             f"got {depth}")
-
-
 def _flag_shape(x_axis: Optional[str]):
     """Per-shard shape of the integrity mismatch count (out_spec puts one
     entry per shard in the global array)."""
     return (1,) if x_axis is None else (1, 1)
 
 
-def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
-                       x_axis: Optional[str], T: int, dt: float,
-                       local_kernel: str, y_tile: Optional[int],
-                       interpret: bool, overlap: bool, exchange: str,
+def _build_local_block(mesh: Mesh, op: _Operator, *, axis: str,
+                       x_axis: Optional[str], T: int, local_kernel: str,
+                       y_tile: Optional[int], interpret: bool,
+                       overlap: bool, exchange: str,
                        verify_integrity: bool = False,
                        corrupt_halo=None):
     """The per-shard substep-block body shared by `make_distributed_step`
     (one block, static `dma_block_index`) and `make_distributed_run`
     (K blocks, the block counter a traced `fori_loop` induction variable
     feeding the remote-DMA engine's recv-slot parity). Returns
-    ``local_block(u, v, w, block_index) -> (u, v, w)``, or with
-    `verify_integrity` ``-> (u, v, w, mismatch)`` where `mismatch` is the
-    shard's uint32 count of band-checksum mismatches this block
-    (`_flag_shape`-shaped so `_wrap_shard_map` can lay one per shard).
-    `corrupt_halo=(field_idx, rows, value)` injects wire damage into that
-    field's hop-1 hi band on the LAST exchanged phase (y when y is
-    decomposed, else x) — the detection path's fault hook.
+    ``local_block(fields, block_index) -> fields``, a tuple of
+    `op.n_fields` slabs, or with `verify_integrity` ``-> fields +
+    (mismatch,)`` where `mismatch` is the shard's uint32 count of
+    band-checksum mismatches this block (`_flag_shape`-shaped so
+    `_wrap_shard_map` can lay one per shard). `corrupt_halo=(field_idx,
+    rows, value)` injects wire damage into that field's hop-1 hi band on
+    the LAST exchanged phase (y when y is decomposed, else x) — the
+    detection path's fault hook.
     """
     n_y = mesh.shape[axis]
     n_x = mesh.shape[x_axis] if x_axis is not None else 1
+    r, D = op.r, op.D
 
-    def _substeps(us, vs, ws, x_int, y_int, tile):
-        """T masked Euler substeps on a (halo'd) slab; None mask = all-interior
-        (the slab edge is then the true boundary, walled structurally)."""
-        if local_kernel == "fused":
-            return K.advect_fused(
-                us, vs, ws, params, T=T, dt=dt, interpret=interpret,
-                y_tile=tile,
-                x_interior_mask=(None if x_int is None
-                                 else x_int.astype(jnp.float32)),
-                y_interior_mask=(None if y_int is None
-                                 else y_int.astype(jnp.float32)))
-        m = jnp.ones((), jnp.bool_)
-        if x_int is not None:
-            m = m & x_int[:, None, None]
-        if y_int is not None:
-            m = m & y_int[None, :, None]
-        for _ in range(T):
-            su, sv, sw = pw_advect_ref(us, vs, ws, params)
-            us = us + dt * jnp.where(m, su, 0.0)
-            vs = vs + dt * jnp.where(m, sv, 0.0)
-            ws = ws + dt * jnp.where(m, sw, 0.0)
-        return us, vs, ws
-
-    def local_block(u, v, w, block_index):
-        Xl, Yl, Z = u.shape
+    def local_block(fields, block_index):
+        Xl, Yl, Z = fields[0].shape
         X_g, Y_g = n_x * Xl, n_y * Yl
-        dx = T if n_x > 1 else 0
-        dy = T if n_y > 1 else 0
-        if dy and T > Y_g - 2:
+        dx = D if n_x > 1 else 0
+        dy = D if n_y > 1 else 0
+        if dy and D > Y_g - 2 * r:
             raise ValueError(
-                f"halo depth T={T} exceeds the decomposable global Y "
-                f"extent ({Y_g} rows, interior {Y_g - 2}); lower T")
-        if dx and T > X_g - 2:
+                f"halo depth {D} (T={T}) exceeds the decomposable global "
+                f"Y extent ({Y_g} rows, interior {Y_g - 2 * r}); lower T")
+        if dx and D > X_g - 2 * r:
             raise ValueError(
-                f"halo depth T={T} exceeds the decomposable global X "
-                f"extent ({X_g} planes, interior {X_g - 2}); lower T")
+                f"halo depth {D} (T={T}) exceeds the decomposable global "
+                f"X extent ({X_g} planes, interior {X_g - 2 * r}); lower T")
         if local_kernel == "fused":
             # static VMEM budget: the ring register summed against
             # VMEM_PER_CORE at trace time, so an over-budget config
             # fails BEFORE compile with the buffer named (the analysis
-            # layer's vmem pass, generalising the serving-only
-            # serving_max_batch check to every rung)
+            # layer's vmem pass)
             from repro.analysis import vmem as _vmem
             _vmem.distributed_block_plan(
-                (Xl, Yl, Z), T=T, itemsize=u.dtype.itemsize,
+                (Xl, Yl, Z), T=T, itemsize=fields[0].dtype.itemsize,
                 local_kernel=local_kernel, y_tile=y_tile, nx=n_x, ny=n_y,
-                context="distributed block").check()
+                **op.vmem).check()
         iy = jax.lax.axis_index(axis)
         ix = jax.lax.axis_index(x_axis) if dx else None
 
@@ -497,18 +557,18 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
                 if interpret:
                     return tuple(
                         _exchange_remote_dma_emulated(
-                            f, ax_name, T, dim,
+                            f, ax_name, D, dim,
                             integrity_out=integrity_out,
                             corrupt=(_corrupt_for(fi)
                                      if corrupt_halo is not None else None))
                         for fi, f in enumerate(fields))
                 bands = K.halo_band_exchange_dma(
                     *fields, axis=ax_name, mesh_axes=mesh.axis_names,
-                    n=n, depth=T, dim=dim, block_index=block_index,
+                    n=n, depth=D, dim=dim, block_index=block_index,
                     collective_id=cid)
                 return tuple(jnp.concatenate([hi, f, lo], axis=dim)
                              for f, (hi, lo) in zip(fields, bands))
-            hs = [_exchange_halos(f, ax_name, depth=T, dim=dim,
+            hs = [_exchange_halos(f, ax_name, depth=D, dim=dim,
                                   integrity_out=integrity_out,
                                   corrupt=(_corrupt_for(fi)
                                            if corrupt_halo is not None
@@ -525,272 +585,17 @@ def _build_local_block(mesh: Mesh, params: AdvectParams, *, axis: str,
                 mismatch = mismatch + m.reshape(())
             return out + (mismatch.reshape(_flag_shape(x_axis)),)
 
-        fields = (u, v, w)
+        ext = fields
         if dx:
             with jax.named_scope("exchange_x"):
-                fields = _extend(fields, x_axis, n_x, 0, 0)
+                ext = _extend(ext, x_axis, n_x, 0, 0)
         if dy:
             with jax.named_scope("exchange_y"):
-                fields = _extend(fields, axis, n_y, 1, 1)
+                ext = _extend(ext, axis, n_y, 1, 1)
 
-        # ---- global-interior masks over the slab coordinates
-        x_int = y_int = None
-        if dx:
-            gx = ix * Xl - dx + jnp.arange(Xl + 2 * dx)
-            x_int = (gx >= 1) & (gx <= X_g - 2)
-        if dy:
-            gy = iy * Yl - dy + jnp.arange(Yl + 2 * dy)
-            y_int = (gy >= 1) & (gy <= Y_g - 2)
-
-        # ---- boundary pass (consumes the exchange), trimmed to owned rows
-        with jax.named_scope("compute"):
-            us, vs, ws = _substeps(*fields, x_int, y_int, y_tile)
-            out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in (us, vs, ws))
-        if not (overlap and (dx or dy)):
-            return _with_flag(out)
-
-        # ---- interior pass: owned slab only, no exchange dependence.
-        # Shard-cut edges act as walls contaminating < T cells inward; the
-        # select below discards exactly those bands.
-        ox_int = oy_int = None
-        if dx:
-            ogx = ix * Xl + jnp.arange(Xl)
-            ox_int = (ogx >= 1) & (ogx <= X_g - 2)
-        if dy:
-            ogy = iy * Yl + jnp.arange(Yl)
-            oy_int = (ogy >= 1) & (ogy <= Y_g - 2)
-        with jax.named_scope("compute_interior"):
-            inner = _substeps(u, v, w, ox_int, oy_int, y_tile)
-        sx = jnp.arange(Xl)
-        ok_x = jnp.ones((Xl,), jnp.bool_) if not dx else (
-            ((ix == 0) | (sx >= T)) & ((ix == n_x - 1) | (sx < Xl - T)))
-        sy = jnp.arange(Yl)
-        ok_y = jnp.ones((Yl,), jnp.bool_) if not dy else (
-            ((iy == 0) | (sy >= T)) & ((iy == n_y - 1) | (sy < Yl - T)))
-        sel = (ok_x[:, None] & ok_y[None, :])[:, :, None]
-        return _with_flag(tuple(jnp.where(sel, i, b)
-                                for i, b in zip(inner, out)))
-
-    return local_block
-
-
-def _wrap_shard_map(local, mesh: Mesh, axis: str, x_axis: Optional[str],
-                    local_kernel: str, exchange: str, interpret: bool,
-                    *, integrity: bool = False, n_scalars: int = 0,
-                    donate: bool = False):
-    """jit(shard_map(local)) with the repo's spec/check_vma conventions.
-
-    `integrity` appends the per-shard mismatch flag to the out_specs
-    (one `_flag_shape` entry per shard, laid out along the mesh axes);
-    `n_scalars` appends replicated scalar inputs (the run core's traced
-    block bounds).
-    """
-    spec = (P(None, axis, None) if x_axis is None
-            else P(x_axis, axis, None))
-    flag_spec = P(axis) if x_axis is None else P(x_axis, axis)
-    # check_vma=False whenever a Pallas kernel runs per shard (the fused
-    # local kernel, or the compiled remote-DMA exchange) — rationale in the
-    # module docstring, documented once there.
-    uses_pallas = (local_kernel == "fused"
-                   or (exchange == "remote_dma" and not interpret))
-    out_specs = (spec, spec, spec)
-    if integrity:
-        out_specs = out_specs + (flag_spec,)
-    fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=(spec, spec, spec) + (P(),) * n_scalars,
-                       out_specs=out_specs,
-                       check_vma=not uses_pallas)
-    return _jit_fields(fn, mesh, spec, 3, out_specs[3:], n_scalars,
-                       interpret, donate)
-
-
-def _jit_fields(fn, mesh: Mesh, spec, n_fields: int, extra_out_specs,
-                n_scalars: int, interpret: bool, donate: bool = False):
-    """jit a shard_mapped driver. Compiled, its field arguments and results
-    keep the kernels' row-major layout (`kernels.advection.field_format`),
-    so no field is relaid out on entry or exit; a caller places its fields
-    with `field_formats`. `donate` hands the field arguments' buffers to
-    the program."""
-    donate_argnums = tuple(range(n_fields)) if donate else ()
-    if interpret:
-        return jax.jit(fn, donate_argnums=donate_argnums)
-    fmt = K.field_format(NamedSharding(mesh, spec))
-    rep = NamedSharding(mesh, P())
-    return jax.jit(
-        fn, in_shardings=(fmt,) * n_fields + (rep,) * n_scalars,
-        out_shardings=((fmt,) * n_fields
-                       + tuple(NamedSharding(mesh, s)
-                               for s in extra_out_specs)),
-        donate_argnums=donate_argnums)
-
-
-def field_formats(mesh: Mesh, axis: str = "data",
-                  x_axis: Optional[str] = None):
-    """Where a compiled step or run on `mesh` takes its fields: the
-    sharding of the decomposition in the kernels' row-major layout.
-    `jax.device_put(field, field_formats(mesh, ...))` places a host array
-    there directly."""
-    spec = (P(None, axis, None) if x_axis is None
-            else P(x_axis, axis, None))
-    return K.field_format(NamedSharding(mesh, spec))
-
-
-def _check_spec_step_config(spec, T: int, local_kernel: str, exchange: str,
-                            interpret: bool, mesh: Mesh,
-                            verify_integrity: bool = False,
-                            corrupt_halo=None) -> None:
-    """Build-time validation of the spec-driven distributed path."""
-    _check_step_config(T, local_kernel, exchange, interpret, mesh)
-    if not isinstance(spec, SP.StencilSpec):
-        raise ValueError(f"spec must be a StencilSpec, got {type(spec)!r}")
-    if exchange == "remote_dma" and not interpret:
-        raise RuntimeError(
-            "spec-driven steps have no compiled Mosaic DMA kernel yet (the "
-            "hand-written halo_band_exchange_dma is 3-field advection-"
-            "specific); use exchange='collective', or interpret=True for "
-            "the schedule-faithful emulation.")
-    # verify_integrity / corrupt_halo ride the ppermute transports, which
-    # are field-count-generic (`band_checksum` works on any band) — the
-    # knobs plumb straight through; only the field-index bound changes.
-    _check_integrity_config(verify_integrity, corrupt_halo, exchange,
-                            interpret, n_fields=spec.n_fields)
-
-
-def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
-                            x_axis: Optional[str], T: int, dt: float,
-                            local_kernel: str, y_tile: Optional[int],
-                            interpret: bool, overlap: bool, exchange: str,
-                            verify_integrity: bool = False,
-                            corrupt_halo=None):
-    """Spec-generalised per-shard substep-block body: `spec.n_fields`
-    fields exchanged ONCE at depth `D = spec.halo(T) = radius*stages*T`
-    per T integrator steps — `_build_local_block` with every halo=T and
-    every 3-field literal replaced by the spec's radius, stage count and
-    field tuple. Both ppermute transports are already field-count- and
-    depth-generic, so the engines are reused unchanged; only the compiled
-    Mosaic DMA kernel (3-field, advection-specific) is rejected at build
-    time. Returns ``local_block(fields, block_index) -> fields``, or
-    with `verify_integrity` ``-> fields + (mismatch,)`` — the
-    checksummed exchange of `_build_local_block` at the spec's field
-    count and depth (`corrupt_halo=(field_idx, rows, value)` is the
-    matching fault hook; `integrity_bytes_model(n_fields=spec.n_fields,
-    depth=spec.halo(T))` prices the extra words).
-    """
-    n_y = mesh.shape[axis]
-    n_x = mesh.shape[x_axis] if x_axis is not None else 1
-    r = spec.radius
-    D = spec.halo(T)
-
-    def _substeps(fields, x_int, y_int, tile):
-        """T masked integrator steps on a (halo'd) slab; None mask =
-        all-interior (slab edge then walls structurally, zero_source)."""
-        if local_kernel == "fused":
-            return K.stencil_fused(
-                fields, spec_params, spec, T=T, dt=dt, interpret=interpret,
-                y_tile=tile,
-                x_interior_mask=(None if x_int is None
-                                 else x_int.astype(jnp.float32)),
-                y_interior_mask=(None if y_int is None
-                                 else y_int.astype(jnp.float32)))
-        m = jnp.ones((), jnp.bool_)
-        if x_int is not None:
-            m = m & x_int[:, None, None]
-        if y_int is not None:
-            m = m & y_int[None, :, None]
-        half = 0.5 * dt
-        for _ in range(T):
-            if spec.integrator == "rk2":
-                s0 = SP.spec_sources(fields, spec_params, spec)
-                g = tuple(f + half * jnp.where(m, s, 0.0)
-                          for f, s in zip(fields, s0))
-                s1 = SP.spec_sources(g, spec_params, spec)
-                fields = tuple(f + dt * jnp.where(m, s, 0.0)
-                               for f, s in zip(fields, s1))
-            else:
-                srcs = SP.spec_sources(fields, spec_params, spec)
-                fields = tuple(f + dt * jnp.where(m, s, 0.0)
-                               for f, s in zip(fields, srcs))
-        return fields
-
-    def local_block(fields, block_index):
-        del block_index  # no double-buffered DMA slots on the spec path yet
-        Xl, Yl, Z = fields[0].shape
-        X_g, Y_g = n_x * Xl, n_y * Yl
-        dx = D if n_x > 1 else 0
-        dy = D if n_y > 1 else 0
-        if local_kernel == "fused":
-            # static VMEM budget: refuse an over-budget ring at trace
-            # time, naming the buffer (analysis layer's vmem pass)
-            from repro.analysis import vmem as _vmem
-            _vmem.distributed_block_plan(
-                (Xl, Yl, Z), T=T, itemsize=fields[0].dtype.itemsize,
-                local_kernel=local_kernel, y_tile=y_tile, nx=n_x, ny=n_y,
-                spec=spec, context="spec-driven distributed block"
-            ).check()
-        if dy and D > Y_g - 2 * r:
-            raise ValueError(
-                f"halo depth spec.halo(T)={D} exceeds the decomposable "
-                f"global Y extent ({Y_g} rows, interior {Y_g - 2 * r}); "
-                f"lower T")
-        if dx and D > X_g - 2 * r:
-            raise ValueError(
-                f"halo depth spec.halo(T)={D} exceeds the decomposable "
-                f"global X extent ({X_g} planes, interior {X_g - 2 * r}); "
-                f"lower T")
-        iy = jax.lax.axis_index(axis)
-        ix = jax.lax.axis_index(x_axis) if dx else None
-
-        # ---- integrity / fault-injection plumbing (as in
-        # `_build_local_block`): one mismatch word per verified band,
-        # injected damage on the LAST exchanged phase.
-        integrity_out = [] if verify_integrity else None
-        corrupt_dim = None
-        if corrupt_halo is not None and (dx or dy):
-            corrupt_dim = 1 if dy else 0
-
-        # ---- two-phase x-then-y exchange at depth D; same engine dispatch
-        # and corner contract as `_build_local_block` (module docstring).
-        def _extend(fs, ax_name, dim):
-            def _corrupt_for(fi):
-                if corrupt_dim != dim or fi != int(corrupt_halo[0]):
-                    return None
-                return (int(corrupt_halo[1]), corrupt_halo[2])
-
-            if exchange == "remote_dma":
-                return tuple(
-                    _exchange_remote_dma_emulated(
-                        f, ax_name, D, dim,
-                        integrity_out=integrity_out,
-                        corrupt=(_corrupt_for(fi)
-                                 if corrupt_halo is not None else None))
-                    for fi, f in enumerate(fs))
-            hs = [_exchange_halos(f, ax_name, depth=D, dim=dim,
-                                  integrity_out=integrity_out,
-                                  corrupt=(_corrupt_for(fi)
-                                           if corrupt_halo is not None
-                                           else None))
-                  for fi, f in enumerate(fs)]
-            return tuple(jnp.concatenate([h[0], f, h[1]], axis=dim)
-                         for f, h in zip(fs, hs))
-
-        def _with_flag(out):
-            if not verify_integrity:
-                return out
-            mismatch = jnp.zeros((), jnp.uint32)
-            for m in (integrity_out or []):
-                mismatch = mismatch + m.reshape(())
-            return tuple(out) + (mismatch.reshape(_flag_shape(x_axis)),)
-
-        ext = tuple(fields)
-        if dx:
-            with jax.named_scope("exchange_x"):
-                ext = _extend(ext, x_axis, 0)
-        if dy:
-            with jax.named_scope("exchange_y"):
-                ext = _extend(ext, axis, 1)
-
-        # ---- global-interior masks: the wall is `radius` cells wide (a
-        # radius-r stencil cannot carry values past r frozen cells).
+        # ---- global-interior masks over the slab coordinates: the wall
+        # is `r` cells wide (a radius-r stencil cannot carry values past
+        # r frozen cells)
         x_int = y_int = None
         if dx:
             gx = ix * Xl - dx + jnp.arange(Xl + 2 * dx)
@@ -799,14 +604,16 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
             gy = iy * Yl - dy + jnp.arange(Yl + 2 * dy)
             y_int = (gy >= r) & (gy <= Y_g - 1 - r)
 
+        # ---- boundary pass (consumes the exchange), trimmed to owned rows
         with jax.named_scope("compute"):
-            outs = _substeps(ext, x_int, y_int, y_tile)
+            outs = op.substeps(ext, x_int, y_int, y_tile)
             out = tuple(f[dx:dx + Xl, dy:dy + Yl, :] for f in outs)
         if not (overlap and (dx or dy)):
             return _with_flag(out)
 
-        # ---- interior pass (no exchange dependence); shard-cut walls
-        # contaminate < D cells inward, the select discards those bands.
+        # ---- interior pass: owned slab only, no exchange dependence.
+        # Shard-cut edges act as walls contaminating < D cells inward; the
+        # select below discards exactly those bands.
         ox_int = oy_int = None
         if dx:
             ogx = ix * Xl + jnp.arange(Xl)
@@ -815,7 +622,7 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
             ogy = iy * Yl + jnp.arange(Yl)
             oy_int = (ogy >= r) & (ogy <= Y_g - 1 - r)
         with jax.named_scope("compute_interior"):
-            inner = _substeps(tuple(fields), ox_int, oy_int, y_tile)
+            inner = op.substeps(fields, ox_int, oy_int, y_tile)
         sx = jnp.arange(Xl)
         ok_x = jnp.ones((Xl,), jnp.bool_) if not dx else (
             ((ix == 0) | (sx >= D)) & ((ix == n_x - 1) | (sx < Xl - D)))
@@ -829,26 +636,59 @@ def _build_spec_local_block(mesh: Mesh, spec, spec_params, *, axis: str,
     return local_block
 
 
-def _wrap_spec_shard_map(local, mesh: Mesh, axis: str,
-                         x_axis: Optional[str], local_kernel: str,
-                         n_fields: int, interpret: bool, *,
-                         integrity: bool = False,
-                         n_scalars: int = 0):
-    """`_wrap_shard_map` for an n-field spec program. `integrity`
-    appends the per-shard mismatch flag to the out_specs — the same
-    `_flag_shape` layout as the legacy path."""
-    p = (P(None, axis, None) if x_axis is None else P(x_axis, axis, None))
+def _wrap_shard_map(local, mesh: Mesh, n_fields: int, axis: str,
+                    x_axis: Optional[str], local_kernel: str, exchange: str,
+                    interpret: bool, *, integrity: bool = False,
+                    n_scalars: int = 0, donate: bool = False):
+    """jit(shard_map(local)) over `n_fields` sharded slabs, with the repo's
+    spec/check_vma conventions.
+
+    `integrity` appends the per-shard mismatch flag to the out_specs
+    (one `_flag_shape` entry per shard, laid out along the mesh axes);
+    `n_scalars` appends replicated scalar inputs (the run core's traced
+    block bounds). Compiled, the field arguments and results keep the
+    kernels' row-major layout (`kernels.advection.field_format`), so no
+    field is relaid out on entry or exit; a caller places its fields with
+    `field_formats`. `donate` hands the field arguments' buffers to the
+    program.
+    """
+    spec = (P(None, axis, None) if x_axis is None
+            else P(x_axis, axis, None))
     flag_spec = P(axis) if x_axis is None else P(x_axis, axis)
-    uses_pallas = local_kernel == "fused"
-    out_specs = (p,) * n_fields
+    # check_vma=False whenever a Pallas kernel runs per shard (the fused
+    # local kernel, or the compiled remote-DMA exchange) — rationale in the
+    # module docstring, documented once there.
+    uses_pallas = (local_kernel == "fused"
+                   or (exchange == "remote_dma" and not interpret))
+    out_specs = (spec,) * n_fields
     if integrity:
         out_specs = out_specs + (flag_spec,)
     fn = jax.shard_map(local, mesh=mesh,
-                       in_specs=(p,) * n_fields + (P(),) * n_scalars,
+                       in_specs=(spec,) * n_fields + (P(),) * n_scalars,
                        out_specs=out_specs,
                        check_vma=not uses_pallas)
-    return _jit_fields(fn, mesh, p, n_fields, out_specs[n_fields:],
-                       n_scalars, interpret)
+    donate_argnums = tuple(range(n_fields)) if donate else ()
+    if interpret:
+        return jax.jit(fn, donate_argnums=donate_argnums)
+    fmt = K.field_format(NamedSharding(mesh, spec))
+    rep = NamedSharding(mesh, P())
+    return jax.jit(
+        fn, in_shardings=(fmt,) * n_fields + (rep,) * n_scalars,
+        out_shardings=((fmt,) * n_fields
+                       + tuple(NamedSharding(mesh, s)
+                               for s in out_specs[n_fields:])),
+        donate_argnums=donate_argnums)
+
+
+def field_formats(mesh: Mesh, axis: str = "data",
+                  x_axis: Optional[str] = None):
+    """Where a compiled step or run on `mesh` takes its fields: the
+    sharding of the decomposition in the kernels' row-major layout.
+    `jax.device_put(field, field_formats(mesh, ...))` places a host array
+    there directly."""
+    spec = (P(None, axis, None) if x_axis is None
+            else P(x_axis, axis, None))
+    return K.field_format(NamedSharding(mesh, spec))
 
 
 def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
@@ -869,10 +709,11 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     `pack_params` consumes) generalises the step beyond PW advection: the
     returned jit takes `spec.n_fields` slabs and the ONE exchange runs at
     depth `spec.halo(T) = radius * stages * T` — deeper stencils and the
-    RK2 integrator simply exchange deeper, through the same two-phase
-    engines (`params` is ignored; pass the spec's params via
-    `spec_params`). The spec path rejects the compiled Mosaic DMA kernel
-    and the integrity knobs at build time (`_check_spec_step_config`).
+    RK2 integrator simply exchange deeper, through the same driver and
+    the same two-phase engines (`params` is ignored; pass the spec's
+    params via `spec_params`). Only the operator differs (`_operator`);
+    the compiled Mosaic DMA kernel carries (u, v, w) alone, so a spec
+    build refuses it.
 
     `axis` is the mesh axis decomposing y. With `x_axis` the step runs on a
     2D (x, y) device mesh — each shard owns an (X/nx, Y/ny, Z) slab and the
@@ -898,11 +739,12 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     multi-hop via one remote copy per `_band_schedule` hop, so T is
     bounded only by the global extent like the collective engine) and
     runs the schedule-faithful ppermute emulation in interpret mode
-    (bitwise-equal to "collective" — the gate CI runs). `dma_block_index`
-    is the substep block number k, selecting the engine's double-buffered
-    recv slot (k % 2) DYNAMICALLY — alternating parity never retraces;
-    `make_distributed_run` threads a traced counter through K blocks in
-    one program so block k+1's bands land beside block k's.
+    (bitwise-equal to "collective", `tests/test_distributed_remote_dma.py`).
+    `dma_block_index` is the substep block number k, selecting the
+    engine's double-buffered recv slot (k % 2) DYNAMICALLY — alternating
+    parity never retraces; `make_distributed_run` threads a traced counter
+    through K blocks in one program so block k+1's bands land beside
+    block k's.
 
     `local_kernel` selects the per-shard slab update: "reference" is the
     jnp T-substep loop; "fused" streams the slab through the v4 Pallas
@@ -935,12 +777,13 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
 
     `verify_integrity=True` rides a `kernels.advection.band_checksum`
     uint32 word on every band message of both ppermute transports and
-    returns a FOURTH output: the per-shard mismatch count (pass to
+    returns one more output: the per-shard mismatch count (pass to
     `check_integrity` to raise `HaloCorrupted`). The fields are
     bit-untouched — the verified step is BITWISE-equal to the unchecked
     one on clean wires, and the extra bytes are priced by
     `roofline.integrity_bytes_model` / counted by
-    `count_integrity_bytes` (both gated in BENCH_recovery.json).
+    `count_integrity_bytes` (both held equal by
+    `tests/test_recovery_distributed.py`).
     `corrupt_halo=(field_idx, rows, value)` is the matching fault hook:
     wire damage to one received band, injected after the send-side
     checksum so a verified step MUST flag it. Both knobs need the
@@ -948,77 +791,67 @@ def make_distributed_step(mesh: Mesh, params: AdvectParams, *,
     compiled Mosaic DMA path rejects them at build time.
     """
     interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
-    if spec is not None:
-        _check_spec_step_config(spec, T, local_kernel, exchange, interpret,
-                                mesh, verify_integrity, corrupt_halo)
-        spec_block = _build_spec_local_block(
-            mesh, spec, spec_params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-            local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-            overlap=overlap, exchange=exchange,
-            verify_integrity=verify_integrity, corrupt_halo=corrupt_halo)
-
-        def spec_local(*fields):
-            return spec_block(fields, dma_block_index)
-
-        return _wrap_spec_shard_map(spec_local, mesh, axis, x_axis,
-                                    local_kernel, spec.n_fields, interpret,
-                                    integrity=verify_integrity)
-    _check_integrity_config(verify_integrity, corrupt_halo, exchange,
-                            interpret)
-    _check_step_config(T, local_kernel, exchange, interpret, mesh)
+    op = _operator(params, spec, spec_params, T, dt, local_kernel, interpret)
+    _check_config(op, T, local_kernel, exchange, interpret, mesh,
+                  verify_integrity, corrupt_halo)
     local_block = _build_local_block(
-        mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-        local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-        overlap=overlap, exchange=exchange,
-        verify_integrity=verify_integrity, corrupt_halo=corrupt_halo)
+        mesh, op, axis=axis, x_axis=x_axis, T=T, local_kernel=local_kernel,
+        y_tile=y_tile, interpret=interpret, overlap=overlap,
+        exchange=exchange, verify_integrity=verify_integrity,
+        corrupt_halo=corrupt_halo)
 
-    def local(u, v, w):
-        return local_block(u, v, w, dma_block_index)
+    def local(*fields):
+        return local_block(fields, dma_block_index)
 
-    return _wrap_shard_map(local, mesh, axis, x_axis, local_kernel,
-                           exchange, interpret, integrity=verify_integrity)
+    return _wrap_shard_map(local, mesh, op.n_fields, axis, x_axis,
+                           local_kernel, exchange, interpret,
+                           integrity=verify_integrity)
 
 
-def _make_run_core(mesh: Mesh, params: AdvectParams, *, axis: str,
-                   x_axis: Optional[str], T: int, dt: float,
-                   local_kernel: str, y_tile: Optional[int],
-                   interpret: bool, overlap: bool, exchange: str,
-                   verify_integrity: bool, donate: bool = False):
-    """The span-generic run program: ``core(u, v, w, start, end)`` runs
+def _make_run_core(mesh: Mesh, op: _Operator, *, axis: str,
+                   x_axis: Optional[str], T: int, local_kernel: str,
+                   y_tile: Optional[int], interpret: bool, overlap: bool,
+                   exchange: str, verify_integrity: bool,
+                   donate: bool = False):
+    """The span-generic run program: ``core(*fields, start, end)`` runs
     blocks [start, end) with BOTH bounds traced, so one trace serves the
     full run, every checkpoint interval, and every resume continuation —
     interval boundaries never retrace and the per-block wire/integrity
     counts stay span-independent (the trace-once gate). With
-    `verify_integrity` the core returns a fourth output: per-shard
+    `verify_integrity` the core returns one more output: per-shard
     mismatch counts ACCUMULATED over the span. `donate` hands the field
     arguments' buffers to the program.
     """
-    _check_integrity_config(verify_integrity, None, exchange, interpret)
-    _check_step_config(T, local_kernel, exchange, interpret, mesh)
+    _check_config(op, T, local_kernel, exchange, interpret, mesh,
+                  verify_integrity)
     local_block = _build_local_block(
-        mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-        local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-        overlap=overlap, exchange=exchange,
-        verify_integrity=verify_integrity)
+        mesh, op, axis=axis, x_axis=x_axis, T=T, local_kernel=local_kernel,
+        y_tile=y_tile, interpret=interpret, overlap=overlap,
+        exchange=exchange, verify_integrity=verify_integrity)
 
-    def local(u, v, w, start, end):
+    def local(*args):
+        fields, start, end = args[:-2], args[-2], args[-1]
         if verify_integrity:
             def body(k, carry):
                 with jax.named_scope("block"):
-                    uu, vv, ww, m = local_block(carry[0], carry[1],
-                                                carry[2], k)
-                return (uu, vv, ww, carry[3] + m)
-            init = (u, v, w, jnp.zeros(_flag_shape(x_axis), jnp.uint32))
+                    out = local_block(carry[:-1], k)
+                return out[:-1] + (carry[-1] + out[-1],)
+            # the carried count varies over the mesh axes as the block
+            # counts it sums do, or a checked shard_map refuses the loop
+            zero = jnp.zeros(_flag_shape(x_axis), jnp.uint32)
+            init = fields + (jax.lax.pcast(
+                zero, tuple(a for a in (x_axis, axis) if a), to="varying"),)
         else:
             def body(k, carry):
                 with jax.named_scope("block"):
-                    return local_block(*carry, k)
-            init = (u, v, w)
+                    return local_block(carry, k)
+            init = fields
         return jax.lax.fori_loop(start, end, body, init)
 
-    return _wrap_shard_map(local, mesh, axis, x_axis, local_kernel,
-                           exchange, interpret, integrity=verify_integrity,
-                           n_scalars=2, donate=donate)
+    return _wrap_shard_map(local, mesh, op.n_fields, axis, x_axis,
+                           local_kernel, exchange, interpret,
+                           integrity=verify_integrity, n_scalars=2,
+                           donate=donate)
 
 
 def _run_state(u, v, w, block: int, flags) -> dict:
@@ -1080,7 +913,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
                          keep_last: int = 3,
                          spec=None, spec_params=None,
                          donate: bool = False):
-    """Returns run(u, v, w): `n_blocks` substep-blocks (n_blocks * T Euler
+    """Returns run(*fields): `n_blocks` substep-blocks (n_blocks * T Euler
     substeps, ONE depth-T exchange per block) in ONE traced program — the
     pipelined multi-block driver the remote-DMA engine's double-buffered
     recv slabs exist for.
@@ -1107,7 +940,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
     bitwise, the acceptance gate.
 
     `verify_integrity` adds the checksummed exchange of
-    `make_distributed_step` to every block; the run returns a fourth
+    `make_distributed_step` to every block; the run returns one more
     output accumulating the per-shard mismatch counts over all blocks.
 
     `checkpoint_every=k` with `checkpoint_dir=` turns the returned run
@@ -1116,7 +949,7 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
     `training.checkpoint`'s atomic writes at every k-block boundary (and
     block 0 and the final block), `keep_last` bounding disk. A run killed
     mid-way resumes via `resume_distributed_run` BITWISE-equal to the
-    uninterrupted run (the BENCH_recovery.json gate) because every
+    uninterrupted run (`tests/test_recovery_distributed.py`) because every
     segment replays through the same traced core with the restored block
     index feeding the recv-slot parity. Without checkpointing the
     returned run is a pure jitted program (traceable — the byte-counting
@@ -1134,53 +967,20 @@ def make_distributed_run(mesh: Mesh, params: AdvectParams, *,
         raise ValueError("checkpoint_every and checkpoint_dir come "
                          "together: both or neither")
     interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
-    if spec is not None:
-        if checkpoint_every is not None:
-            raise ValueError(
-                "checkpointing is not wired to the spec-driven run yet "
-                "(the snapshot leaf dict is (u, v, w)-specific); run "
-                "without spec= or without checkpoint_every=")
-        _check_spec_step_config(spec, T, local_kernel, exchange, interpret,
-                                mesh, verify_integrity, None)
-        spec_block = _build_spec_local_block(
-            mesh, spec, spec_params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-            local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-            overlap=overlap, exchange=exchange,
-            verify_integrity=verify_integrity)
-
-        def spec_local(*args):
-            fields, start, end = args[:-2], args[-2], args[-1]
-
-            if verify_integrity:
-                def body(k, carry):
-                    with jax.named_scope("block"):
-                        out = spec_block(carry[:-1], k)
-                    return out[:-1] + (carry[-1] + out[-1],)
-                init = tuple(fields) + (
-                    jnp.zeros(_flag_shape(x_axis), jnp.uint32),)
-            else:
-                def body(k, carry):
-                    with jax.named_scope("block"):
-                        return spec_block(carry, k)
-                init = tuple(fields)
-            return jax.lax.fori_loop(start, end, body, init)
-
-        spec_core = _wrap_spec_shard_map(
-            spec_local, mesh, axis, x_axis, local_kernel, spec.n_fields,
-            interpret, integrity=verify_integrity, n_scalars=2)
-
-        def spec_run(*fields):
-            return spec_core(*fields, 0, n_blocks)
-        return spec_run
+    op = _operator(params, spec, spec_params, T, dt, local_kernel, interpret)
+    if checkpoint_every is not None and not op.checkpoints:
+        raise ValueError(
+            "checkpointing is not wired to the spec-driven run yet "
+            "(the snapshot leaf dict is (u, v, w)-specific); run "
+            "without spec= or without checkpoint_every=")
     core = _make_run_core(
-        mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-        local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-        overlap=overlap, exchange=exchange,
-        verify_integrity=verify_integrity, donate=donate)
+        mesh, op, axis=axis, x_axis=x_axis, T=T, local_kernel=local_kernel,
+        y_tile=y_tile, interpret=interpret, overlap=overlap,
+        exchange=exchange, verify_integrity=verify_integrity, donate=donate)
 
     if checkpoint_every is None:
-        def run(u, v, w):
-            return core(u, v, w, 0, n_blocks)
+        def run(*fields):
+            return core(*fields, 0, n_blocks)
         return run
 
     if checkpoint_every < 1:
@@ -1219,9 +1019,10 @@ def resume_distributed_run(mesh: Mesh, params: AdvectParams, u, v, w, *,
     """Restore the latest (or `step=`) checkpoint a checkpointing
     `make_distributed_run` wrote under `checkpoint_dir` and continue to
     `n_blocks`, returning what the uninterrupted run would have —
-    BITWISE (the BENCH_recovery.json gate): the restored block index
-    feeds the recv-slot parity through the same traced core, so replayed
-    intervals are the intervals the dead run would have executed.
+    BITWISE (`tests/test_recovery_distributed.py`): the restored block
+    index feeds the recv-slot parity through the same traced core, so
+    replayed intervals are the intervals the dead run would have
+    executed.
 
     (u, v, w) are templates for structure/dtype only — their VALUES are
     replaced by the restored snapshot (restoring from the block-0
@@ -1238,10 +1039,10 @@ def resume_distributed_run(mesh: Mesh, params: AdvectParams, u, v, w, *,
         raise ValueError(f"n_blocks must be >= 1, got {n_blocks}")
     interpret, local_kernel = resolve_modes(mesh, interpret, local_kernel)
     core = _make_run_core(
-        mesh, params, axis=axis, x_axis=x_axis, T=T, dt=dt,
-        local_kernel=local_kernel, y_tile=y_tile, interpret=interpret,
-        overlap=overlap, exchange=exchange,
-        verify_integrity=verify_integrity)
+        mesh, _operator(params, None, None, T, dt, local_kernel, interpret),
+        axis=axis, x_axis=x_axis, T=T, local_kernel=local_kernel,
+        y_tile=y_tile, interpret=interpret, overlap=overlap,
+        exchange=exchange, verify_integrity=verify_integrity)
     like = _run_state(u, v, w, 0,
                       np.zeros(_global_flag_shape(mesh, axis, x_axis),
                                np.uint32) if verify_integrity else None)
@@ -1279,8 +1080,8 @@ def resume_distributed_run(mesh: Mesh, params: AdvectParams, u, v, w, *,
 # (ONE walker instead of four copies); re-exported under the old private
 # names so existing callers and tests need no edits, and the four
 # counters below are thin wrappers whose values are byte-identical to
-# the pre-refactor implementations (the BENCH gates are the regression
-# test; tests/test_analysis_ledger.py pins the equivalence directly).
+# the pre-refactor implementations (tests/test_analysis_ledger.py pins
+# the equivalence).
 from repro.analysis.jaxpr import iter_jaxprs as _iter_jaxprs  # noqa: E402
 from repro.analysis.ledger import (  # noqa: E402
     MovementLedger as _MovementLedger,
@@ -1298,8 +1099,9 @@ def count_exchange_wire_bytes(fn, *args) -> int:
     exactly the (x, y, z) band payloads; the rank-1 uint32 checksum words
     a `verify_integrity=True` program additionally permutes are counted
     by `count_integrity_bytes` instead, so THIS count is identical with
-    verification on or off — itself a BENCH_recovery.json gate (the
-    integrity layer may not change what the band model prices). The
+    verification on or off, as `tests/test_recovery_distributed.py`
+    holds (the integrity layer may not change what the band model
+    prices). The
     compiled remote-DMA kernel's transfers live inside a `pallas_call`
     and are priced instead by `remote_dma_schedule_wire_bytes` (the same
     `_band_schedule` message sizes the kernel issues), which the overlap
@@ -1323,10 +1125,10 @@ def count_integrity_bytes(fn, *args) -> int:
     exchange rides on each band message, and nothing else (field bands
     are rank 3; `count_exchange_wire_bytes` owns them). Zero on an
     unverified program. The measured counterpart of
-    `roofline.integrity_bytes_model`; BENCH_recovery.json gates the two
-    equal EXACTLY, per block even on a `make_distributed_run` program
-    (the fori body is walked once — same trace-once argument as the wire
-    count)."""
+    `roofline.integrity_bytes_model`;
+    `tests/test_recovery_distributed.py` holds the two equal EXACTLY, per
+    block even on a `make_distributed_run` program (the fori body is
+    walked once — same trace-once argument as the wire count)."""
     return _MovementLedger.of(fn, *args).total("integrity_words")
 
 
@@ -1343,8 +1145,9 @@ def count_pallas_hbm_bytes(fn, *args) -> int:
     For the fused kernel on lane-aligned Z this count equals
     ``kernels.advection.hbm_bytes_model(..., "fused", grid_tiled=True)``
     EXACTLY (and the batched mega-launch counts B times that) — the
-    measured counterpart of the model, gated in BENCH_serving.json the
-    way `count_exchange_wire_bytes` is gated in BENCH_scaling2d.json.
+    measured counterpart of the model, held equal to it by
+    `tests/test_stencil_serving.py` the way `tests/test_distributed_2d.py`
+    holds `count_exchange_wire_bytes` to the wire model.
 
     The ledger splits the guard pass's field re-read into its own
     category; this counter keeps the legacy semantics (EVERY
@@ -1365,7 +1168,7 @@ def count_guard_bytes(fn, *args) -> int:
     The advection kernels proper are never miscounted (their field
     results are rank >= 3, counted by `count_pallas_hbm_bytes` and
     untouched by guarding), so this isolates exactly the detection
-    traffic. Gated in BENCH_faults.json against
+    traffic. Held by `tests/test_faults.py` equal to
     `roofline.guard_bytes_model` EXACTLY — the recovery tier's detection
     traffic priced under the same model-equals-counted discipline as the
     field and wire bytes.

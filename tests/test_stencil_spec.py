@@ -257,3 +257,70 @@ print("OK")
 
 def test_distributed_spec_path_bitwise_and_oracle():
     run_ok(DIST_CODE)
+
+
+# ---------------------------------------------------------------------------
+# the one distributed driver: a spec run is the PW run (4 host devices)
+# ---------------------------------------------------------------------------
+
+MERGED_RUN_CODE = """
+import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_stencil_mesh
+from repro.stencil import spec as SP
+from repro.stencil import distributed as D
+from repro.stencil.advection import stratus_fields
+from repro.kernels.advection.ref import default_params
+
+CASE = "@CASE@"
+X, Y, Z = 8, 12, 8
+p = default_params(Z)
+mesh = make_stencil_mesh(2, 2)
+sh = NamedSharding(mesh, P("x", "y", None))
+pw = SP.pw_advection_spec()
+kw = dict(n_blocks=3, axis="y", x_axis="x", T=2, dt=0.01)
+
+
+def put():
+    return [jax.device_put(f, sh) for f in stratus_fields(X, Y, Z)]
+
+
+def same(a, b, ctx):
+    assert len(a) == len(b), ctx
+    for x, y in zip(a, b):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), ctx
+
+
+if CASE == "donate":
+    kept = D.make_distributed_run(mesh, p, spec=pw, spec_params=p,
+                                  **kw)(*put())
+    args = put()
+    out = D.make_distributed_run(mesh, p, spec=pw, spec_params=p,
+                                 donate=True, **kw)(*args)
+    assert all(a.is_deleted() for a in args)
+    same(out, kept, CASE)
+else:
+    verify = CASE == "verified"
+    for ex, lk in (("collective", "reference"), ("remote_dma", "reference"),
+                   ("collective", "fused")):
+        rkw = dict(kw, exchange=ex, local_kernel=lk,
+                   verify_integrity=verify)
+        legacy = D.make_distributed_run(mesh, p, **rkw)(*put())
+        via_spec = D.make_distributed_run(mesh, p, spec=pw, spec_params=p,
+                                          **rkw)(*put())
+        assert len(via_spec) == 3 + verify, (ex, lk)
+        same(legacy, via_spec, (ex, lk))   # the mismatch flags too
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("case", ["unverified", "verified", "donate"])
+def test_distributed_spec_run_is_the_pw_run(case):
+    """`make_distributed_run(spec=pw_advection_spec())` over K blocks on a
+    2x2 mesh is bitwise the PW run, with and without the verified
+    exchange, and honours `donate` as the PW run does."""
+    run_ok(MERGED_RUN_CODE.replace("@CASE@", case))
